@@ -69,7 +69,6 @@ def engine_bench(out: List[str], smoke: bool = False) -> dict:
         "n_items": spec.n_items, "min_sup": float(ms), "smoke": bool(smoke),
         "jax_backend": jax.default_backend(), "backends": {},
     }
-    on_tpu = jax.default_backend() == "tpu"
     for backend in BACKENDS:
         cfg = EclatConfig(min_sup=ms, variant="v4", p=10, backend=backend)
         t0 = time.perf_counter()
@@ -85,8 +84,7 @@ def engine_bench(out: List[str], smoke: bool = False) -> dict:
         # jnp-vs-pallas delta there measures the fused call pattern (fewer
         # host transfers), not the Mosaic kernel — record which path ran
         entry = {
-            "executed_path": ("pallas-kernel" if on_tpu else "fused-xla-ref")
-            if backend == "pallas" else "xla-ref",
+            "executed_path": res.stats["kernel_path"],
             "mine_wall_s": wall,
             "mine_cold_wall_s": cold_wall,   # trace+compile-inclusive first run
             "itemsets": res.total,

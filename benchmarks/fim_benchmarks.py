@@ -101,7 +101,8 @@ print(json.dumps({"s": time.perf_counter() - t0, "total": res.total,
 
 def fim_cores(out: List[str]) -> None:
     """Fig 15: scaling with executor cores (device count via subprocess)."""
-    env = dict(os.environ, PYTHONPATH="src")
+    # forced host devices: a CPU rehearsal, pinned off any accelerator
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     for cores in ([2, 4, 6, 8, 10] if FULL else [2, 4, 8]):
         for variant in ["v4", "v5"]:
             proc = subprocess.run(

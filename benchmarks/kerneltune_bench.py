@@ -127,6 +127,9 @@ def _mesh_crossover(cells, n_devices: int = 4) -> Optional[dict]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={n_devices}")
+    # forced host devices are a CPU rehearsal: pin the child to the CPU so
+    # it never contends for an accelerator the parent process holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [root, os.path.join(root, "src"), env.get("PYTHONPATH", "")])
     script = _MESH_PROBE.format(src=os.path.join(root, "src"))

@@ -199,6 +199,9 @@ def recovery_bench(out: List[str], smoke: bool = False) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4").strip()
+    # forced host devices are a CPU rehearsal: pin the child to the CPU so
+    # it never contends for an accelerator the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     cmd = [sys.executable, os.path.abspath(__file__), "--child"]

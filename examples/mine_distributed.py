@@ -3,7 +3,8 @@ over a device mesh, kill a partition, recover it from lineage.
 
     PYTHONPATH=src python examples/mine_distributed.py [--devices 4]
 
-(The script re-execs itself with XLA_FLAGS so --devices takes effect.)
+(The script re-execs itself on the CPU with XLA_FLAGS so --devices takes
+effect.)
 """
 import argparse
 import os
@@ -22,6 +23,8 @@ def main():
     if os.environ.get("_MINE_CHILD") != "1":
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
+        # forced host devices: the re-exec runs on the CPU, never a chip
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["_MINE_CHILD"] = "1"
         os.execv(sys.executable, [sys.executable] + sys.argv)
 

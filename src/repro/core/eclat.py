@@ -101,7 +101,6 @@ class EclatConfig:
     mode: str = "all"                   # workload: all | closed | maximal (lineage post-filter, DESIGN.md §9)
     max_k: Optional[int] = None         # deepest itemset length to mine (>= 1); None = unbounded
     bucket_min: int = 128               # pair-buffer bucket-ladder floor (half-pow2 rungs; low floor = low padding waste)
-    chunk_pairs: int = 1 << 18          # level-2 chunking when tri-matrix off
     checkpoint_dir: Optional[str] = None
     checkpoint_every_level: bool = False
 
@@ -318,9 +317,8 @@ def mine(
         return _finish(store, db, stats, config, t_start)
 
     # place the level-1 frontier the way the backend carries it, once —
-    # the chunked no-tri-matrix path below expands the same frontier many
-    # times, and per-call placement (a word-axis reshard for tidsharded)
-    # would repeat for every chunk
+    # level 2 may expand it in several kernel-sized calls, and per-call
+    # placement (a word-axis reshard for tidsharded) would repeat for each
     bitmaps = execu.prepare_frontier(jax.device_put(db.bitmaps))
     diffsets = config.use_diffsets
 
@@ -336,54 +334,32 @@ def mine(
     if tri:
         counts2 = cooccurrence_counts(bitmaps)
         iu, ju, _ = frequent_pairs(counts2, abs_min_sup)
-        # materialize bitmaps only for the survivors; every pre-filtered pair
-        # must pass the engine's threshold again
-        res = execu.expand(
-            bitmaps, iu.astype(np.int32), ju.astype(np.int32), sup1[iu],
-            mode=mode2, min_sup=abs_min_sup,
-            device_of_pair=part_to_dev[table[iu]] if iu.size else None,
-        )
-        # the level-2 LevelRecord below aligns iu/ju (all pre-filtered
-        # pairs) with res.supports (survivors only) on the assumption that
-        # the two sets are identical; a corrupt triangular count matrix
-        # breaks that silently, misaligning every deeper level.  Same
-        # contract as the streaming miner's cached-count check — a real
-        # exception, not an ``assert``, so it fires under ``python -O``.
-        if iu.size and not res.mask.all():
-            bad = np.nonzero(~res.mask)[0]
-            raise RuntimeError(
-                f"triangular-matrix co-occurrence counts disagree with the "
-                f"engine on {bad.size}/{res.mask.size} level-2 pair(s) "
-                f"(first: item ranks {int(iu[bad[0]])},{int(ju[bad[0]])}) — "
-                f"the tri-matrix pass is corrupt")
-        sup2 = res.supports.astype(np.int32)
-        lvl_bitmaps = res.bitmaps
     else:
-        # chunked all-pairs (the paper's no-tri-matrix path for BMS datasets)
-        iu_all, ju_all = np.triu_indices(n1, k=1)
-        keep_i, keep_j, keep_s, keep_bm = [], [], [], []
-        for s in range(0, iu_all.shape[0], config.chunk_pairs):
-            ic = iu_all[s: s + config.chunk_pairs].astype(np.int32)
-            jc = ju_all[s: s + config.chunk_pairs].astype(np.int32)
-            res = execu.expand(
-                bitmaps, ic, jc, sup1[ic],
-                mode=mode2, min_sup=abs_min_sup,
-                device_of_pair=part_to_dev[table[ic]] if ic.size else None,
-            )
-            if res.mask.any():
-                keep_i.append(ic[res.mask]); keep_j.append(jc[res.mask])
-                keep_s.append(res.supports.astype(np.int32))
-                # chunks are concatenated into one frontier: strip the
-                # engine's rung padding so survivor rows stay contiguous
-                keep_bm.append(res.bitmaps[: int(res.mask.sum())])
-        if keep_i:
-            iu = np.concatenate(keep_i).astype(np.int64)
-            ju = np.concatenate(keep_j).astype(np.int64)
-            sup2 = np.concatenate(keep_s)
-            lvl_bitmaps = jnp.concatenate(keep_bm, axis=0)
-        else:
-            iu = ju = np.zeros(0, np.int64); sup2 = np.zeros(0, np.int32)
-            lvl_bitmaps = jnp.zeros((0, w), jnp.uint32)
+        # all pairs (the paper's no-tri-matrix path for BMS datasets); the
+        # engine splits them into kernel-sized calls
+        iu, ju = np.triu_indices(n1, k=1)
+    res = execu.expand(
+        bitmaps, iu.astype(np.int32), ju.astype(np.int32), sup1[iu],
+        mode=mode2, min_sup=abs_min_sup,
+        device_of_pair=part_to_dev[table[iu]] if iu.size else None,
+    )
+    # with the tri-matrix every pre-filtered pair must pass the engine's
+    # threshold again: the level-2 record aligns iu/ju (all pre-filtered
+    # pairs) with res.supports (survivors only), and a corrupt count matrix
+    # would misalign every deeper level silently.  Same contract as the
+    # streaming miner's cached-count check — a real exception, not an
+    # ``assert``, so it fires under ``python -O``.
+    if tri and iu.size and not res.mask.all():
+        bad = np.nonzero(~res.mask)[0]
+        raise RuntimeError(
+            f"triangular-matrix co-occurrence counts disagree with the "
+            f"engine on {bad.size}/{res.mask.size} level-2 pair(s) "
+            f"(first: item ranks {int(iu[bad[0]])},{int(ju[bad[0]])}) — "
+            f"the tri-matrix pass is corrupt")
+    iu = iu[res.mask].astype(np.int64)
+    ju = ju[res.mask].astype(np.int64)
+    sup2 = res.supports.astype(np.int32)
+    lvl_bitmaps = res.bitmaps
     stats["phase_s"]["tri_matrix"] = time.perf_counter() - t0
 
     parent = iu.copy()

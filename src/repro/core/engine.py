@@ -16,8 +16,9 @@ Backends (``register_backend`` registry, selected by ``EclatConfig.backend``):
            semantics every other backend must match bit-exactly.
   pallas   fused executor — one ``pallas_call`` (kernels.fused_intersect)
            gathers rows by scalar-prefetch index maps, intersects, popcounts
-           and applies the min-support threshold in a single kernel on TPU;
-           off-TPU it dispatches to the identically-fused jnp path.  Default.
+           and applies the min-support threshold in one executable on TPU;
+           off-TPU it dispatches to the identically-fused jnp path
+           (``stats()["kernel_path"]`` says which ran).  Default.
   sharded  shard_map-over-either: pairs are grouped by the device their
            equivalence class was partitioned to, padded per device to a
            common bucket, and executed under ``shard_map`` — the paper's
@@ -57,6 +58,8 @@ The default floor is 128 — the ladder is discrete, so a low floor costs at
 most a handful of extra one-time compiles, while a high one (the old 1024)
 dominated padding waste on small levels (BENCH_engine.json recorded
 ``padding_efficiency: 0.115`` with every sub-floor level padded to 1024).
+No rung exceeds ``MAX_PAIRS_PER_CALL`` (the kernel's SMEM bound):
+``Engine.expand`` runs a larger level as several capped calls.
 """
 from __future__ import annotations
 
@@ -74,14 +77,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..dist.compat import shard_map, shard_map_unchecked
 from ..dist.sharding import (grid_block_spec, grid_pair_spec, mesh_descriptor,
                              shard_words, word_shard_spec)
-from ..kernels.fused_intersect import (MODE_DIFFSET, MODE_TID_TO_DIFF,
-                                       MODE_TIDSET, compact_epilogue,
-                                       fused_intersect,
+from ..kernels.fused_intersect import (MAX_PAIRS_PER_CALL, MODE_DIFFSET,
+                                       MODE_TID_TO_DIFF, MODE_TIDSET,
+                                       compact_epilogue, fused_intersect,
                                        fused_intersect_compact,
                                        fused_intersect_compact_ref,
                                        fused_intersect_partial,
                                        fused_intersect_partial_ref,
-                                       fused_intersect_ref)
+                                       fused_intersect_ref, kernel_path)
 
 __all__ = [
     "MODE_TIDSET", "MODE_TID_TO_DIFF", "MODE_DIFFSET",
@@ -89,7 +92,7 @@ __all__ = [
     "ShardedEngine", "TidShardedEngine", "GridShardedEngine",
     "group_pairs_by_device", "register_backend", "available_backends",
     "make_engine", "engine_from_state", "resolve_engine",
-    "DispatchPolicy", "KERNELTUNE_ENV",
+    "DispatchPolicy", "KERNELTUNE_ENV", "MAX_PAIRS_PER_CALL",
 ]
 
 
@@ -219,6 +222,14 @@ def bucket_size(n: int, floor: int) -> int:
     return b
 
 
+def pair_bucket(n: int, floor: int) -> int:
+    """Ladder rung for a pair batch of ``n <= MAX_PAIRS_PER_CALL`` pairs,
+    clipped to that cap: the kernel's scalar-prefetched indices must fit
+    its SMEM (``kernels.fused_intersect.MAX_PAIRS_PER_CALL``), so no rung
+    above it is ever issued.  :meth:`Engine.expand` splits larger levels."""
+    return min(bucket_size(n, floor), MAX_PAIRS_PER_CALL)
+
+
 class PairBuffers:
     """Persistent bucket-ladder host buffers for padded pair batches.
 
@@ -233,7 +244,7 @@ class PairBuffers:
 
     def fill(self, left: np.ndarray, right: np.ndarray, sup_left: np.ndarray):
         q = int(left.shape[0])
-        qb = bucket_size(q, self.floor)
+        qb = pair_bucket(q, self.floor)
         rung = self._rungs.get(qb)
         if rung is None:
             rung = tuple(np.zeros(qb, np.int32) for _ in range(3))
@@ -282,7 +293,7 @@ def group_pairs_by_device(
             f"{d}-device pair axis: {np.unique(bad).tolist()[:8]}")
     order = np.argsort(device_of_pair, kind="stable")
     counts = np.bincount(device_of_pair, minlength=d)
-    qmax = bucket_size(int(counts.max()), floor)
+    qmax = pair_bucket(int(counts.max()), floor)
     lpad = np.zeros((d, qmax), np.int32)
     rpad = np.zeros((d, qmax), np.int32)
     spad = np.zeros((d, qmax), np.int32)
@@ -434,11 +445,16 @@ class DispatchPolicy:
 
     @classmethod
     def load(cls, path: Optional[str] = None) -> Optional["DispatchPolicy"]:
+        """First usable table on the search path.  A table measured on
+        another platform (``jax_backend`` other than the running one, or
+        none recorded) is skipped: CPU crossovers must not steer a TPU."""
         for p in ([path] if path else _default_policy_paths()):
             try:
                 with open(p) as f:
                     data = json.load(f)
             except (OSError, ValueError):
+                continue
+            if data.get("jax_backend") != jax.default_backend():
                 continue
             cells = data.get("crossover", [])
             if cells:
@@ -589,12 +605,16 @@ class Engine:
         self.block_w = None if block_w is None else int(block_w)
         self.compact = bool(compact)
         self.autotune = bool(autotune)
+        if self.autotune:
+            from ..kernels import autotune as at
+            at.enable_user_cache()
         self.compact_min = (min(self.buffers.floor, 128)
                             if compact_min is None else max(int(compact_min), 1))
         self.n_intersections = 0
         self.n_padded = 0
         self.device_pair_counts: List[np.ndarray] = []
         self.level_padding: List[Tuple[int, int]] = []
+        self.call_shapes: set = set()
         self.n_devices = 1
 
     def _record_padding(self, q: int, padded: int) -> None:
@@ -624,8 +644,53 @@ class Engine:
         device_of_pair: Optional[np.ndarray] = None,
     ) -> LevelResult:
         """Intersect all (left[q], right[q]) frontier-row pairs, threshold at
-        ``min_sup``, and return the device-compacted survivors."""
+        ``min_sup``, and return the device-compacted survivors.
+
+        A batch above ``MAX_PAIRS_PER_CALL`` pairs is split into calls of at
+        most that many; their survivors are concatenated in pair order, so
+        the result is bit-exact with one unbounded call."""
+        q = int(left.shape[0])
+        if q == 0:
+            return self._empty(bitmaps)
+        cap = MAX_PAIRS_PER_CALL
+        parts = [
+            self._expand_call(
+                bitmaps, left[s:s + cap], right[s:s + cap],
+                sup_left[s:s + cap], mode=mode, min_sup=min_sup,
+                device_of_pair=(None if device_of_pair is None
+                                else device_of_pair[s:s + cap]))
+            for s in range(0, q, cap)]
+        if len(parts) == 1:
+            return parts[0]
+        # each call's survivors lead its rung-padded block: gather them out
+        # of the concatenated blocks into one survivor rung
+        offsets = np.cumsum([0] + [p.bitmaps.shape[0] for p in parts[:-1]])
+        sel = np.concatenate([o + np.arange(p.supports.shape[0])
+                              for o, p in zip(offsets, parts)])
+        block = jnp.concatenate([p.bitmaps for p in parts], axis=0)
+        return LevelResult(
+            mask=np.concatenate([p.mask for p in parts]),
+            supports=np.concatenate([p.supports for p in parts]),
+            bitmaps=self._compact(block, sel.astype(np.int32)))
+
+    def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
+                     device_of_pair=None) -> LevelResult:
+        """One kernel-sized batch (``0 < Q <= MAX_PAIRS_PER_CALL``)."""
         raise NotImplementedError
+
+    def kernel_path(self) -> str:
+        """Which executable this engine's expansions run (``mosaic``,
+        ``interpret`` or ``xla-ref``; see ``kernels.fused_intersect.ops``)."""
+        inner = getattr(self, "inner", self.name)
+        if inner != "pallas":
+            return "xla-ref"
+        return kernel_path(getattr(self, "interpret", None))
+
+    def _note_call(self, bitmaps: jax.Array, qb: int, mode: int) -> None:
+        """Record a dispatched (rows, words, pairs, mode) kernel shape — one
+        compiled executable each — for ``stats()["call_shapes"]``."""
+        self.call_shapes.add((int(bitmaps.shape[0]), int(bitmaps.shape[1]),
+                              int(qb), int(mode)))
 
     def _empty(self, bitmaps: jax.Array) -> LevelResult:
         w = bitmaps.shape[1]
@@ -653,9 +718,11 @@ class Engine:
         """Rung-slice a fused-epilogue compaction result: rows ``[:n_surv]``
         are the survivors, the rung padding beyond them duplicates row 0 —
         the same convention :meth:`_compact` produces, so the two paths are
-        interchangeable bit-for-bit."""
+        interchangeable bit-for-bit.  The rung is clipped to the block: a
+        pair batch clipped to ``MAX_PAIRS_PER_CALL`` can be shorter than the
+        survivor ladder's next rung."""
         sb = bucket_size(max(int(n_surv), 1), self.compact_min)
-        return _prefix_rows(compact, sb)
+        return _prefix_rows(compact, min(sb, compact.shape[0]))
 
     def prepare_frontier(self, bitmaps: jax.Array) -> jax.Array:
         """Place a frontier the way this backend will carry it (identity for
@@ -713,9 +780,12 @@ class Engine:
         i0, p0, d0, l0 = (tuple(since) + (0,) * 4)[:4] if since else (0,) * 4
         out = {
             "backend": self.name,
+            "kernel_path": self.kernel_path(),
             "n_intersections": self.n_intersections - i0,
             "n_padded": self.n_padded - p0,
         }
+        if self.call_shapes:
+            out["call_shapes"] = sorted(list(c) for c in self.call_shapes)
         levels = self.level_padding[l0:]
         if levels:
             tot_q = sum(q for q, _ in levels)
@@ -765,14 +835,13 @@ class JnpEngine(Engine):
     :func:`fused_intersect_compact_ref`; ``compact=False`` keeps the legacy
     host-mask -> separate-gather two-step."""
 
-    def expand(self, bitmaps, left, right, sup_left, *, mode, min_sup,
-               device_of_pair=None):
+    def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
+                     device_of_pair=None):
         q = int(left.shape[0])
-        if q == 0:
-            return self._empty(bitmaps)
         self.n_intersections += q
         qb, l, r, s = self.buffers.fill(left, right, sup_left)
         self._record_padding(q, qb)
+        self._note_call(bitmaps, qb, mode)
         if self.compact:
             out, sup, mask_dev, n_surv = fused_intersect_compact_ref(
                 bitmaps, _dput(l), _dput(r), _dput(s),
@@ -812,14 +881,13 @@ class PallasEngine(Engine):
                          autotune=autotune, compact_min=compact_min)
         self.interpret = interpret
 
-    def expand(self, bitmaps, left, right, sup_left, *, mode, min_sup,
-               device_of_pair=None):
+    def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
+                     device_of_pair=None):
         q = int(left.shape[0])
-        if q == 0:
-            return self._empty(bitmaps)
         self.n_intersections += q
         qb, l, r, s = self.buffers.fill(left, right, sup_left)
         self._record_padding(q, qb)
+        self._note_call(bitmaps, qb, mode)
         self._maybe_tune(qb, bitmaps.shape[1], mode)
         if self.compact:
             inter, sup, mask_dev, n_surv = fused_intersect_compact(
@@ -899,11 +967,9 @@ class ShardedEngine(Engine):
             for mode in (MODE_TIDSET, MODE_TID_TO_DIFF, MODE_DIFFSET)
         }
 
-    def expand(self, bitmaps, left, right, sup_left, *, mode, min_sup,
-               device_of_pair=None):
+    def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
+                     device_of_pair=None):
         q = int(left.shape[0])
-        if q == 0:
-            return self._empty(bitmaps)
         self.n_intersections += q
         d = self.n_devices
         qmax, lpad, rpad, spad, slot_of_pair, counts = group_pairs_by_device(
@@ -1098,11 +1164,9 @@ class TidShardedEngine(_WordShardedFrontierMixin, Engine):
         out["n_word_shards"] = self.n_shards
         return out
 
-    def expand(self, bitmaps, left, right, sup_left, *, mode, min_sup,
-               device_of_pair=None):
+    def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
+                     device_of_pair=None):
         q = int(left.shape[0])
-        if q == 0:
-            return self._empty(bitmaps)
         self.n_intersections += q
         qb, l, r, s = self.buffers.fill(left, right, sup_left)
         self._record_padding(q, qb)
@@ -1201,11 +1265,9 @@ class GridShardedEngine(_WordShardedFrontierMixin, Engine):
         out["grid"] = [self.n_class, self.n_shards]
         return out
 
-    def expand(self, bitmaps, left, right, sup_left, *, mode, min_sup,
-               device_of_pair=None):
+    def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
+                     device_of_pair=None):
         q = int(left.shape[0])
-        if q == 0:
-            return self._empty(bitmaps)
         self.n_intersections += q
         d = self.n_class
         qmax, lpad, rpad, spad, slot_of_pair, counts = group_pairs_by_device(

@@ -4,13 +4,13 @@ The paper's scalability property — equivalence classes partitioned once,
 mined communication-free per executor — maps onto JAX as a mesh + a small
 set of placement rules:
 
-  compat    jax-version shims (make_mesh / shard_map / AxisType)
+  compat    the mesh and shard_map entry points (make_mesh / shard_map /
+            AxisType)
   sharding  mesh registry, data-parallel axes, parameter/batch placement
             rules, activation sharding constraints
 
 Everything model- and launch-side goes through :mod:`repro.dist.sharding`;
-everything that touches a drifting jax API goes through
-:mod:`repro.dist.compat`.
+meshes and shard_map come from :mod:`repro.dist.compat`.
 """
 from .compat import AxisType, make_mesh, shard_map
 from .sharding import (batch_spec, constrain, dp_axes, get_mesh,

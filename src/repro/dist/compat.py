@@ -1,92 +1,35 @@
-"""jax version-drift shims for the dist layer.
+"""The dist layer's one door to ``jax.make_mesh`` and ``jax.shard_map``.
 
-The repo targets the jax.sharding API as of jax >= 0.5 (``AxisType``,
-``jax.make_mesh(..., axis_types=...)``, top-level ``jax.shard_map``) while
-remaining runnable on jax 0.4.x, where none of those exist yet.  Every call
-site that would otherwise touch a drifting symbol goes through this module:
-
-  make_mesh   ``jax.make_mesh`` with ``axis_types`` accepted on every version
-              (silently dropped on 0.4.x, where all mesh axes are Auto-like)
-  shard_map   ``jax.shard_map`` on >= 0.5/0.6, else
-              ``jax.experimental.shard_map.shard_map``
-  AxisType    the real enum when available, else a stand-in with the same
-              member names so ``AxisType.Auto`` spells the same everywhere
+  make_mesh            ``jax.make_mesh`` with every axis ``AxisType.Auto``
+                       unless told otherwise (the GSPMD-propagation
+                       behaviour the whole codebase assumes)
+  shard_map            ``jax.shard_map``
+  shard_map_unchecked  ``jax.shard_map`` with ``check_vma=False``
+  AxisType             ``jax.sharding.AxisType``
 """
 from __future__ import annotations
 
-import enum
-import inspect
-
 import jax
 
-__all__ = ["AxisType", "make_mesh", "shard_map", "shard_map_unchecked",
-           "HAS_AXIS_TYPES"]
+__all__ = ["AxisType", "make_mesh", "shard_map", "shard_map_unchecked"]
 
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-if HAS_AXIS_TYPES:
-    AxisType = jax.sharding.AxisType
-else:
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Stand-in for ``jax.sharding.AxisType`` on jax 0.4.x."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+AxisType = jax.sharding.AxisType
+shard_map = jax.shard_map
 
 
 def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that accepts ``axis_types`` on every jax version.
-
-    On jax >= 0.5 the types are forwarded (defaulting every axis to Auto, the
-    GSPMD-propagation behaviour the whole codebase assumes).  On 0.4.x the
-    argument is dropped — meshes there are implicitly Auto.
-    """
-    axis_shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` defaulting every axis to ``AxisType.Auto``."""
     axis_names = tuple(axis_names)
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axis_names)
-        kwargs["axis_types"] = tuple(axis_types)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def _rep_check_flag():
-    try:
-        params = inspect.signature(shard_map).parameters
-    except (TypeError, ValueError):
-        return None
-    for name in ("check_rep", "check_vma"):
-        if name in params:
-            return name
-    return None
-
-
-_REP_CHECK_FLAG = _rep_check_flag()
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(axis_shapes), axis_names,
+                         axis_types=tuple(axis_types), **kwargs)
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking disabled, across versions.
-
-    ``pallas_call`` has no replication rule, so a shard-mapped Pallas kernel
-    (the sharded mining engine's fused inner executor) must opt out of the
-    check.  The flag is ``check_rep`` on jax <= 0.6 and ``check_vma`` later;
-    the flag name is resolved from ``shard_map``'s signature at import time,
-    so an unknown rename fails loudly here instead of as an opaque
-    replication-rule error inside the first sharded kernel launch.
-    """
-    if _REP_CHECK_FLAG is None:
-        raise NotImplementedError(
-            "this jax version's shard_map exposes neither check_rep nor "
-            "check_vma; teach dist.compat._rep_check_flag its new name")
+    """``shard_map`` with replication checking disabled: ``pallas_call``
+    has no replication rule, so a shard-mapped Pallas kernel (the mesh
+    engines' fused inner executor) must opt out of the check."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{_REP_CHECK_FLAG: False})
+                     check_vma=False)

@@ -16,9 +16,12 @@ elsewhere).  This module makes it a measured decision:
     the predicted winner and the sweep can be truncated without losing it.
 3.  **Measurement, then cache.**  Each candidate is timed steady-state
     (compile excluded, ``block_until_ready`` inside the timed region) on
-    synthetic data of the class shape; the winner lands in a persistent
-    JSON table (``REPRO_AUTOTUNE_CACHE`` or
-    ``~/.cache/repro-eclat/autotune.json``) keyed by shape class.
+    synthetic data of the class shape; the winner lands in a JSON table
+    keyed by shape class.  A table on disk is read only when asked for:
+    ``REPRO_AUTOTUNE_CACHE`` names one, or ``--autotune``
+    (:func:`enable_user_cache`) opts into ``~/.cache/repro-eclat/
+    autotune.json``.  Otherwise the table lives in memory, so nothing
+    outside the checkout changes which block width a run compiles.
 4.  **Lookup at trace time.**  ``repro.kernels.fused_intersect.ops``
     resolves ``block_w=None`` through :func:`lookup`; the table read is a
     host-side dict hit during tracing, so tuned widths reach every backend
@@ -49,7 +52,8 @@ from .fused_intersect.fused_intersect import (DEFAULT_BLOCK_W, MODE_TIDSET,
 
 __all__ = ["KernelConfig", "shape_class", "block_w_candidates",
            "seeded_candidates", "AutotuneTable", "table_path", "load_table",
-           "lookup", "tune_shape", "reset", "DEFAULT_BLOCK_W"]
+           "lookup", "tune_shape", "reset", "enable_user_cache",
+           "DEFAULT_BLOCK_W"]
 
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 _DEFAULT_CACHE = os.path.join("~", ".cache", "repro-eclat", "autotune.json")
@@ -174,8 +178,24 @@ class AutotuneTable:
         os.replace(tmp, self.path)
 
 
-def table_path() -> str:
-    return os.path.expanduser(os.environ.get(CACHE_ENV, _DEFAULT_CACHE))
+_USE_USER_CACHE = False
+
+
+def enable_user_cache() -> None:
+    """Opt into the per-user table on disk (``--autotune``)."""
+    global _USE_USER_CACHE
+    if not _USE_USER_CACHE:
+        _USE_USER_CACHE = True
+        reset()
+
+
+def table_path() -> Optional[str]:
+    """``REPRO_AUTOTUNE_CACHE`` if set; else the per-user table once
+    :func:`enable_user_cache` opted in; else ``None`` (in memory only)."""
+    env = os.environ.get(CACHE_ENV)
+    if env:
+        return os.path.expanduser(env)
+    return os.path.expanduser(_DEFAULT_CACHE) if _USE_USER_CACHE else None
 
 
 _TABLE: Optional[AutotuneTable] = None

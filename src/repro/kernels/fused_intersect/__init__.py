@@ -1,17 +1,18 @@
-from .fused_intersect import (DEFAULT_BLOCK_W, LANE, MODE_DIFFSET,
-                              MODE_TID_TO_DIFF, MODE_TIDSET, compact_epilogue,
+from .fused_intersect import (DEFAULT_BLOCK_W, LANE, MAX_PAIRS_PER_CALL,
+                              MODE_DIFFSET, MODE_TID_TO_DIFF, MODE_TIDSET,
+                              compact_epilogue,
                               fused_intersect_compact_pairs,
                               fused_intersect_pairs,
                               fused_intersect_partial_pairs, round_up_lanes)
 from .ops import (fused_intersect, fused_intersect_compact,
-                  fused_intersect_partial, resolve_block_w)
+                  fused_intersect_partial, kernel_path, resolve_block_w)
 from .ref import (fused_intersect_compact_ref, fused_intersect_partial_ref,
                   fused_intersect_ref)
 
 __all__ = [
     "MODE_TIDSET", "MODE_TID_TO_DIFF", "MODE_DIFFSET",
-    "DEFAULT_BLOCK_W", "LANE", "round_up_lanes", "resolve_block_w",
-    "compact_epilogue",
+    "DEFAULT_BLOCK_W", "LANE", "MAX_PAIRS_PER_CALL", "round_up_lanes",
+    "resolve_block_w", "kernel_path", "compact_epilogue",
     "fused_intersect", "fused_intersect_pairs", "fused_intersect_ref",
     "fused_intersect_compact", "fused_intersect_compact_pairs",
     "fused_intersect_compact_ref",

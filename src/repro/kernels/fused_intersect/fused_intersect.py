@@ -1,49 +1,43 @@
-"""Pallas TPU kernel: fused gather + AND + popcount + min-support mask.
+"""Pallas TPU kernel: fused gather + AND + popcount for candidate pairs.
 
 The Eclat hot loop in one ``pallas_call``: for each candidate pair ``q`` the
 kernel DMA-gathers the two parent bitmap rows straight out of the frontier
 (no materialized ``jnp.take`` copies), intersects them in the mode the miner
-is running in, accumulates the per-row popcount across the word grid, and on
-the last word block converts the count into a support and compares it against
-``min_sup``.  Only the ``(Q,)`` support and mask vectors need to cross back
-to the driver; the ``(Q, W)`` intersection stays device-resident for the
-survivor compaction.
+is running in, and accumulates the row popcount across the word grid.  The
+jitted wrappers turn the popcount into a support and compare it against
+``min_sup`` in the same executable, so only the ``(Q,)`` support and mask
+vectors need to cross back to the driver; the ``(Q, W)`` intersection stays
+device-resident for the survivor compaction.
 
 Modes (match ``repro.core.engine``):
     0  tidset:           inter = a & b,   sup = |inter|
     1  tidset->diffset:  inter = a & ~b,  sup = sup_left - |inter|
     2  diffset:          inter = b & ~a,  sup = sup_left - |inter|
 
-Raw-speed structure (ISSUE 7 / ROADMAP item 2):
+Block shapes are the ones Mosaic accepts (the TPU compile tests in
+``tests/test_tpu_compile.py`` hold every variant to them):
 
-* **Scalar-prefetch row gather, double-buffered.**  The pair-index array is
-  a scalar-prefetch operand (``PrefetchScalarGridSpec``), so the input
-  ``BlockSpec`` index maps read ``idx_ref[0, q]`` / ``idx_ref[1, q]`` and
-  the Mosaic pipeline issues the row DMAs from the prefetched indices.  The
-  grid is (Q, W/bw) with the word axis innermost and the two parent rows as
-  *separate* operands: the pipeline keeps two buffers in flight per operand,
-  so the gather of step ``(q, j+1)`` (and of the next pair's first block)
-  overlaps the AND+popcount of step ``(q, j)``.  The q dimension cannot be
-  blocked — gathered rows are not contiguous — so overlap, not blocking, is
-  what hides the gather.
-* **Lane-aligned popcount accumulation.**  Block widths are rounded to the
-  VPU lane width (128); the running popcount is carried as a ``(1, 128)``
-  per-lane partial vector in VMEM scratch and only collapsed to a scalar on
-  the last word block.  Accumulating per-lane keeps every grid step a pure
-  element-wise VPU op (AND, popcount, add) with no cross-lane reduction in
-  the loop body.
-* **Survivor compaction in the fused executable.**  The ``*_compact``
-  variants append a prefix-sum survivor compaction (mask -> ascending
-  survivor indices -> row gather) to the kernel epilogue inside the same
-  jit, so one dispatch returns the min-sup mask, supports, *and* the
-  survivor-compacted block — the engine no longer round-trips the mask to
-  the host before launching a second gather dispatch, and only survivor
-  rows are live downstream (DESIGN.md §3, §6).
+* **Row gather through a ``(P, 1, W)`` view.**  A ``(1, bw)`` block of a
+  ``(P, W)`` array is refused (its second-minor dim is neither 8-aligned nor
+  the full dim).  Viewing the frontier as ``(P, 1, W)`` makes the block
+  ``(None, 1, bw)``: the squeezed leading dim is the gathered row, picked by
+  the scalar-prefetched pair indices (``PrefetchScalarGridSpec``), and the
+  trailing ``(1, bw)`` is a full-extent tile.  The grid is ``(Q, W/bw)`` with
+  the word axis innermost; the pipeline double-buffers each operand, so the
+  gather of step ``(q, j+1)`` overlaps the AND+popcount of ``(q, j)``.
+* **Lane-dense per-pair output.**  The popcount of pair ``q`` is written to
+  lane ``q % 128`` of a ``(1, 128)`` block that stays resident for 128
+  consecutive pairs, so the output is ``(ceil(Q/128), 1, 128)`` int32 and no
+  ``(1,)`` VMEM block or scalar store exists.  Revisiting that block makes
+  the pair axis ``arbitrary`` (sequential), which costs nothing on a
+  one-TensorCore v5e.
+* **SMEM budget.**  The ``(2, Q)`` int32 pair indices are the only
+  scalar-prefetch operand: 8 bytes per pair of the 1 MiB SMEM.
+  :data:`MAX_PAIRS_PER_CALL` is the per-call cap the engine never exceeds.
 
-``block_w`` is no longer a single hard-coded constant: callers that pass
-``None`` to the ``ops`` dispatch layer get the autotuned width for their
-(Q, W, mode) shape class (``repro.kernels.autotune``); ``DEFAULT_BLOCK_W``
-remains the seed/fallback value only.
+``block_w`` is resolved by the ``ops`` dispatch layer (autotuned table or
+cost-model seed, ``repro.kernels.autotune``); ``DEFAULT_BLOCK_W`` is the
+fallback value only.
 """
 from __future__ import annotations
 
@@ -56,6 +50,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_W = 512
 LANE = 128                      # VPU lane width: all block widths are 128-multiples
+
+SMEM_BYTES = 1 << 20            # scalar memory of one TPU v5e core
+IDX_BYTES_PER_PAIR = 8          # (2, Q) int32 scalar-prefetched pair indices
+# Largest pair batch one kernel call may take: a power of two whose index
+# prefetch fills at most half of SMEM, leaving the rest to Mosaic's own
+# scalars.  2**16 pairs -> 512 KiB; 2**18 pairs (the old level-2 chunk) asked
+# for 2 MiB and was refused by the compiler.
+MAX_PAIRS_PER_CALL = 1 << ((SMEM_BYTES // 2 // IDX_BYTES_PER_PAIR).bit_length() - 1)
 
 MODE_TIDSET = 0
 MODE_TID_TO_DIFF = 1
@@ -81,65 +83,29 @@ def _intersect(a, b, mode):
     return jnp.bitwise_and(b, jnp.bitwise_not(a))
 
 
-def _lane_popcount(inter) -> jax.Array:
-    """(1, bw) uint32 block -> (1, LANE) int32 per-lane popcount partials.
-    Pure VPU work: popcount, a sublane-folding reshape, and an add-reduce
-    that never crosses lanes."""
-    pc = jax.lax.population_count(inter).astype(jnp.int32)
-    return pc.reshape(-1, LANE).sum(axis=0, keepdims=True)
-
-
-def _kernel(idx_ref, supl_ref, msup_ref, a_ref, b_ref,
-            inter_ref, sup_ref, mask_ref, acc_ref, *, mode):
+def _kernel(idx_ref, a_ref, b_ref, inter_ref, pop_ref, acc_ref, *, mode):
+    """One (pair, word block) grid step: intersect, store, accumulate the
+    per-word popcount; on the last word block fold it into the pair's lane
+    of the lane-dense popcount output."""
     q = pl.program_id(0)
     wj = pl.program_id(1)
-    nw = pl.num_programs(1)
     inter = _intersect(a_ref[...], b_ref[...], mode)
     inter_ref[...] = inter
-    lanes = _lane_popcount(inter)
+    pc = jax.lax.population_count(inter).astype(jnp.int32)
 
     @pl.when(wj == 0)
     def _init():
-        acc_ref[...] = lanes
+        acc_ref[...] = pc
 
     @pl.when(wj != 0)
     def _acc():
-        acc_ref[...] = acc_ref[...] + lanes
+        acc_ref[...] = acc_ref[...] + pc
 
-    @pl.when(wj == nw - 1)
+    @pl.when(wj == pl.num_programs(1) - 1)
     def _finish():
-        pop = acc_ref[...].sum()
-        sup = pop if mode == MODE_TIDSET else supl_ref[q] - pop
-        sup_ref[0] = sup
-        mask_ref[0] = (sup >= msup_ref[0]).astype(jnp.int32)
-
-
-def _kernel_partial(idx_ref, a_ref, b_ref, inter_ref, pop_ref, acc_ref, *,
-                    mode):
-    """Shard-local half of the fused kernel: intersect + accumulate popcount.
-
-    No ``sup_left`` finishing and no min-support mask — on a word-sharded
-    frontier each device sees only its word slice, so the popcount here is a
-    *partial* count; the caller psums it across shards before thresholding
-    (``repro.core.engine.TidShardedEngine``, DESIGN.md §7).
-    """
-    wj = pl.program_id(1)
-    nw = pl.num_programs(1)
-    inter = _intersect(a_ref[...], b_ref[...], mode)
-    inter_ref[...] = inter
-    lanes = _lane_popcount(inter)
-
-    @pl.when(wj == 0)
-    def _init():
-        acc_ref[...] = lanes
-
-    @pl.when(wj != 0)
-    def _acc():
-        acc_ref[...] = acc_ref[...] + lanes
-
-    @pl.when(wj == nw - 1)
-    def _finish():
-        pop_ref[0] = acc_ref[...].sum()
+        total = jnp.sum(acc_ref[...], axis=1, keepdims=True)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
+        pop_ref[...] = jnp.where(lane == q % LANE, total, pop_ref[...])
 
 
 def _pad_words(bitmaps: jax.Array, bw: int) -> jax.Array:
@@ -147,6 +113,63 @@ def _pad_words(bitmaps: jax.Array, bw: int) -> jax.Array:
     if pad_w:
         bitmaps = jnp.pad(bitmaps, ((0, 0), (0, pad_w)))
     return bitmaps
+
+
+def _popcount_pairs(bitmaps, left, right, *, mode, block_w, interpret):
+    """Shared kernel launch: validate, lane-pad, gather-intersect-count.
+    Returns the *word-padded* ``(Q, Wp)`` intersection block, the ``(Q,)``
+    int32 popcounts and the unpadded width ``w``."""
+    if bitmaps.ndim != 2:
+        raise ValueError(f"expected (P, W) frontier, got {bitmaps.shape}")
+    if left.shape != right.shape or left.ndim != 1:
+        raise ValueError("left/right must share a (Q,) shape")
+    qn = left.shape[0]
+    if qn > MAX_PAIRS_PER_CALL:
+        raise ValueError(f"{qn} pairs exceed the per-call cap "
+                         f"MAX_PAIRS_PER_CALL={MAX_PAIRS_PER_CALL}")
+    w = bitmaps.shape[1]
+    bw = _resolve_block_w(w, block_w)
+    frontier = _pad_words(bitmaps, bw)
+    wp = frontier.shape[1]
+    rows = frontier.reshape(frontier.shape[0], 1, wp)
+    idx = jnp.stack([left.astype(jnp.int32), right.astype(jnp.int32)])
+    nqb = pl.cdiv(qn, LANE)
+
+    def row_spec(side):
+        return pl.BlockSpec((None, 1, bw),
+                            lambda q, j, idx_ref: (idx_ref[side, q], 0, j))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(qn, wp // bw),
+        in_specs=[row_spec(0), row_spec(1)],
+        out_specs=[
+            pl.BlockSpec((None, 1, bw), lambda q, j, *_: (q, 0, j)),
+            pl.BlockSpec((None, 1, LANE), lambda q, j, *_: (q // LANE, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((1, bw), jnp.int32)],
+    )
+    inter, pop = pl.pallas_call(
+        functools.partial(_kernel, mode=mode),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((qn, 1, wp), jnp.uint32),
+            jax.ShapeDtypeStruct((nqb, 1, LANE), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ) if not interpret else None,
+        interpret=interpret,
+    )(idx, rows, rows)
+    return inter.reshape(qn, wp), pop.reshape(nqb * LANE)[:qn], w
+
+
+def _support_mask(pop, sup_left, min_sup, mode):
+    """Popcount -> support (tidset: the count; diffset modes: the parent's
+    support minus it) -> min-support mask."""
+    sup = pop if mode == MODE_TIDSET else sup_left.astype(jnp.int32) - pop
+    mask = (sup >= jnp.asarray(min_sup, jnp.int32)).astype(jnp.int32)
+    return sup, mask
 
 
 @functools.partial(
@@ -166,95 +189,17 @@ def fused_intersect_partial_pairs(
 
     The word-sharded counterpart of :func:`fused_intersect_pairs`: it stops
     at the raw popcount (no support conversion, no threshold) because both
-    need the *total* count, which only exists after a cross-shard psum.
+    need the *total* count, which only exists after a cross-shard psum
+    (``repro.core.engine.TidShardedEngine``, DESIGN.md §7).
     """
-    if bitmaps.ndim != 2:
-        raise ValueError(f"expected (P, W) frontier shard, got {bitmaps.shape}")
-    if left.shape != right.shape:
-        raise ValueError("left/right must share a (Q,) shape")
-    qn = left.shape[0]
-    w = bitmaps.shape[1]
-    bw = _resolve_block_w(w, block_w)
-    bitmaps = _pad_words(bitmaps, bw)
-    wp = bitmaps.shape[1]
-
-    idx = jnp.stack([left.astype(jnp.int32), right.astype(jnp.int32)])
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(qn, wp // bw),
-        in_specs=[
-            pl.BlockSpec((1, bw), lambda q, j, idx_ref: (idx_ref[0, q], j)),
-            pl.BlockSpec((1, bw), lambda q, j, idx_ref: (idx_ref[1, q], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bw), lambda q, j, *_: (q, j)),
-            pl.BlockSpec((1,), lambda q, j, *_: (q,)),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, LANE), jnp.int32)],
-    )
-    inter, pop = pl.pallas_call(
-        functools.partial(_kernel_partial, mode=mode),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, wp), jnp.uint32),
-            jax.ShapeDtypeStruct((qn,), jnp.int32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ) if not interpret else None,
-        interpret=interpret,
-    )(idx, bitmaps, bitmaps)
+    inter, pop, w = _popcount_pairs(bitmaps, left, right, mode=mode,
+                                    block_w=block_w, interpret=interpret)
     return inter[:, :w], pop
 
 
-def _fused_pairs_call(bitmaps, left, right, sup_left, min_sup, *, mode,
-                      block_w, interpret):
-    """Shared core of the fused kernel call: validate, lane-pad, launch.
-    Returns the *word-padded* intersection block plus (Q,) supports/mask —
-    the public wrappers slice (plain) or compact (``*_compact``) it."""
-    if bitmaps.ndim != 2:
-        raise ValueError(f"expected (P, W) frontier, got {bitmaps.shape}")
-    if left.shape != right.shape or left.shape != sup_left.shape:
+def _check_sup_left(left, sup_left):
+    if left.shape != sup_left.shape:
         raise ValueError("left/right/sup_left must share a (Q,) shape")
-    qn = left.shape[0]
-    w = bitmaps.shape[1]
-    bw = _resolve_block_w(w, block_w)
-    bitmaps = _pad_words(bitmaps, bw)
-    wp = bitmaps.shape[1]
-
-    idx = jnp.stack([left.astype(jnp.int32), right.astype(jnp.int32)])
-    supl = sup_left.astype(jnp.int32)
-    msup = jnp.asarray(min_sup, jnp.int32).reshape(1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(qn, wp // bw),
-        in_specs=[
-            pl.BlockSpec((1, bw), lambda q, j, idx_ref, supl_ref, msup_ref: (idx_ref[0, q], j)),
-            pl.BlockSpec((1, bw), lambda q, j, idx_ref, supl_ref, msup_ref: (idx_ref[1, q], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bw), lambda q, j, *_: (q, j)),
-            pl.BlockSpec((1,), lambda q, j, *_: (q,)),
-            pl.BlockSpec((1,), lambda q, j, *_: (q,)),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, LANE), jnp.int32)],
-    )
-    inter, sup, mask = pl.pallas_call(
-        functools.partial(_kernel, mode=mode),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, wp), jnp.uint32),
-            jax.ShapeDtypeStruct((qn,), jnp.int32),
-            jax.ShapeDtypeStruct((qn,), jnp.int32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ) if not interpret else None,
-        interpret=interpret,
-    )(idx, supl, msup, bitmaps, bitmaps)
-    return inter, sup, mask, w
 
 
 @functools.partial(
@@ -274,14 +219,15 @@ def fused_intersect_pairs(
     """(P, W) uint32 frontier x (Q,) int32 pair indices ->
     ((Q, W) uint32 intersections, (Q,) int32 supports, (Q,) int32 mask).
 
-    ``min_sup`` is a traced operand (scalar prefetch), so sweeping the
-    threshold does not recompile; only ``mode`` and the block shape do.
-    W need not be a multiple of ``block_w``; the frontier is zero-padded
-    (zero words contribute zero popcount).
+    ``min_sup`` is a traced operand, so sweeping the threshold does not
+    recompile; only ``mode`` and the block shape do.  W need not be a
+    multiple of ``block_w``; the frontier is zero-padded (zero words
+    contribute zero popcount).
     """
-    inter, sup, mask, w = _fused_pairs_call(
-        bitmaps, left, right, sup_left, min_sup,
-        mode=mode, block_w=block_w, interpret=interpret)
+    _check_sup_left(left, sup_left)
+    inter, pop, w = _popcount_pairs(bitmaps, left, right, mode=mode,
+                                    block_w=block_w, interpret=interpret)
+    sup, mask = _support_mask(pop, sup_left, min_sup, mode)
     return inter[:, :w], sup, mask
 
 
@@ -330,8 +276,9 @@ def fused_intersect_compact_pairs(
     never survive.  The engine reads the mask once and slices the compacted
     block to its survivor rung — no second gather dispatch, no index upload
     (DESIGN.md §3)."""
-    inter, sup, mask, w = _fused_pairs_call(
-        bitmaps, left, right, sup_left, min_sup,
-        mode=mode, block_w=block_w, interpret=interpret)
+    _check_sup_left(left, sup_left)
+    inter, pop, w = _popcount_pairs(bitmaps, left, right, mode=mode,
+                                    block_w=block_w, interpret=interpret)
+    sup, mask = _support_mask(pop, sup_left, min_sup, mode)
     compact, sup, mask, n_surv = compact_epilogue(inter, sup, mask, n_valid)
     return compact[:, :w], sup, mask, n_surv

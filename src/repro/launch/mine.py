@@ -16,9 +16,12 @@ import time
 
 from ..core import EclatConfig, generate_rules, mine, resume_mine, top_k_mine
 from ..data import PAPER_DATASETS, generate, load_fimi
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    """Run one mining job; returns its result (an ``EclatResult``, or the
+    ``top_k_mine`` result with ``--top-k``) for callers that check it."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="chess", choices=list(PAPER_DATASETS))
     ap.add_argument("--fimi", default=None, metavar="FILE.dat",
@@ -70,6 +73,7 @@ def main(argv=None):
     ap.add_argument("--min-conf", type=float, default=0.0,
                     help="if >0, also generate association rules")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.fimi:
         txns, n_items = load_fimi(args.fimi)
@@ -104,7 +108,7 @@ def main(argv=None):
         if args.min_conf > 0:
             rules = generate_rules(res.support_map(), args.min_conf)
             print(f"[mine] {len(rules)} rules at conf>={args.min_conf}")
-        return
+        return res
 
     if args.top_k is not None:
         t0 = time.perf_counter()
@@ -116,7 +120,7 @@ def main(argv=None):
               f"abs_min_sup={tk.abs_min_sup}")
         for itemset, sup in tk.itemsets[: min(args.top_k, 10)]:
             print(f"[mine]   {itemset} sup={sup}")
-        return
+        return tk
 
     t0 = time.perf_counter()
     res = mine(txns, n_items, cfg, mesh=mesh)
@@ -131,6 +135,7 @@ def main(argv=None):
     if args.min_conf > 0:
         rules = generate_rules(res.support_map(), args.min_conf)
         print(f"[mine] {len(rules)} rules at conf>={args.min_conf}")
+    return res
 
 
 if __name__ == "__main__":

@@ -30,6 +30,8 @@ import time
 import jax
 import numpy as np
 
+from .compile_cache import enable_compile_cache
+
 
 def serve_lm(args) -> None:
     from ..configs import get_config
@@ -179,6 +181,7 @@ def main(argv=None):
                     help="rebuild the front end from the newest checkpoint "
                          "and answer the storm from the restored window")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.workload == "fim":
         serve_fim(args)
     else:

@@ -34,6 +34,7 @@ from ..faults import InjectedFault, clear_kill_hook, set_kill_hook
 from ..serving import StreamQueryService
 from ..streaming import (StreamCheckpointer, StreamConfig, StreamingMiner,
                          peek_config, restore_miner)
+from .compile_cache import enable_compile_cache
 
 
 def _serve_mode(args, miner, cfg, ck, start):
@@ -122,9 +123,15 @@ def _serve_mode(args, miner, cfg, ck, start):
         raise SystemExit(f"[stream] query errors: {outcome['errors']}")
     if ck is not None:
         print(f"[stream] checkpoints durable in {args.checkpoint_dir}")
+    return {"n_queries": len(queries), "n_answered": m["n_answered"],
+            "n_shed": m["n_shed"], "n_errors": m["n_errors"],
+            "verify": ver, "window_version": frontend.window_version,
+            "kernel_path": miner.engine.kernel_path()}
 
 
 def main(argv=None):
+    """Run the stream; with ``--serve`` returns the storm summary (counts,
+    the ``verify_storm`` record, final window version, kernel path)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="T10I4D100K",
                     choices=list(PAPER_DATASETS))
@@ -201,6 +208,7 @@ def main(argv=None):
                          "disables); a stalled writer is reported, readers "
                          "keep answering from the last published window")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from .mesh import mesh_for_mining
     spec = stream_spec(args.dataset)

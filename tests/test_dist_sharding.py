@@ -203,7 +203,7 @@ def test_constrain_places_on_mesh(host_devices):
 
 
 # ---------------------------------------------------------------------------
-# compat shims
+# compat entry points
 # ---------------------------------------------------------------------------
 
 def test_compat_make_mesh_accepts_axis_types(host_devices):
@@ -220,3 +220,19 @@ def test_compat_shard_map_runs(host_devices):
         lambda v: jax.lax.psum(v, "data"),
         mesh=mesh, in_specs=P("data"), out_specs=P()))
     np.testing.assert_allclose(np.asarray(f(x)), 6.0)
+
+
+def test_compat_shard_map_unchecked_skips_replication_check(host_devices):
+    """An out_spec of P() over a value that differs per shard is refused by
+    the checked shard_map and let through by the unchecked one (the form a
+    pallas_call body needs): each shard keeps its own value, and the
+    result is device 0's."""
+    mesh = compat.make_mesh((4,), ("data",))
+    x = jnp.arange(4.0)
+    body = lambda v: v * 2.0   # noqa: E731
+    with pytest.raises(Exception):
+        jax.jit(compat.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                 out_specs=P()))(x)
+    f = jax.jit(compat.shard_map_unchecked(body, mesh=mesh,
+                                           in_specs=P("data"), out_specs=P()))
+    np.testing.assert_allclose(np.asarray(f(x)), [0.0])

@@ -207,6 +207,66 @@ def test_mine_no_trimatrix_backend_parity():
     assert r_jnp.support_map() == r_pal.support_map() == ORACLE
 
 
+# ---------------------------------------------------------------------------
+# per-call pair cap (kernels.fused_intersect.MAX_PAIRS_PER_CALL)
+# ---------------------------------------------------------------------------
+
+SMALL_CAP = 16
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas", "pallas-kernel",
+                                     "sharded-jnp", "tidsharded-jnp",
+                                     "grid-jnp"])
+@pytest.mark.parametrize("mode", MODES)
+def test_level_above_pair_cap_is_split(backend, mode, monkeypatch):
+    """A level with more pairs than the cap runs as calls of at most the
+    cap, and the concatenated survivors are bit-exact with the oracle."""
+    monkeypatch.setattr(eng, "MAX_PAIRS_PER_CALL", SMALL_CAP)
+    p, w, q = 40, 3, 3 * SMALL_CAP + 5
+    bitmaps, left, right, sup_left, dev = _case(p, w, q, seed=7 + mode)
+    _, all_sup, _ = _oracle(bitmaps, left, right, sup_left, mode, 0)
+    min_sup = int(np.median(all_sup))
+    ref_bm, ref_sup, ref_mask = _oracle(bitmaps, left, right, sup_left, mode,
+                                        min_sup)
+    assert 0 < ref_mask.sum() < q
+    e = _engine(backend)
+    res = e.expand(jnp.asarray(bitmaps), left, right, sup_left,
+                   mode=mode, min_sup=min_sup,
+                   device_of_pair=dev % max(e.n_devices, 1))
+    _check_level(res, ref_bm, ref_sup, ref_mask, w)
+    per_call = [padded // max(e.n_devices, 1) for _, padded in e.level_padding]
+    assert len(per_call) == 4 and max(per_call) <= SMALL_CAP
+
+
+def test_pair_rung_never_exceeds_cap():
+    cap = eng.MAX_PAIRS_PER_CALL
+    assert eng.pair_bucket(cap, 128) == cap
+    assert eng.pair_bucket(cap - 1, 96) == cap     # 96 x 2**k skips the cap
+    assert eng.pair_bucket(1, 4 * cap) == cap      # a floor above the cap
+    assert max(eng.pair_bucket(n, 128) for n in (1, 129, cap // 2 + 1)) <= cap
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas", "tidsharded", "grid"])
+def test_mine_no_trimatrix_above_pair_cap(backend, monkeypatch):
+    """The all-pairs level-2 path (tri-matrix off) with more pairs than the
+    cap is split into capped calls and stays bit-exact with jnp and the
+    oracle."""
+    monkeypatch.setattr(eng, "MAX_PAIRS_PER_CALL", SMALL_CAP)
+    mesh = {"tidsharded": _mesh4, "grid": _grid22}.get(backend)
+    res = mine(DB, 10, EclatConfig(min_sup=25, variant="v4", p=3,
+                                   tri_matrix=False, backend=backend),
+               mesh=mesh() if mesh else None)
+    n1 = res.stats["n_freq_items"]
+    n2 = n1 * (n1 - 1) // 2
+    assert n2 > SMALL_CAP
+    calls = res.stats["pair_padding"]["per_level"]
+    split = [SMALL_CAP] * (n2 // SMALL_CAP) + [n2 % SMALL_CAP] * bool(n2 % SMALL_CAP)
+    assert [c["pairs"] for c in calls[:len(split)]] == split
+    width = res.stats.get("n_class_shards", 1)
+    assert all(c["padded_to"] // width <= SMALL_CAP for c in calls)
+    assert res.support_map() == ORACLE
+
+
 def test_mine_mesh_routes_to_sharded():
     res = mine(DB, 10, EclatConfig(min_sup=25, variant="v4", p=4), mesh=_mesh4())
     assert res.stats["backend"] == "sharded"

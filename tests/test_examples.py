@@ -74,3 +74,25 @@ def test_mine_distributed():
              "--min-sup", "0.35"])
     assert r.returncode == 0, r.stderr[-2000:]
     assert "recovered" in r.stdout
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_drivers_compile_cache_location(env_set, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the only compile cache (the
+    helper sets nothing); unset, the drivers use <repo>/.jax_cache."""
+    import jax
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        if env_set:
+            monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv(compile_cache.ENV, raising=False)
+            want = os.path.join(repo, ".jax_cache")
+            assert compile_cache.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -24,6 +24,7 @@ import os
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import EclatConfig, bruteforce_fim, mine
@@ -215,7 +216,8 @@ FAKE_CELLS = [
 def fake_table(tmp_path):
     path = str(tmp_path / "BENCH_kerneltune.json")
     with open(path, "w") as f:
-        json.dump({"crossover": FAKE_CELLS}, f)
+        json.dump({"jax_backend": jax.default_backend(),
+                   "crossover": FAKE_CELLS}, f)
     return path
 
 
@@ -242,6 +244,46 @@ def test_policy_missing_corrupt_empty(tmp_path):
     junk = tmp_path / "junk.json"
     junk.write_text(json.dumps({"crossover": [{"q": 1}]}))
     assert eng.DispatchPolicy.load(str(junk)) is None
+
+
+def test_policy_skips_table_of_another_platform(tmp_path):
+    """A crossover table measured on another platform, or with none
+    recorded, never steers this one (the committed CPU table on a TPU)."""
+    for backend in ("tpu" if jax.default_backend() != "tpu" else "cpu", None):
+        path = tmp_path / f"{backend}.json"
+        table = {"crossover": FAKE_CELLS}
+        if backend is not None:
+            table["jax_backend"] = backend
+        path.write_text(json.dumps(table))
+        assert eng.DispatchPolicy.load(str(path)) is None
+        e = eng.resolve_engine("auto", policy_path=str(path), hints=(100, 16))
+        assert e.name == "pallas" and e.dispatch["policy"] is None
+
+
+def test_autotune_user_table_needs_opt_in(tmp_path, monkeypatch):
+    """The per-user autotune table is read only after --autotune opts in
+    (or REPRO_AUTOTUNE_CACHE names a table); by default lookup is the
+    cost-model seed, whatever sits in ~/.cache."""
+    monkeypatch.delenv(autotune.CACHE_ENV, raising=False)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    monkeypatch.setattr(autotune, "_USE_USER_CACHE", False)
+    user = autotune.AutotuneTable(
+        os.path.expanduser(os.path.join("~", ".cache", "repro-eclat",
+                                        "autotune.json")))
+    seed = autotune.seeded_candidates(64, 1000, "tpu")[0]
+    planted = 128 if seed != 128 else 256
+    user.put(autotune.shape_class(64, 1000, 0, "tpu"),
+             autotune.KernelConfig(block_w=planted))
+    user.save()
+    autotune.reset()
+    try:
+        assert autotune.table_path() is None
+        assert autotune.lookup(64, 1000, 0, "tpu").block_w == seed
+        eng.make_engine("jnp", autotune=True)         # --autotune opts in
+        assert autotune.table_path() == user.path
+        assert autotune.lookup(64, 1000, 0, "tpu").block_w == planted
+    finally:
+        autotune.reset()
 
 
 def test_policy_env_path(fake_table, monkeypatch):
