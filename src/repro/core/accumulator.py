@@ -103,6 +103,7 @@ def build_vertical_accumulated(
         packed = acc.value()
 
     supports = bm.support_np(packed)
+    n_incidences = int(supports.sum())
     freq_mask = supports >= int(min_sup)
     items = np.nonzero(freq_mask)[0].astype(np.int64)
     packed = packed[freq_mask]
@@ -110,5 +111,5 @@ def build_vertical_accumulated(
     perm = sort_items(items, supports, order)
     return VerticalDB(
         bitmaps=packed[perm], items=items[perm], supports=supports[perm],
-        n_txn=n_txn, order=order,
+        n_txn=n_txn, order=order, n_incidences=n_incidences,
     )

@@ -23,8 +23,8 @@ Variants:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -32,12 +32,17 @@ import jax
 import jax.numpy as jnp
 
 from . import engine as eng
+from .engine import merge_stats
 from .accumulator import build_vertical_accumulated
 from .equivalence import class_segments, pair_work, segment_pairs
 from .itemsets import ItemsetStore, LevelRecord
 from .partitioners import assign_partitions, partition_stats
-from .triangular import cooccurrence_counts, frequent_pairs
+from .triangular import cooc_blocks, cooccurrence_counts, frequent_pairs
 from .vertical import VerticalDB, build_vertical, filter_transactions, filtering_reduction
+from ..spans import span
+
+# the batch miner's phases, as ``mine.<phase>`` spans and ``phase_s`` keys
+_span = functools.partial(span, prefix="mine.")
 
 __all__ = ["EclatConfig", "EclatResult", "mine", "resume_mine",
            "resolve_min_sup", "run_bottom_up", "VARIANTS"]
@@ -128,8 +133,10 @@ class EclatResult:
 
     def support_map(self):
         """The full frequent map (every mode mines the whole lattice —
-        closed/maximal are post-filters over it, see :meth:`workload_map`)."""
-        return self.store.support_map()
+        closed/maximal are post-filters over it, see :meth:`workload_map`).
+        Each call adds its time to ``stats["phase_s"]["support_map"]``."""
+        with _span("support_map", self.stats["phase_s"]):
+            return self.store.support_map()
 
     def workload_map(self):
         """The mode-filtered map this run was configured for: the full
@@ -174,26 +181,30 @@ def run_bottom_up(
         left, right = segment_pairs(starts, sizes)
         if left.size == 0:
             break
-        res = execu.expand(
-            lvl_bitmaps, left.astype(np.int32), right.astype(np.int32),
-            support[left].astype(np.int32),
-            mode=mode, min_sup=abs_min_sup,
-            device_of_pair=part_to_dev[partition[left]],
-        )
         k += 1
-        if not res.mask.any():
-            break
-        sel = np.nonzero(res.mask)[0]
-        parent = left[sel]
-        item_rank = item_rank[right[sel]]
-        class_id = left[sel]
-        partition = partition[left[sel]]
-        support = res.supports
-        store.add_level(LevelRecord(k=k, parent=parent, item_rank=item_rank,
-                                    support=support, partition=partition))
-        lvl_bitmaps = res.bitmaps
-        if on_level is not None:
-            on_level(k, class_id, item_rank, partition, support, lvl_bitmaps)
+        with span("level", prefix=execu.trace_prefix, k=k,
+                  pairs=int(left.size)):
+            res = execu.expand(
+                lvl_bitmaps, left.astype(np.int32), right.astype(np.int32),
+                support[left].astype(np.int32),
+                mode=mode, min_sup=abs_min_sup,
+                device_of_pair=part_to_dev[partition[left]],
+            )
+            if not res.mask.any():
+                break
+            sel = np.nonzero(res.mask)[0]
+            parent = left[sel]
+            item_rank = item_rank[right[sel]]
+            class_id = left[sel]
+            partition = partition[left[sel]]
+            support = res.supports
+            store.add_level(LevelRecord(k=k, parent=parent,
+                                        item_rank=item_rank,
+                                        support=support, partition=partition))
+            lvl_bitmaps = res.bitmaps
+            if on_level is not None:
+                on_level(k, class_id, item_rank, partition, support,
+                         lvl_bitmaps)
 
 
 def _build_db(transactions, n_items, abs_min_sup, spec, mesh) -> Tuple[VerticalDB, dict]:
@@ -213,16 +224,15 @@ def _build_db(transactions, n_items, abs_min_sup, spec, mesh) -> Tuple[VerticalD
 
 
 def _finish(store: ItemsetStore, db: VerticalDB, stats: dict,
-            config: EclatConfig, t_start: float) -> EclatResult:
+            config: EclatConfig) -> EclatResult:
     """Common tail of every ``mine()`` return path: record the workload
     mode (and, for closed/maximal, the post-filtered count — the filter
-    itself is lazy via :meth:`EclatResult.workload_map`) and stamp wall
-    time last so it covers the mode bookkeeping too."""
+    itself is lazy via :meth:`EclatResult.workload_map`).  It runs inside
+    the ``mine`` span, so ``total_s`` covers the mode bookkeeping too."""
     stats["mode"] = config.mode
     res = EclatResult(store=store, db=db, stats=stats, mode=config.mode)
     if config.mode != "all":
         stats["mode_itemsets"] = len(res.workload_map())
-    stats["total_s"] = time.perf_counter() - t_start
     return res
 
 
@@ -233,7 +243,26 @@ def mine(
     mesh: Optional[jax.sharding.Mesh] = None,
 ) -> EclatResult:
     """Mine all frequent itemsets.  ``mesh`` enables the mesh-mapped
-    backends (``config.shard`` picks pair-, word-, or 2D grid-sharding)."""
+    backends (``config.shard`` picks pair-, word-, or 2D grid-sharding).
+
+    ``stats["phase_s"]`` times the phases, each a ``mine.<phase>`` span of
+    the profiler trace inside the call's ``mine`` span, whose length is
+    ``stats["total_s"]``: ``vertical`` (the vertical build), ``plan``
+    (store, partitions, engine, frontier upload), ``tri_matrix`` (level 2,
+    holding ``cooc``, the co-occurrence pass), ``bottom_up`` (levels >= 3,
+    one ``mine.level`` span each) and ``expand_wait`` (the host blocked on
+    the engine's reads, inside the last two).  ``stats["counts"]`` holds
+    ``incidences`` (distinct (transaction, item) bits the vertical build
+    scattered) and ``host_reads`` (blocking device->host reads)."""
+    wall: dict = {}
+    with span("mine", wall, prefix="", n_txn=len(transactions),
+              n_items=int(n_items)):
+        res = _mine(transactions, n_items, config, mesh)
+    res.stats["total_s"] = wall["mine"]
+    return res
+
+
+def _mine(transactions, n_items, config, mesh) -> EclatResult:
     spec = VARIANTS[config.variant]
     if config.use_diffsets and config.variant != "v6":
         # every variant but v6 mines tidsets; silently dropping the flag
@@ -248,119 +277,124 @@ def mine(
     if config.mode not in WORKLOAD_MODES:
         raise ValueError(f"unknown workload mode {config.mode!r}; "
                          f"expected one of {WORKLOAD_MODES}")
-    t_start = time.perf_counter()
-    stats: dict = {"variant": config.variant, "phase_s": {}}
+    stats: dict = {"variant": config.variant, "phase_s": {}, "counts": {}}
+    phase_s = stats["phase_s"]
 
     n_txn = len(transactions)
     abs_min_sup = config.resolve_min_sup(n_txn)
     stats["abs_min_sup"] = abs_min_sup
 
     # ---- Phase 1 (+2 filtering / +3 accumulator): vertical DB -------------
-    t0 = time.perf_counter()
-    db, info = _build_db(transactions, n_items, abs_min_sup, spec, mesh)
+    with _span("vertical", phase_s):
+        db, info = _build_db(transactions, n_items, abs_min_sup, spec, mesh)
     stats.update(info)
-    stats["phase_s"]["vertical"] = time.perf_counter() - t0
+    stats["counts"]["incidences"] = db.n_incidences
     n1, w = db.n_items, db.n_words
     stats["n_freq_items"] = n1
     stats["n_words"] = w
 
-    store = ItemsetStore(db.items)
-    # partition table over 1-length-prefix classes (class rank r, r < n1-1)
-    n_classes = max(n1 - 1, 0)
-    sizes1 = (n1 - 1 - np.arange(n_classes)).clip(min=0)
-    est = pair_work(sizes1 + 1, w)  # +1: member count of class r is n1-1-r
-    eff_p = config.p if spec["partitioner"] in ("hash", "reverse_hash", "greedy") else max(n_classes, 1)
-    table = assign_partitions(n_classes, spec["partitioner"], eff_p, work=est)
-    # dispatch hints for backend="auto": the dominant expansion is level 2
-    # (all cross-class pairs of the n1 frequent items over w words); the
-    # measured crossover table is indexed by exactly that (Q, W) shape
-    est_q2 = n1 * (n1 - 1) // 2
-    execu = eng.resolve_engine(config.backend, mesh,
-                               bucket_min=config.bucket_min,
-                               shard=config.shard,
-                               block_w=config.block_w,
-                               autotune=config.autotune,
-                               compact=config.compact,
-                               hints=(max(est_q2, 1), max(w, 1)))
-    stats["backend"] = execu.name
-    stats["backend_requested"] = config.backend
-    # partition -> device round robin (mesh-mapped backends' pair axis)
-    part_to_dev = np.arange(eff_p, dtype=np.int64) % max(execu.n_devices, 1)
+    with _span("plan", phase_s):
+        store = ItemsetStore(db.items)
+        # partition table over 1-length-prefix classes (class rank r, r < n1-1)
+        n_classes = max(n1 - 1, 0)
+        sizes1 = (n1 - 1 - np.arange(n_classes)).clip(min=0)
+        est = pair_work(sizes1 + 1, w)  # +1: member count of class r is n1-1-r
+        eff_p = config.p if spec["partitioner"] in ("hash", "reverse_hash", "greedy") else max(n_classes, 1)
+        table = assign_partitions(n_classes, spec["partitioner"], eff_p, work=est)
+        # dispatch hints for backend="auto": the dominant expansion is level 2
+        # (all cross-class pairs of the n1 frequent items over w words); the
+        # measured crossover table is indexed by exactly that (Q, W) shape
+        est_q2 = n1 * (n1 - 1) // 2
+        execu = eng.resolve_engine(config.backend, mesh,
+                                   bucket_min=config.bucket_min,
+                                   shard=config.shard,
+                                   block_w=config.block_w,
+                                   autotune=config.autotune,
+                                   compact=config.compact,
+                                   hints=(max(est_q2, 1), max(w, 1)))
+        stats["backend"] = execu.name
+        stats["backend_requested"] = config.backend
+        # partition -> device round robin (mesh-mapped backends' pair axis)
+        part_to_dev = np.arange(eff_p, dtype=np.int64) % max(execu.n_devices, 1)
 
-    # balance of the *estimated* class work that drove partitioning (the
-    # pair_work model the partitioners optimized), not a uniform per-pair
-    # weight — so the reported efficiency reflects the actual assignment.
-    # Recorded up front so every return path (max_k=1, single frequent
-    # item, full run) carries the same stats shape.
-    if n_classes > 0:
-        pstats = partition_stats(table, est, eff_p)
-        stats["partition_balance"] = {
-            **{k_: v for k_, v in pstats.items() if k_ != "loads"},
-            "estimated_loads": pstats["loads"].tolist(),
-        }
+        # balance of the *estimated* class work that drove partitioning (the
+        # pair_work model the partitioners optimized), not a uniform per-pair
+        # weight — so the reported efficiency reflects the actual assignment.
+        # Recorded up front so every return path (max_k=1, single frequent
+        # item, full run) carries the same stats shape.
+        if n_classes > 0:
+            pstats = partition_stats(table, est, eff_p)
+            stats["partition_balance"] = {
+                **{k_: v for k_, v in pstats.items() if k_ != "loads"},
+                "estimated_loads": pstats["loads"].tolist(),
+            }
 
-    lvl1_partition = np.concatenate([table, [table[-1] if n_classes else 0]])[:n1] if n1 else np.zeros(0, np.int64)
-    store.add_level(
-        LevelRecord(
-            k=1,
-            parent=np.full(n1, -1, np.int64),
-            item_rank=np.arange(n1, dtype=np.int64),
-            support=db.supports.astype(np.int64),
-            partition=lvl1_partition,
+        lvl1_partition = np.concatenate([table, [table[-1] if n_classes else 0]])[:n1] if n1 else np.zeros(0, np.int64)
+        store.add_level(
+            LevelRecord(
+                k=1,
+                parent=np.full(n1, -1, np.int64),
+                item_rank=np.arange(n1, dtype=np.int64),
+                support=db.supports.astype(np.int64),
+                partition=lvl1_partition,
+            )
         )
-    )
-    # max_k bounds every level, including 2: with max_k=1 the frequent items
-    # are the whole answer (the regression was recording level 2 regardless)
-    max_k = n1 if config.max_k is None else config.max_k
-    if n1 < 2 or max_k < 2:
-        stats.update(execu.stats())
-        return _finish(store, db, stats, config, t_start)
-
-    # place the level-1 frontier the way the backend carries it, once —
-    # level 2 may expand it in several kernel-sized calls, and per-call
-    # placement (a word-axis reshard for tidsharded) would repeat for each
-    bitmaps = execu.prepare_frontier(jax.device_put(db.bitmaps))
+        # max_k bounds every level, including 2: with max_k=1 the frequent
+        # items are the whole answer (the regression was recording level 2
+        # regardless)
+        max_k = n1 if config.max_k is None else config.max_k
+        deep = n1 >= 2 and max_k >= 2
+        if deep:
+            # place the level-1 frontier the way the backend carries it,
+            # once — level 2 may expand it in several kernel-sized calls, and
+            # per-call placement (a word-axis reshard for tidsharded) would
+            # repeat for each
+            bitmaps = execu.prepare_frontier(jax.device_put(db.bitmaps))
+    if not deep:
+        merge_stats(stats, execu.stats())
+        return _finish(store, db, stats, config)
     diffsets = config.use_diffsets
 
     # ---- Phase 2: triangular matrix (2-itemset counts) --------------------
-    t0 = time.perf_counter()
-    tri = config.tri_matrix
-    if tri is None:
-        tri = n1 <= config.tri_matrix_max_items  # paper's BMS1/BMS2 opt-out
-    stats["tri_matrix"] = bool(tri)
+    with _span("tri_matrix", phase_s):
+        tri = config.tri_matrix
+        if tri is None:
+            tri = n1 <= config.tri_matrix_max_items  # paper's BMS1/BMS2 opt-out
+        stats["tri_matrix"] = bool(tri)
 
-    sup1 = db.supports.astype(np.int32)
-    mode2 = eng.MODE_TID_TO_DIFF if diffsets else eng.MODE_TIDSET
-    if tri:
-        counts2 = cooccurrence_counts(bitmaps)
-        iu, ju, _ = frequent_pairs(counts2, abs_min_sup)
-    else:
-        # all pairs (the paper's no-tri-matrix path for BMS datasets); the
-        # engine splits them into kernel-sized calls
-        iu, ju = np.triu_indices(n1, k=1)
-    res = execu.expand(
-        bitmaps, iu.astype(np.int32), ju.astype(np.int32), sup1[iu],
-        mode=mode2, min_sup=abs_min_sup,
-        device_of_pair=part_to_dev[table[iu]] if iu.size else None,
-    )
-    # with the tri-matrix every pre-filtered pair must pass the engine's
-    # threshold again: the level-2 record aligns iu/ju (all pre-filtered
-    # pairs) with res.supports (survivors only), and a corrupt count matrix
-    # would misalign every deeper level silently.  Same contract as the
-    # streaming miner's cached-count check — a real exception, not an
-    # ``assert``, so it fires under ``python -O``.
-    if tri and iu.size and not res.mask.all():
-        bad = np.nonzero(~res.mask)[0]
-        raise RuntimeError(
-            f"triangular-matrix co-occurrence counts disagree with the "
-            f"engine on {bad.size}/{res.mask.size} level-2 pair(s) "
-            f"(first: item ranks {int(iu[bad[0]])},{int(ju[bad[0]])}) — "
-            f"the tri-matrix pass is corrupt")
-    iu = iu[res.mask].astype(np.int64)
-    ju = ju[res.mask].astype(np.int64)
-    sup2 = res.supports.astype(np.int32)
-    lvl_bitmaps = res.bitmaps
-    stats["phase_s"]["tri_matrix"] = time.perf_counter() - t0
+        sup1 = db.supports.astype(np.int32)
+        mode2 = eng.MODE_TID_TO_DIFF if diffsets else eng.MODE_TIDSET
+        if tri:
+            with _span("cooc", phase_s):
+                counts2 = cooccurrence_counts(bitmaps)
+            stats["counts"]["host_reads"] = cooc_blocks(n1)
+            iu, ju, _ = frequent_pairs(counts2, abs_min_sup)
+        else:
+            # all pairs (the paper's no-tri-matrix path for BMS datasets); the
+            # engine splits them into kernel-sized calls
+            iu, ju = np.triu_indices(n1, k=1)
+        res = execu.expand(
+            bitmaps, iu.astype(np.int32), ju.astype(np.int32), sup1[iu],
+            mode=mode2, min_sup=abs_min_sup,
+            device_of_pair=part_to_dev[table[iu]] if iu.size else None,
+        )
+        # with the tri-matrix every pre-filtered pair must pass the engine's
+        # threshold again: the level-2 record aligns iu/ju (all pre-filtered
+        # pairs) with res.supports (survivors only), and a corrupt count matrix
+        # would misalign every deeper level silently.  Same contract as the
+        # streaming miner's cached-count check — a real exception, not an
+        # ``assert``, so it fires under ``python -O``.
+        if tri and iu.size and not res.mask.all():
+            bad = np.nonzero(~res.mask)[0]
+            raise RuntimeError(
+                f"triangular-matrix co-occurrence counts disagree with the "
+                f"engine on {bad.size}/{res.mask.size} level-2 pair(s) "
+                f"(first: item ranks {int(iu[bad[0]])},{int(ju[bad[0]])}) — "
+                f"the tri-matrix pass is corrupt")
+        iu = iu[res.mask].astype(np.int64)
+        ju = ju[res.mask].astype(np.int64)
+        sup2 = res.supports.astype(np.int32)
+        lvl_bitmaps = res.bitmaps
 
     parent = iu.copy()
     item_rank = ju.copy()
@@ -371,33 +405,37 @@ def mine(
                                 support=support, partition=partition))
 
     # ---- Phase 3/4: level-wise Bottom-Up -----------------------------------
-    t0 = time.perf_counter()
-    mode_k = eng.MODE_DIFFSET if diffsets else eng.MODE_TIDSET
+    with _span("bottom_up", phase_s):
+        mode_k = eng.MODE_DIFFSET if diffsets else eng.MODE_TIDSET
+        on_level = None
+        if config.checkpoint_dir and config.checkpoint_every_level:
+            # resume metadata: everything resume_mine needs that is not
+            # derivable from the frontier arrays themselves (DESIGN.md §10)
+            on_level = _level_checkpointer(
+                config.checkpoint_dir, store,
+                {"abs_min_sup": int(abs_min_sup), "engine_mode": int(mode_k),
+                 "max_k": int(max_k), "eff_p": int(eff_p),
+                 "use_diffsets": bool(diffsets)})
+        run_bottom_up(execu, store, lvl_bitmaps, class_id, item_rank,
+                      partition, support, abs_min_sup=abs_min_sup,
+                      mode=mode_k, max_k=max_k, part_to_dev=part_to_dev,
+                      on_level=on_level)
 
-    on_level = None
-    if config.checkpoint_dir and config.checkpoint_every_level:
-        from .lineage import save_mining_checkpoint
-        # resume metadata: everything resume_mine needs that is not derivable
-        # from the frontier arrays themselves (DESIGN.md §10)
-        ckpt_meta = {"abs_min_sup": int(abs_min_sup), "engine_mode": int(mode_k),
-                     "max_k": int(max_k), "eff_p": int(eff_p),
-                     "use_diffsets": bool(diffsets)}
+    merge_stats(stats, execu.stats())
+    return _finish(store, db, stats, config)
 
-        def on_level(k, class_id, item_rank, partition, support, lvl_bitmaps):
-            # slice the rung padding off on device before the host transfer
-            save_mining_checkpoint(config.checkpoint_dir, store, k, class_id,
-                                   item_rank, partition, support,
-                                   jax.device_get(lvl_bitmaps[: support.shape[0]]),
-                                   meta=ckpt_meta)
 
-    run_bottom_up(execu, store, lvl_bitmaps, class_id, item_rank, partition,
-                  support, abs_min_sup=abs_min_sup, mode=mode_k,
-                  max_k=max_k, part_to_dev=part_to_dev,
-                  on_level=on_level)
-    stats["phase_s"]["bottom_up"] = time.perf_counter() - t0
+def _level_checkpointer(ckpt_dir: str, store: ItemsetStore, meta: dict):
+    """``run_bottom_up``'s ``on_level``: a resumable checkpoint per level."""
+    from .lineage import save_mining_checkpoint
 
-    stats.update(execu.stats())
-    return _finish(store, db, stats, config, t_start)
+    def on_level(k, class_id, item_rank, partition, support, lvl_bitmaps):
+        # slice the rung padding off on device before the host transfer
+        save_mining_checkpoint(ckpt_dir, store, k, class_id, item_rank,
+                               partition, support,
+                               jax.device_get(lvl_bitmaps[: support.shape[0]]),
+                               meta=meta)
+    return on_level
 
 
 def resume_mine(
@@ -417,12 +455,18 @@ def resume_mine(
     backend is bit-exact on the same frontier.  The original transactions
     are not needed; ``EclatResult.db`` is ``None`` on a resumed run.
     """
-    from .lineage import (latest_mining_checkpoint, load_mining_checkpoint,
-                          save_mining_checkpoint)
+    wall: dict = {}
+    with span("mine", wall, prefix="", resumed=1):
+        res = _resume_mine(config, mesh)
+    res.stats["total_s"] = wall["mine"]
+    return res
+
+
+def _resume_mine(config: EclatConfig, mesh) -> EclatResult:
+    from .lineage import latest_mining_checkpoint, load_mining_checkpoint
 
     if not config.checkpoint_dir:
         raise ValueError("resume_mine needs config.checkpoint_dir")
-    t_start = time.perf_counter()
     path = latest_mining_checkpoint(config.checkpoint_dir)
     store, fr = load_mining_checkpoint(path)
     meta = fr.get("meta") or {}
@@ -449,22 +493,16 @@ def resume_mine(
     part_to_dev = np.arange(eff_p, dtype=np.int64) % max(execu.n_devices, 1)
     lvl_bitmaps = execu.prepare_frontier(jax.device_put(fr["bitmaps"]))
 
-    on_level = None
-    if config.checkpoint_every_level:
-        def on_level(k, class_id, item_rank, partition, support, lvl_bitmaps):
-            save_mining_checkpoint(config.checkpoint_dir, store, k, class_id,
-                                   item_rank, partition, support,
-                                   jax.device_get(lvl_bitmaps[: support.shape[0]]),
-                                   meta=meta)
+    on_level = (_level_checkpointer(config.checkpoint_dir, store, meta)
+                if config.checkpoint_every_level else None)
 
-    t0 = time.perf_counter()
-    run_bottom_up(execu, store, lvl_bitmaps,
-                  class_id=np.asarray(fr["class_id"]),
-                  item_rank=np.asarray(fr["item_rank"]),
-                  partition=np.asarray(fr["partition"]),
-                  support=np.asarray(fr["support"]).astype(np.int64),
-                  abs_min_sup=abs_min_sup, mode=mode_k, max_k=max_k,
-                  part_to_dev=part_to_dev, on_level=on_level)
-    stats["phase_s"]["bottom_up"] = time.perf_counter() - t0
-    stats.update(execu.stats())
-    return _finish(store, None, stats, config, t_start)
+    with _span("bottom_up", stats["phase_s"]):
+        run_bottom_up(execu, store, lvl_bitmaps,
+                      class_id=np.asarray(fr["class_id"]),
+                      item_rank=np.asarray(fr["item_rank"]),
+                      partition=np.asarray(fr["partition"]),
+                      support=np.asarray(fr["support"]).astype(np.int64),
+                      abs_min_sup=abs_min_sup, mode=mode_k, max_k=max_k,
+                      part_to_dev=part_to_dev, on_level=on_level)
+    merge_stats(stats, execu.stats())
+    return _finish(store, None, stats, config)

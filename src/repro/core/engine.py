@@ -85,13 +85,14 @@ from ..kernels.fused_intersect import (MAX_PAIRS_PER_CALL, MODE_DIFFSET,
                                        fused_intersect_partial,
                                        fused_intersect_partial_ref,
                                        fused_intersect_ref, kernel_path)
+from ..spans import span
 
 __all__ = [
     "MODE_TIDSET", "MODE_TID_TO_DIFF", "MODE_DIFFSET",
     "LevelResult", "Engine", "EngineState", "JnpEngine", "PallasEngine",
     "ShardedEngine", "TidShardedEngine", "GridShardedEngine",
     "group_pairs_by_device", "register_backend", "available_backends",
-    "make_engine", "engine_from_state", "resolve_engine",
+    "make_engine", "engine_from_state", "resolve_engine", "merge_stats",
     "DispatchPolicy", "KERNELTUNE_ENV", "MAX_PAIRS_PER_CALL",
 ]
 
@@ -572,6 +573,17 @@ def resolve_engine(
     return engine
 
 
+def merge_stats(stats: dict, engine_stats: dict) -> dict:
+    """``stats.update(engine_stats)``, except that the engine's ``phase_s``
+    and ``counts`` sub-dicts are added into the miner's own, key by key."""
+    for key in ("phase_s", "counts"):
+        for k, v in engine_stats.pop(key, {}).items():
+            sub = stats.setdefault(key, {})
+            sub[k] = sub.get(k, 0) + v
+    stats.update(engine_stats)
+    return stats
+
+
 class Engine:
     """Backend interface + shared accounting.
 
@@ -616,11 +628,25 @@ class Engine:
         self.level_padding: List[Tuple[int, int]] = []
         self.call_shapes: set = set()
         self.n_devices = 1
+        # host time blocked on expansions (``expand_wait``) and the blocking
+        # reads behind it: timings of this process, not engine state
+        self.phase_s: Dict[str, float] = {}
+        self.n_reads = 0
+        # trace-name prefix of the miner that owns this engine
+        self.trace_prefix = "mine."
 
     def _record_padding(self, q: int, padded: int) -> None:
         """Per-level pair-padding ledger behind ``stats()['pair_padding']``."""
         self.n_padded += padded - q
         self.level_padding.append((int(q), int(padded)))
+
+    def _read(self, *arrays) -> tuple:
+        """An expansion's one blocking device->host read, of all its
+        ``arrays`` at once: the host waiting on the kernel, timed as
+        ``expand_wait`` and counted in ``stats()["counts"]["host_reads"]``."""
+        self.n_reads += 1
+        with span("expand_wait", self.phase_s, prefix=self.trace_prefix):
+            return jax.device_get(arrays)
 
     def _maybe_tune(self, q: int, w: int, mode: int) -> None:
         """Tune-on-miss: warm the autotune table for this call shape so the
@@ -769,21 +795,29 @@ class Engine:
         self.device_pair_counts = dpc
         return self
 
-    def snapshot(self) -> Tuple[int, int, int, int]:
+    def snapshot(self) -> Tuple[int, int, int, int, int, float]:
         """Counter snapshot, for per-call deltas on a long-lived engine
         (``stats(since=snapshot)`` — the streaming miner reports per-slide
         work, not lifetime totals)."""
         return (self.n_intersections, self.n_padded,
-                len(self.device_pair_counts), len(self.level_padding))
+                len(self.device_pair_counts), len(self.level_padding),
+                self.n_reads, self.phase_s.get("expand_wait", 0.0))
 
-    def stats(self, since: Optional[Tuple[int, ...]] = None) -> dict:
-        i0, p0, d0, l0 = (tuple(since) + (0,) * 4)[:4] if since else (0,) * 4
+    def stats(self, since: Optional[Tuple] = None) -> dict:
+        """Counters since ``since`` (or ever).  ``phase_s`` and ``counts``
+        are sub-dicts a miner merges into its own (:func:`merge_stats`)."""
+        i0, p0, d0, l0, r0, w0 = ((tuple(since) + (0,) * 6)[:6] if since
+                                  else (0,) * 6)
         out = {
             "backend": self.name,
             "kernel_path": self.kernel_path(),
             "n_intersections": self.n_intersections - i0,
             "n_padded": self.n_padded - p0,
         }
+        if self.n_reads > r0:
+            out["phase_s"] = {
+                "expand_wait": self.phase_s.get("expand_wait", 0.0) - w0}
+            out["counts"] = {"host_reads": self.n_reads - r0}
         if self.call_shapes:
             out["call_shapes"] = sorted(list(c) for c in self.call_shapes)
         levels = self.level_padding[l0:]
@@ -846,15 +880,17 @@ class JnpEngine(Engine):
             out, sup, mask_dev, n_surv = fused_intersect_compact_ref(
                 bitmaps, _dput(l), _dput(r), _dput(s),
                 _dput_i32(min_sup), _dput_i32(q), mode=mode)
-            mask = jax.device_get(mask_dev)[:q].astype(bool)
-            sup_np = jax.device_get(sup)[:q]
+            mask_h, sup_h = self._read(mask_dev, sup)
+            mask = mask_h[:q].astype(bool)
+            sup_np = sup_h[:q]
             return LevelResult(mask=mask,
                                supports=sup_np[mask].astype(np.int64),
                                bitmaps=self._slice_survivors(out, int(mask.sum())))
         out, sup, _ = fused_intersect_ref(
             bitmaps, _dput(l), _dput(r), _dput(s),
             _dput_i32(min_sup), mode=mode)
-        sup_np = jax.device_get(sup)[:q]
+        sup_h, = self._read(sup)
+        sup_np = sup_h[:q]
         mask = sup_np >= min_sup
         sel = np.nonzero(mask)[0]
         return LevelResult(mask=mask,
@@ -894,8 +930,9 @@ class PallasEngine(Engine):
                 bitmaps, _dput(l), _dput(r), _dput(s),
                 _dput_i32(min_sup), _dput_i32(q), mode=mode,
                 block_w=self.block_w, interpret=self.interpret)
-            mask = jax.device_get(mask_dev)[:q].astype(bool)
-            sup_np = jax.device_get(sup)[:q]
+            mask_h, sup_h = self._read(mask_dev, sup)
+            mask = mask_h[:q].astype(bool)
+            sup_np = sup_h[:q]
             return LevelResult(mask=mask,
                                supports=sup_np[mask].astype(np.int64),
                                bitmaps=self._slice_survivors(inter, int(mask.sum())))
@@ -903,8 +940,9 @@ class PallasEngine(Engine):
             bitmaps, _dput(l), _dput(r), _dput(s),
             _dput_i32(min_sup), mode=mode, block_w=self.block_w,
             interpret=self.interpret)
-        mask = jax.device_get(mask_dev)[:q].astype(bool)
-        sup_np = jax.device_get(sup)[:q]
+        mask_h, sup_h = self._read(mask_dev, sup)
+        mask = mask_h[:q].astype(bool)
+        sup_np = sup_h[:q]
         sel = np.nonzero(mask)[0]
         return LevelResult(mask=mask,
                            supports=sup_np[sel].astype(np.int64),
@@ -985,7 +1023,8 @@ class ShardedEngine(Engine):
             _dput(spad.reshape(d * qmax), self._pair_sharding),
             _dput_i32(min_sup, self._rep_sharding),
         )
-        sup_np = jax.device_get(sup).reshape(-1)[slot_of_pair]
+        sup_h, = self._read(sup)
+        sup_np = sup_h.reshape(-1)[slot_of_pair]
         mask = sup_np >= min_sup
         sel = np.nonzero(mask)[0]
         surv = self._compact(out.reshape(d * qmax, -1),
@@ -1177,8 +1216,9 @@ class TidShardedEngine(_WordShardedFrontierMixin, Engine):
             inter, sup, mask_dev, _ = self._sharded[mode](
                 bitmaps, _dput(l, rep), _dput(r, rep), _dput(s, rep),
                 _dput_i32(min_sup, rep), _dput_i32(q, rep))
-            mask = jax.device_get(mask_dev)[:q].astype(bool)
-            sup_np = jax.device_get(sup)[:q]
+            mask_h, sup_h = self._read(mask_dev, sup)
+            mask = mask_h[:q].astype(bool)
+            sup_np = sup_h[:q]
             surv = jax.device_put(
                 self._slice_survivors(inter, int(mask.sum())), self._sharding)
             return LevelResult(mask=mask,
@@ -1188,8 +1228,9 @@ class TidShardedEngine(_WordShardedFrontierMixin, Engine):
         inter, sup, mask_dev = self._sharded[mode](
             bitmaps, _dput(l, rep), _dput(r, rep), _dput(s, rep),
             _dput_i32(min_sup, rep))
-        mask = jax.device_get(mask_dev)[:q].astype(bool)
-        sup_np = jax.device_get(sup)[:q]
+        mask_h, sup_h = self._read(mask_dev, sup)
+        mask = mask_h[:q].astype(bool)
+        sup_np = sup_h[:q]
         sel = np.nonzero(mask)[0]
         return LevelResult(mask=mask,
                            supports=sup_np[sel].astype(np.int64),
@@ -1283,8 +1324,9 @@ class GridShardedEngine(_WordShardedFrontierMixin, Engine):
             _dput(spad.reshape(d * qmax), self._pair_vec_sharding),
             _dput_i32(min_sup, self._rep_sharding),
         )
-        sup_np = jax.device_get(sup).reshape(-1)[slot_of_pair]
-        mask = jax.device_get(mask_dev).reshape(-1)[slot_of_pair].astype(bool)
+        sup_h, mask_h = self._read(sup, mask_dev)
+        sup_np = sup_h.reshape(-1)[slot_of_pair]
+        mask = mask_h.reshape(-1)[slot_of_pair].astype(bool)
         sel = np.nonzero(mask)[0]
         surv = self._compact(inter, slot_of_pair[sel].astype(np.int32))
         return LevelResult(mask=mask,

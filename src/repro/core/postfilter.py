@@ -123,7 +123,7 @@ class TopKResult:
     itemsets: List[Tuple[Itemset, int]]   # exactly k, or all if fewer exist
     k: int
     abs_min_sup: int                      # the rung the answer was read at
-    ladder: List[dict]                    # per rung: abs_min_sup, n_found
+    ladder: List[dict]                    # per rung: abs_min_sup, n_found, total_s
     stats: dict
 
 
@@ -179,7 +179,8 @@ def top_k_mine(
         res = mine(transactions, n_items, cfg, mesh=mesh)
         found = [(s, v) for s, v in res.support_map().items()
                  if len(s) >= min_len]
-        ladder.append({"abs_min_sup": int(abs_ms), "n_found": len(found)})
+        ladder.append({"abs_min_sup": int(abs_ms), "n_found": len(found),
+                       "total_s": res.stats["total_s"]})
         if len(found) >= k or abs_ms <= 1:
             break
         abs_ms = max(1, abs_ms // 2)

@@ -17,15 +17,16 @@ import jax
 import jax.numpy as jnp
 from functools import partial
 
-__all__ = ["cooccurrence_counts", "frequent_pairs"]
+__all__ = ["cooc_blocks", "cooccurrence_counts", "frequent_pairs"]
 
 
 @partial(jax.jit, static_argnames=("block",))
 def _cooc_block(bitmaps: jax.Array, row_start: jax.Array, block: int) -> jax.Array:
     """Counts for rows [row_start, row_start+block) against all rows."""
-    rows = jax.lax.dynamic_slice_in_dim(bitmaps, row_start, block, axis=0)
-    inter = jnp.bitwise_and(rows[:, None, :], bitmaps[None, :, :])
-    return jax.lax.population_count(inter).astype(jnp.int32).sum(-1)
+    with jax.named_scope("level2_cooc"):
+        rows = jax.lax.dynamic_slice_in_dim(bitmaps, row_start, block, axis=0)
+        inter = jnp.bitwise_and(rows[:, None, :], bitmaps[None, :, :])
+        return jax.lax.population_count(inter).astype(jnp.int32).sum(-1)
 
 
 @partial(jax.jit, static_argnames=("pad",))
@@ -34,6 +35,18 @@ def _pad_rows(bitmaps: jax.Array, pad: int) -> jax.Array:
     # call site would dispatch it as an implicit host scalar, tripping the
     # steady-state transfer guard (staticcheck SH002)
     return jnp.pad(bitmaps, ((0, pad), (0, 0)))
+
+
+def cooc_blocks(n: int, block: int = 64) -> int:
+    """Row blocks of :func:`cooccurrence_counts` over ``n`` rows, one
+    blocking device->host read each: ``n`` padded to ``block`` times a
+    power of two (so nearby ``n`` reuse one compiled block kernel)."""
+    if n == 0:
+        return 0
+    blocks = 1
+    while blocks * block < n:
+        blocks <<= 1
+    return blocks
 
 
 def cooccurrence_counts(bitmaps, block: int = 64) -> np.ndarray:
@@ -46,12 +59,7 @@ def cooccurrence_counts(bitmaps, block: int = 64) -> np.ndarray:
     n = bitmaps.shape[0]
     if n == 0:
         return np.zeros((0, 0), np.int32)
-    # bucket-pad rows (power of two) so repeated calls with nearby n reuse
-    # the same compiled block kernel
-    target = block
-    while target < n:
-        target <<= 1
-    pad = target - n
+    pad = cooc_blocks(n, block) * block - n
     bitmaps_p = _pad_rows(bitmaps, pad) if pad else bitmaps
     out = []
     for s in range(0, n + pad, block):

@@ -33,6 +33,8 @@ class VerticalDB:
       supports:  (n_freq,) int64 item supports.
       n_txn:     number of (possibly compacted) transaction columns.
       order:     how ``items`` rows are sorted ("support_asc" | "lex").
+      n_incidences: distinct (transaction, item) bits the build scattered,
+                 infrequent items included.
     """
 
     bitmaps: np.ndarray
@@ -40,6 +42,7 @@ class VerticalDB:
     supports: np.ndarray
     n_txn: int
     order: str = "support_asc"
+    n_incidences: int = 0
 
     @property
     def n_items(self) -> int:
@@ -85,6 +88,7 @@ def build_vertical(
     """EclatV1 Phase-1: horizontal -> packed vertical DB of frequent items."""
     packed = bm.pack_transactions(transactions, n_items)
     supports = bm.support_np(packed)
+    n_incidences = int(supports.sum())
     freq_mask = supports >= int(min_sup)
     items = np.nonzero(freq_mask)[0].astype(np.int64)
     packed = packed[freq_mask]
@@ -96,6 +100,7 @@ def build_vertical(
         supports=supports[perm],
         n_txn=len(transactions),
         order=order,
+        n_incidences=n_incidences,
     )
 
 
@@ -124,6 +129,7 @@ def filter_transactions(db: VerticalDB, drop_empty_cols: bool = True) -> Vertica
         supports=db.supports,
         n_txn=kept,
         order=db.order,
+        n_incidences=db.n_incidences,
     )
 
 

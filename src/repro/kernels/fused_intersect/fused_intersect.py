@@ -160,6 +160,7 @@ def _popcount_pairs(bitmaps, left, right, *, mode, block_w, interpret):
             dimension_semantics=("arbitrary", "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,
+        name="fused_intersect",
     )(idx, rows, rows)
     return inter.reshape(qn, wp), pop.reshape(nqb * LANE)[:qn], w
 

@@ -11,8 +11,10 @@ et al.) instead of a synthetic paper dataset.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
-import time
+
+import jax
 
 from ..core import EclatConfig, generate_rules, mine, resume_mine, top_k_mine
 from ..data import PAPER_DATASETS, generate, load_fimi
@@ -72,6 +74,10 @@ def main(argv=None):
                          "(live re-meshing, DESIGN.md §10)")
     ap.add_argument("--min-conf", type=float, default=0.0,
                     help="if >0, also generate association rules")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="write a profiler trace of the mine to DIR: the "
+                         "mine.* phase spans beside the device ops, for "
+                         "TensorBoard or Perfetto")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
@@ -96,46 +102,61 @@ def main(argv=None):
     from .mesh import mesh_for_mining
     mesh = mesh_for_mining(args.backend, args.shard, args.grid)
 
+    if args.restore and not args.checkpoint_dir:
+        ap.error("--restore requires --checkpoint-dir")
+    if args.profile:
+        profile = jax.profiler.trace(args.profile)
+    else:
+        profile = contextlib.nullcontext()
+    with profile:
+        return _run(args, cfg, mesh, txns, n_items, name + scale_note)
+
+
+def _run(args, cfg, mesh, txns, n_items, name):
     if args.restore:
-        if not args.checkpoint_dir:
-            ap.error("--restore requires --checkpoint-dir")
-        t0 = time.perf_counter()
         res = resume_mine(cfg, mesh=mesh)
-        dt = time.perf_counter() - t0
         print(f"[mine] resumed {res.stats['resumed_from']} at level "
               f"{res.stats['resume_k']} ({res.stats['backend']}): "
-              f"{res.total} itemsets in {dt:.2f}s levels={res.counts}")
+              f"{res.total} itemsets in {res.stats['total_s']:.2f}s "
+              f"levels={res.counts}")
         if args.min_conf > 0:
             rules = generate_rules(res.support_map(), args.min_conf)
             print(f"[mine] {len(rules)} rules at conf>={args.min_conf}")
+        _print_phases(res.stats)
         return res
 
     if args.top_k is not None:
-        t0 = time.perf_counter()
         tk = top_k_mine(txns, n_items, args.top_k, config=cfg, mesh=mesh)
-        dt = time.perf_counter() - t0
-        print(f"[mine] {name}{scale_note} top-{args.top_k} "
-              f"({len(tk.itemsets)} returned) in {dt:.2f}s: ladder "
+        mined_s = sum(r["total_s"] for r in tk.ladder)
+        print(f"[mine] {name} top-{args.top_k} "
+              f"({len(tk.itemsets)} returned) in {mined_s:.2f}s: ladder "
               f"{[r['abs_min_sup'] for r in tk.ladder]} -> "
               f"abs_min_sup={tk.abs_min_sup}")
         for itemset, sup in tk.itemsets[: min(args.top_k, 10)]:
             print(f"[mine]   {itemset} sup={sup}")
         return tk
 
-    t0 = time.perf_counter()
     res = mine(txns, n_items, cfg, mesh=mesh)
-    dt = time.perf_counter() - t0
     grid_note = (f" grid={mesh.shape['class']}x{mesh.shape['data']}"
                  if mesh is not None and "class" in mesh.axis_names else "")
     mode_note = (f" {args.mode}={res.stats['mode_itemsets']}"
                  if args.mode != "all" else "")
-    print(f"[mine] {name}{scale_note} min_sup={args.min_sup} "
-          f"{args.variant}: {res.total} itemsets in {dt:.2f}s "
+    print(f"[mine] {name} min_sup={args.min_sup} "
+          f"{args.variant}: {res.total} itemsets in "
+          f"{res.stats['total_s']:.2f}s "
           f"levels={res.counts}{grid_note}{mode_note}")
     if args.min_conf > 0:
         rules = generate_rules(res.support_map(), args.min_conf)
         print(f"[mine] {len(rules)} rules at conf>={args.min_conf}")
+    _print_phases(res.stats)
     return res
+
+
+def _print_phases(stats: dict) -> None:
+    """One line: seconds per phase (``phase_s``) and the ``counts``."""
+    phases = " ".join(f"{k}={v:.4f}s" for k, v in stats["phase_s"].items())
+    counts = " ".join(f"{k}={v}" for k, v in stats.get("counts", {}).items())
+    print(f"[mine] phases: {phases} | counts: {counts}")
 
 
 if __name__ == "__main__":

@@ -41,7 +41,8 @@ __all__ = ["Rule", "RULES", "HOT_PATHS", "rule_ids", "LintContext",
 # transfer guards; RS005 keeps them statically free of implicit conversions.
 HOT_PATHS: Dict[str, Union[str, Set[str]]] = {
     "repro/streaming/window.py": {"push"},
-    "repro/streaming/miner.py": {"push", "mine_window", "advance"},
+    "repro/streaming/miner.py": {"push", "mine_window", "_mine_window",
+                                 "advance"},
     "repro/core/engine.py": {"expand", "_compact", "_take"},
     "repro/core/triangular.py": {"cooccurrence_counts"},
     "repro/core/eclat.py": {"run_bottom_up"},

@@ -29,7 +29,7 @@ backends to it).
 from __future__ import annotations
 
 import dataclasses
-import time
+import functools
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -38,13 +38,18 @@ import jax.numpy as jnp
 
 from ..core import engine as eng
 from ..core.eclat import resolve_min_sup, run_bottom_up
+from ..core.engine import merge_stats
 from ..core.equivalence import pair_work
 from ..core.itemsets import ItemsetStore, LevelRecord, generate_rules
 from ..core.partitioners import assign_partitions
 from ..core.triangular import cooccurrence_counts, frequent_pairs
 from ..core.vertical import sort_items
 from ..faults import kill_point
+from ..spans import span
 from .window import RingState, WindowRing
+
+# the streaming miner's steps, as ``slide.<step>`` spans
+_span = functools.partial(span, prefix="slide.")
 
 __all__ = ["StreamConfig", "WindowResult", "StreamingMiner", "MinerState"]
 
@@ -190,6 +195,7 @@ class StreamingMiner:
                                          autotune=config.autotune,
                                          compact=config.compact,
                                          hints=(est_q, est_w))
+        self.engine.trace_prefix = "slide."
         self._prev_frequent: Optional[np.ndarray] = None
         # monotonic window-content stamp: bumped once per completed push();
         # mine_window() stamps its result with the current value, so equal
@@ -206,26 +212,29 @@ class StreamingMiner:
 
     def push(self, batch: Sequence[Sequence[int]]) -> dict:
         """Admit one micro-batch; update ring + counts by block deltas."""
-        t0 = time.perf_counter()
-        new_block, old_block, n_evicted = self.ring.push(batch)
-        # ring written, count matrix not yet — the torn state recovery must
-        # handle (tests/faultinject.py kills here)
-        kill_point("miner:mid_append")
-        # popcount is additive over word blocks, so the count matrix follows
-        # the ring exactly: add the admitted block, subtract the evicted one.
-        self.cooc += cooccurrence_counts(
-            jax.device_put(new_block)).astype(np.int64)
-        # admitted block counted, evicted block not yet subtracted
-        kill_point("miner:mid_evict")
-        if n_evicted or old_block.any():
-            self.cooc -= cooccurrence_counts(
-                jax.device_put(old_block)).astype(np.int64)
-        # the window's contents changed: new version.  Bumped only after the
-        # ring AND the count matrix agree, so a crash between the kill points
-        # above never publishes a version for a half-applied slide.
-        self.window_version += 1
+        took: Dict[str, float] = {}
+        with _span("push", took, n_txn=len(batch)):
+            new_block, old_block, n_evicted = self.ring.push(batch)
+            # ring written, count matrix not yet — the torn state recovery
+            # must handle (tests/faultinject.py kills here)
+            kill_point("miner:mid_append")
+            # popcount is additive over word blocks, so the count matrix
+            # follows the ring exactly: add the admitted block, subtract the
+            # evicted one.
+            self.cooc += cooccurrence_counts(
+                jax.device_put(new_block)).astype(np.int64)
+            # admitted block counted, evicted block not yet subtracted
+            kill_point("miner:mid_evict")
+            if n_evicted or old_block.any():
+                self.cooc -= cooccurrence_counts(
+                    jax.device_put(old_block)).astype(np.int64)
+            # the window's contents changed: new version.  Bumped only after
+            # the ring AND the count matrix agree, so a crash between the
+            # kill points above never publishes a version for a half-applied
+            # slide.
+            self.window_version += 1
         return {
-            "push_s": time.perf_counter() - t0,
+            "push_s": took["push"],
             "n_admitted": len(batch),
             "n_evicted": n_evicted,
         }
@@ -240,12 +249,23 @@ class StreamingMiner:
         frequent pair do device work, through ``engine.expand`` (so the jnp /
         pallas / sharded backends are interchangeable here exactly as in
         batch ``mine()``).
+
+        The whole call is the ``slide.mine_window`` span
+        (``stats["total_s"]``);
+        ``stats["phase_s"]`` times ``level2``, ``bottom_up`` and the
+        engine's ``expand_wait`` within it, each a ``slide.<key>`` span.
         """
+        wall: Dict[str, float] = {}
+        with _span("mine_window", wall, version=int(self.window_version)):
+            result = self._mine_window()
+        result.stats["total_s"] = wall["mine_window"]
+        return result
+
+    def _mine_window(self) -> WindowResult:
         cfg = self.config
         if cfg.max_k is not None and cfg.max_k < 1:
             raise ValueError(f"max_k must be >= 1 (or None for unbounded), "
                              f"got {cfg.max_k}")
-        t_start = time.perf_counter()
         engine_snap = self.engine.snapshot()
         n_txn = self.ring.n_txn
         abs_min_sup = cfg.resolve_min_sup(n_txn)
@@ -297,59 +317,56 @@ class StreamingMiner:
         # driver (the regression was expanding level 2 regardless of max_k)
         max_k = n1 if cfg.max_k is None else cfg.max_k
         if n1 < 2 or max_k < 2:
-            stats.update(self.engine.stats(since=engine_snap))
-            stats["total_s"] = time.perf_counter() - t_start
+            merge_stats(stats, self.engine.stats(since=engine_snap))
             return WindowResult(store=store, n_txn=n_txn, stats=stats,
                                 version=self.window_version)
 
         # ---- level 2: straight from the cached count matrix ----------------
-        t0 = time.perf_counter()
-        csub = self.cooc[np.ix_(items, items)]
-        iu, ju, c2 = frequent_pairs(csub, abs_min_sup)
-        if iu.size:
-            res = self.engine.expand(
-                self.ring.device,
-                items[iu].astype(np.int32), items[ju].astype(np.int32),
-                sup1[iu].astype(np.int32),
-                mode=eng.MODE_TIDSET, min_sup=abs_min_sup,
-                device_of_pair=part_to_dev[table[iu]],
-            )
-            # pairs were pre-filtered by the exact cached counts, so the
-            # engine must confirm every one; disagreement means the
-            # incremental state is corrupt and every further window would be
-            # silently wrong.  A real exception, not an ``assert`` — this
-            # must also fire under ``python -O``.
-            if not res.mask.all():
-                bad = np.nonzero(~res.mask)[0]
-                raise RuntimeError(
-                    f"cached co-occurrence counts disagree with the engine "
-                    f"on {bad.size}/{res.mask.size} level-2 pair(s) "
-                    f"(first: items {int(items[iu[bad[0]]])},"
-                    f"{int(items[ju[bad[0]]])}) — incremental window state "
-                    f"is corrupt")
-            sup2 = res.supports.astype(np.int64)
-            lvl_bitmaps = res.bitmaps
-        else:
-            sup2 = np.zeros(0, np.int64)
-            lvl_bitmaps = jnp.zeros((0, self.ring.n_words), jnp.uint32)
-        partition = table[iu] if iu.size else np.zeros(0, np.int64)
-        store.add_level(LevelRecord(k=2, parent=iu.copy(), item_rank=ju.copy(),
-                                    support=sup2, partition=partition))
-        stats["phase_s"]["level2"] = time.perf_counter() - t0
+        with _span("level2", stats["phase_s"]):
+            csub = self.cooc[np.ix_(items, items)]
+            iu, ju, c2 = frequent_pairs(csub, abs_min_sup)
+            if iu.size:
+                res = self.engine.expand(
+                    self.ring.device,
+                    items[iu].astype(np.int32), items[ju].astype(np.int32),
+                    sup1[iu].astype(np.int32),
+                    mode=eng.MODE_TIDSET, min_sup=abs_min_sup,
+                    device_of_pair=part_to_dev[table[iu]],
+                )
+                # pairs were pre-filtered by the exact cached counts, so the
+                # engine must confirm every one; disagreement means the
+                # incremental state is corrupt and every further window
+                # would be silently wrong.  A real exception, not an
+                # ``assert`` — this must also fire under ``python -O``.
+                if not res.mask.all():
+                    bad = np.nonzero(~res.mask)[0]
+                    raise RuntimeError(
+                        f"cached co-occurrence counts disagree with the "
+                        f"engine on {bad.size}/{res.mask.size} level-2 "
+                        f"pair(s) (first: items {int(items[iu[bad[0]]])},"
+                        f"{int(items[ju[bad[0]]])}) — incremental window "
+                        f"state is corrupt")
+                sup2 = res.supports.astype(np.int64)
+                lvl_bitmaps = res.bitmaps
+            else:
+                sup2 = np.zeros(0, np.int64)
+                lvl_bitmaps = jnp.zeros((0, self.ring.n_words), jnp.uint32)
+            partition = table[iu] if iu.size else np.zeros(0, np.int64)
+            store.add_level(LevelRecord(k=2, parent=iu.copy(),
+                                        item_rank=ju.copy(), support=sup2,
+                                        partition=partition))
 
         # ---- levels >= 3: the shared per-class bottom-up loop --------------
         # level-2 read from the cached counts, deep expansion not yet run
         kill_point("miner:pre_deep_expand")
-        t0 = time.perf_counter()
-        run_bottom_up(self.engine, store, lvl_bitmaps,
-                      class_id=iu.copy(), item_rank=ju.copy(),
-                      partition=partition, support=sup2,
-                      abs_min_sup=abs_min_sup, mode=eng.MODE_TIDSET,
-                      max_k=max_k, part_to_dev=part_to_dev)
-        stats["phase_s"]["bottom_up"] = time.perf_counter() - t0
+        with _span("bottom_up", stats["phase_s"]):
+            run_bottom_up(self.engine, store, lvl_bitmaps,
+                          class_id=iu.copy(), item_rank=ju.copy(),
+                          partition=partition, support=sup2,
+                          abs_min_sup=abs_min_sup, mode=eng.MODE_TIDSET,
+                          max_k=max_k, part_to_dev=part_to_dev)
         # engine counters are lifetime-cumulative; report this slide's delta
-        stats.update(self.engine.stats(since=engine_snap))
-        stats["total_s"] = time.perf_counter() - t_start
+        merge_stats(stats, self.engine.stats(since=engine_snap))
         return WindowResult(store=store, n_txn=n_txn, stats=stats,
                             version=self.window_version)
 
