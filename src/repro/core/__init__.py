@@ -33,7 +33,6 @@ from .partitioners import (
     reverse_hash_partitioner,
 )
 from .vertical import VerticalDB, build_vertical, filter_transactions
-from .accumulator import HostAccumulator, build_vertical_accumulated
 
 __all__ = [
     "AprioriResult", "apriori_mine",
@@ -50,5 +49,4 @@ __all__ = [
     "greedy_partitioner", "hash_partitioner", "pack_items", "partition_stats",
     "reverse_hash_partitioner",
     "VerticalDB", "build_vertical", "filter_transactions",
-    "HostAccumulator", "build_vertical_accumulated",
 ]

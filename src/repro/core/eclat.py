@@ -14,7 +14,7 @@ padding logic; ``EclatConfig.backend`` selects the engine backend.
 
 Variants:
   v1  vertical build via scatter, no filtering, default partitioner
-  v2  + filtered transactions (bitmap column compaction)
+  v2  + filtered transactions (dropped before the pack)
   v3  + accumulator-built vertical DB (psum path)
   v4  v3 + hash partitioner (p user-set)
   v5  v3 + reverse-hash partitioner
@@ -33,12 +33,11 @@ import jax.numpy as jnp
 
 from . import engine as eng
 from .engine import merge_stats
-from .accumulator import build_vertical_accumulated
 from .equivalence import class_segments, pair_work, segment_pairs
 from .itemsets import ItemsetStore, LevelRecord
 from .partitioners import assign_partitions, partition_stats
 from .triangular import cooc_blocks, cooccurrence_counts, frequent_pairs
-from .vertical import VerticalDB, build_vertical, filter_transactions, filtering_reduction
+from .vertical import VerticalDB, build_vertical
 from ..spans import span
 
 # the batch miner's phases, as ``mine.<phase>`` spans and ``phase_s`` keys
@@ -208,18 +207,17 @@ def run_bottom_up(
 
 
 def _build_db(transactions, n_items, abs_min_sup, spec, mesh) -> Tuple[VerticalDB, dict]:
+    """The variant's vertical build: v2+ filter transactions before the
+    pack, v3+ merge the per-shard partials by ``psum`` when given a mesh.
+    ``filter_reduction`` is the share of transactions the filter removed
+    (paper §5.2.1 reports e.g. 3.2%..25.8% for T40I10D100K)."""
+    db = build_vertical(transactions, n_items, abs_min_sup, order="support_asc",
+                        filter_txns=spec["filter_txns"],
+                        mesh=mesh if spec["accumulator"] else None)
     info: dict = {}
-    if spec["accumulator"]:
-        db = build_vertical_accumulated(
-            transactions, n_items, abs_min_sup, order="support_asc",
-            mesh=mesh if mesh is not None else None,
-        )
-    else:
-        db = build_vertical(transactions, n_items, abs_min_sup, order="support_asc")
     if spec["filter_txns"]:
-        before = db
-        db = filter_transactions(db)
-        info["filter_reduction"] = filtering_reduction(before, db)
+        n_txn = len(transactions)
+        info["filter_reduction"] = 1.0 - db.n_txn / n_txn if n_txn else 0.0
     return db, info
 
 

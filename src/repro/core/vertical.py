@@ -1,23 +1,31 @@
 """Vertical database construction (Phase-1/2/3 of the paper's variants).
 
-Three construction paths mirror the paper:
+One builder, :func:`build_vertical`, covers the paper's three phases on the
+flat (transaction, item) incidence arrays of the horizontal database:
 
-* :func:`build_vertical` — EclatV1 Phase-1: scatter the horizontal DB into a
-  packed bitmap, compute item supports, keep frequent items.
-* :func:`filter_transactions` — EclatV2 Phase-2: Borgelt's filtered
-  transactions; here a bitmap compaction (drop infrequent item rows, drop
-  transaction columns that became empty, optionally re-sort items).
-* :func:`build_vertical_accumulated` — EclatV3 Phase-3: the accumulator-built
-  vertical DB; semantically identical output, produced through the
-  ``repro.core.accumulator`` psum path so the V3 lineage is honest.
+* EclatV1 Phase-1: scatter every incidence into a packed bitmap, compute
+  item supports, keep frequent items.
+* EclatV2 Phase-2 (``filter_txns``): Borgelt's filtered transactions, applied
+  before the pack -- incidences of infrequent items are dropped and the
+  transactions left with none are removed, so the packed width W shrinks.
+* EclatV3 Phase-3 (``mesh``): the accumulator-built vertical DB.  Each shard
+  of the data axis scatters its own block of transactions into a partial
+  bitmap, and the bit-disjoint partials are merged by ``psum`` (add == or).
+
+:func:`filter_transactions` is the same filter for a caller that already
+holds a :class:`VerticalDB`: a bitmap column compaction.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+from typing import Optional, Sequence
 
 import numpy as np
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from ..dist.compat import shard_map
 from . import bitmap as bm
 
 __all__ = ["VerticalDB", "build_vertical", "filter_transactions", "sort_items"]
@@ -79,26 +87,81 @@ def sort_items(items: np.ndarray, supports: np.ndarray, order: str):
     return perm
 
 
+def _psum_pack(tids: np.ndarray, rows: np.ndarray, n_rows: int, n_txn: int,
+               mesh: jax.sharding.Mesh, axis: str) -> np.ndarray:
+    """The accumulator merge: shard ``i`` of ``mesh``'s ``axis`` scatters the
+    ``i``-th contiguous block of transaction ids into its own full-width
+    partial, and ``psum`` adds the bit-disjoint partials, which is their OR."""
+    d = mesh.shape[axis]
+    bounds = np.linspace(0, n_txn, d + 1).astype(int)
+    cuts = np.searchsorted(tids, bounds)            # tids ascend
+    partials = np.stack([
+        bm.scatter_incidences(tids[cuts[i]:cuts[i + 1]], rows[cuts[i]:cuts[i + 1]],
+                              n_rows, n_txn)
+        for i in range(d)
+    ])
+
+    def _merge(part):  # part: (1, n_rows, w) per shard
+        return jax.lax.psum(part[0], axis)
+
+    merged = jax.jit(
+        shard_map(_merge, mesh=mesh, in_specs=P(axis, None, None), out_specs=P())
+    )(jnp.asarray(partials))
+    return np.asarray(merged).astype(np.uint32)
+
+
 def build_vertical(
     transactions: Sequence[Sequence[int]],
     n_items: int,
     min_sup: int,
     order: str = "support_asc",
+    *,
+    filter_txns: bool = False,
+    mesh: Optional[jax.sharding.Mesh] = None,
+    axis: str = "data",
 ) -> VerticalDB:
-    """EclatV1 Phase-1: horizontal -> packed vertical DB of frequent items."""
-    packed = bm.pack_transactions(transactions, n_items)
-    supports = bm.support_np(packed)
+    """Horizontal -> packed vertical DB of frequent items.
+
+    The input is flattened once into incidence arrays; item supports are
+    counted on them exactly (:func:`bm.incidence_supports`), and the
+    incidences of frequent items are packed into their rows by one
+    vectorised scatter -- on the host, or with ``mesh`` per shard of
+    ``axis`` with a ``psum`` merge.
+
+    ``filter_txns`` drops the transactions left with no frequent item
+    before the pack and renumbers the rest in their original order: the
+    same bitmaps as :func:`filter_transactions` on the unfiltered DB, with
+    no bit-level column gather.  ``n_txn`` is then the number of
+    transactions kept; supports and ``n_incidences`` are those of the whole
+    input.
+    """
+    tids, items, n_txn = bm.flatten_transactions(transactions, n_items)
+    supports = bm.incidence_supports(tids, items, n_items, n_txn)
     n_incidences = int(supports.sum())
     freq_mask = supports >= int(min_sup)
-    items = np.nonzero(freq_mask)[0].astype(np.int64)
-    packed = packed[freq_mask]
+    freq_items = np.nonzero(freq_mask)[0].astype(np.int64)
+    row_of = np.where(freq_mask, np.cumsum(freq_mask) - 1, -1)
+    rows = row_of[items]
+    keep = rows >= 0
+    tids, rows = tids[keep], rows[keep]
+    if filter_txns:
+        touched = np.zeros(n_txn, dtype=bool)
+        touched[tids] = True
+        n_kept = int(np.count_nonzero(touched))
+        if n_kept < n_txn:
+            tids = (np.cumsum(touched) - 1)[tids]
+            n_txn = n_kept
+    if mesh is None:
+        packed = bm.scatter_incidences(tids, rows, freq_items.size, n_txn)
+    else:
+        packed = _psum_pack(tids, rows, freq_items.size, n_txn, mesh, axis)
     supports = supports[freq_mask]
-    perm = sort_items(items, supports, order)
+    perm = sort_items(freq_items, supports, order)
     return VerticalDB(
         bitmaps=packed[perm],
-        items=items[perm],
+        items=freq_items[perm],
         supports=supports[perm],
-        n_txn=len(transactions),
+        n_txn=n_txn,
         order=order,
         n_incidences=n_incidences,
     )
@@ -131,11 +194,3 @@ def filter_transactions(db: VerticalDB, drop_empty_cols: bool = True) -> Vertica
         order=db.order,
         n_incidences=db.n_incidences,
     )
-
-
-def filtering_reduction(db_before: VerticalDB, db_after: VerticalDB) -> float:
-    """Fraction of transaction columns removed by filtering (paper §5.2.1
-    reports e.g. 3.2%..25.8% for T40I10D100K)."""
-    if db_before.n_txn == 0:
-        return 0.0
-    return 1.0 - db_after.n_txn / db_before.n_txn
