@@ -42,7 +42,7 @@ from ..core.engine import merge_stats
 from ..core.equivalence import pair_work
 from ..core.itemsets import ItemsetStore, LevelRecord, generate_rules
 from ..core.partitioners import assign_partitions
-from ..core.triangular import cooccurrence_counts, frequent_pairs
+from ..core.triangular import cooc_blocks, cooccurrence_counts, frequent_pairs
 from ..core.vertical import sort_items
 from ..faults import kill_point
 from ..spans import span
@@ -154,7 +154,11 @@ class WindowResult:
         return self.store.itemsets()
 
     def support_map(self):
-        return self.store.support_map()
+        """The full frequent map; each call adds its time to
+        ``stats["phase_s"]["support_map"]`` (the ``slide.support_map``
+        span)."""
+        with _span("support_map", self.stats.setdefault("phase_s", {})):
+            return self.store.support_map()
 
     def rules(self, min_conf: float):
         return generate_rules(self.support_map(), min_conf)
@@ -211,23 +215,32 @@ class StreamingMiner:
         return np.diag(self.cooc)
 
     def push(self, batch: Sequence[Sequence[int]]) -> dict:
-        """Admit one micro-batch; update ring + counts by block deltas."""
+        """Admit one micro-batch; update ring + counts by block deltas.
+
+        The call is the ``slide.push`` span, holding ``slide.ring`` (pack
+        and device write) and ``slide.cooc_delta`` (the count matrix's
+        block deltas); the returned ``phase_s`` times the three, and
+        ``counts["host_reads"]`` counts the deltas' blocking reads."""
         took: Dict[str, float] = {}
+        passes = 1
         with _span("push", took, n_txn=len(batch)):
-            new_block, old_block, n_evicted = self.ring.push(batch)
+            with _span("ring", took):
+                new_block, old_block, n_evicted = self.ring.push(batch)
             # ring written, count matrix not yet — the torn state recovery
             # must handle (tests/faultinject.py kills here)
             kill_point("miner:mid_append")
-            # popcount is additive over word blocks, so the count matrix
-            # follows the ring exactly: add the admitted block, subtract the
-            # evicted one.
-            self.cooc += cooccurrence_counts(
-                jax.device_put(new_block)).astype(np.int64)
-            # admitted block counted, evicted block not yet subtracted
-            kill_point("miner:mid_evict")
-            if n_evicted or old_block.any():
-                self.cooc -= cooccurrence_counts(
-                    jax.device_put(old_block)).astype(np.int64)
+            with _span("cooc_delta", took):
+                # popcount is additive over word blocks, so the count matrix
+                # follows the ring exactly: add the admitted block, subtract
+                # the evicted one.
+                self.cooc += cooccurrence_counts(
+                    jax.device_put(new_block)).astype(np.int64)
+                # admitted block counted, evicted block not yet subtracted
+                kill_point("miner:mid_evict")
+                if n_evicted or old_block.any():
+                    passes = 2
+                    self.cooc -= cooccurrence_counts(
+                        jax.device_put(old_block)).astype(np.int64)
             # the window's contents changed: new version.  Bumped only after
             # the ring AND the count matrix agree, so a crash between the
             # kill points above never publishes a version for a half-applied
@@ -237,6 +250,8 @@ class StreamingMiner:
             "push_s": took["push"],
             "n_admitted": len(batch),
             "n_evicted": n_evicted,
+            "phase_s": took,
+            "counts": {"host_reads": passes * cooc_blocks(self.n_items)},
         }
 
     # -- re-mining -----------------------------------------------------------
@@ -374,7 +389,7 @@ class StreamingMiner:
         """One window slide: admit the micro-batch, then re-mine."""
         push_stats = self.push(batch)
         result = self.mine_window()
-        result.stats.update(push_stats)
+        merge_stats(result.stats, push_stats)
         result.stats["slide_s"] = push_stats["push_s"] + result.stats["total_s"]
         return result
 

@@ -214,6 +214,73 @@ def test_streaming_slides_report_push_level2_bottom_up_and_emit_spans(
     assert abs(waits / 1e9 - want) <= max(2e-3, 0.05 * want)
 
 
+SLIDE_PHASES = {"push", "ring", "cooc_delta", "level2", "bottom_up",
+                "support_map"}
+
+
+def test_a_slide_times_its_push_parts_and_its_answer(quest, tmp_path):
+    txns, n_items = quest
+    miner = StreamingMiner(n_items, StreamConfig(min_sup=0.01, n_blocks=2,
+                                                 block_txns=512),
+                           keep_transactions=False)
+    miner.advance(txns[:512]).support_map()       # compiles outside the trace
+
+    def run():
+        out = []
+        for i in (512, 1024, 1536):
+            res = miner.advance(txns[i:i + 512])
+            res.support_map()
+            out.append(res)
+        return out
+    results, spans = _traced(tmp_path, run)
+    for res in results:
+        ph = res.stats["phase_s"]
+        assert SLIDE_PHASES <= set(ph)
+        assert 0 < ph["ring"] and 0 < ph["cooc_delta"]
+        assert ph["ring"] + ph["cooc_delta"] <= ph["push"]
+        assert ph["push"] == res.stats["push_s"]
+    pushes = _named(spans, "slide.push")
+    rings = _named(spans, "slide.ring")
+    deltas = _named(spans, "slide.cooc_delta")
+    assert len(pushes) == len(rings) == len(deltas) == 3
+    for push, ring, delta in zip(pushes, rings, deltas):
+        assert _inside(ring, push) and _inside(delta, push)
+        assert ring[2] <= delta[1]
+    maps = _named(spans, "slide.support_map")
+    windows = _named(spans, "slide.mine_window")
+    assert len(maps) == 3
+    # the map is built after its window is mined, outside the re-mine
+    assert all(w[2] <= m[1] for w, m in zip(windows, maps))
+    for res, m in zip(results, maps):
+        want = res.stats["phase_s"]["support_map"]
+        assert abs((m[2] - m[1]) / 1e9 - want) <= max(2e-3, 0.05 * want)
+
+
+def test_a_slide_counts_both_count_passes_among_its_host_reads(quest,
+                                                                monkeypatch):
+    txns, n_items = quest
+    miner = StreamingMiner(n_items, StreamConfig(min_sup=0.01, n_blocks=1,
+                                                 block_txns=512))
+    first = miner.advance(txns[:512])             # nothing evicted yet
+    reads = []
+    device_get = jax.device_get
+
+    def counting(x):
+        reads.append(1)
+        return device_get(x)
+    monkeypatch.setattr(jax, "device_get", counting)
+    res = miner.advance(txns[512:1024])           # evicts the first block
+    monkeypatch.undo()
+    assert res.stats["n_evicted"] == 512
+    engine_reads = len(res.stats["pair_padding"]["per_level"])
+    assert engine_reads >= 1
+    assert len(reads) == 2 * cooc_blocks(n_items) + engine_reads
+    assert res.stats["counts"]["host_reads"] == len(reads)
+    first_engine = len(first.stats["pair_padding"]["per_level"])
+    assert (first.stats["counts"]["host_reads"]
+            == cooc_blocks(n_items) + first_engine)
+
+
 def test_span_accumulates_repeats_and_needs_no_session():
     into = {}
     for _ in range(3):

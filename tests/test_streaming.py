@@ -138,6 +138,31 @@ def test_windowed_parity_on_paper_stream():
         assert res.support_map() == batch_res.support_map()
 
 
+def test_full_25_block_ring_without_kept_transactions_matches_batch():
+    """The deployment's shape (``launch.stream``'s ``keep_transactions=False``,
+    25 blocks a window) at small blocks: every slide past the fill evicts
+    one block, and the window mined from the ring and the cached counts
+    equals batch ``mine()`` of the 25 newest batches, kept by the test."""
+    spec = stream_spec("T10I4D100K")
+    cfg = StreamConfig(min_sup=0.01, n_blocks=25, block_txns=64,
+                       backend="pallas")
+    miner = StreamingMiner(spec.n_items, cfg, keep_transactions=False)
+    batches = list(transaction_stream("T10I4D100K", 64, 27, seed=12))
+    for i, batch in enumerate(batches):
+        res = miner.advance(batch)
+        assert res.stats["n_evicted"] == (64 if i >= 25 else 0)
+        assert res.version == i + 1
+        if i < 24:
+            continue
+        window = [t for b in batches[i - 24:i + 1] for t in b]
+        assert res.n_txn == len(window) == 25 * 64
+        want = mine(window, spec.n_items, EclatConfig(min_sup=0.01))
+        assert res.support_map() == want.support_map(), f"slide {i}"
+        assert max(len(k) for k in want.support_map()) >= 2
+    with pytest.raises(RuntimeError, match="keep_transactions=False"):
+        miner.window_transactions()
+
+
 def test_class_crossing_bookkeeping_under_drift():
     cfg = StreamConfig(min_sup=6, n_blocks=2, block_txns=64)
     miner = StreamingMiner(20, cfg)
