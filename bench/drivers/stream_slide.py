@@ -9,13 +9,18 @@ compiles every program a slide runs, so each timed slide is over a full
 window.  A unit is one ``advance(block)`` -- the push and the re-mine --
 ending with the caller holding the window's full (itemset, support) map.
 
-A seeded reservoir keeps the maps of ``compared_windows`` slides (the last
+A seeded reservoir keeps the maps of ``compared_mines`` slides (the last
 one always among them), each with the pool blocks its window holds and the
 number of pushes before it.  The reference mines each kept window from the
 generator's incidence arrays of those blocks, never from the program's
 ring; a map stamped with another version than the pushes so far, or
 another size than the full window, is of another window and is compared
 with nothing.
+
+The window is the configuration's dataset: ``n_blocks * block_txns`` is its
+``n_txn``.  The traffic's ``databases`` is 1, the one pool the stream is
+drawn from; it and the configuration's ``variant`` are what a batch mine of
+the same data (``batch_mine.Cell``) reads.
 
 The control and the faults take ``ENTRY``'s place (``bench/control.py``):
 each is a class with the miner's constructor, ``push``, ``mine_window`` and
@@ -45,6 +50,10 @@ class Cell:
         self.n_blocks = int(win["n_blocks"])
         self.block_txns = int(win["block_txns"])
         self.min_sup = config["min_sup"]
+        if self.n_blocks * self.block_txns != int(config["dataset"]["n_txn"]):
+            raise ValueError(
+                f"a window of {self.n_blocks} x {self.block_txns} baskets is "
+                f"not the dataset's {config['dataset']['n_txn']}")
         n_pool = int(config["pool_blocks"])
         self.pool = generators.base_database(config, n_pool * self.block_txns)
         bounds = np.searchsorted(
@@ -67,7 +76,7 @@ class Cell:
                                    block_txns=self.block_txns,
                                    backend=prog["backend"]),
             keep_transactions=bool(prog["keep_transactions"]))
-        self.kept = Reservoir(int(traffic["compared_windows"]),
+        self.kept = Reservoir(int(traffic["compared_mines"]),
                               np.random.default_rng(sample_seq))
         self.reference = {}
 

@@ -1,6 +1,6 @@
 """The readers of the program's own phase spans (``support_map_s``,
 ``cooc_s``, ``expand_wait_s``) on records a batch mine writes, at each
-batch configuration's CPU test size."""
+configuration's CPU test size."""
 import types
 
 import pytest
@@ -10,11 +10,9 @@ from bench import run
 from bench.drivers import batch_mine
 
 SPAN_METRICS = ("support_map_s", "cooc_s", "expand_wait_s")
-MINE_CELLS = [w for w in _paths.WORKLOADS
-              if run.find_cell(_paths.BENCH, w)[2]["driver"] == "batch_mine"]
 
 
-@pytest.fixture(scope="module", params=MINE_CELLS)
+@pytest.fixture(scope="module", params=_paths.WORKLOADS)
 def records(request):
     name = request.param
     cell, config, traffic = run.find_cell(_paths.BENCH, name)
