@@ -21,12 +21,19 @@ __all__ = ["cooc_blocks", "cooccurrence_counts", "frequent_pairs"]
 
 
 @partial(jax.jit, static_argnames=("block",))
-def _cooc_block(bitmaps: jax.Array, row_start: jax.Array, block: int) -> jax.Array:
-    """Counts for rows [row_start, row_start+block) against all rows."""
-    with jax.named_scope("level2_cooc"):
-        rows = jax.lax.dynamic_slice_in_dim(bitmaps, row_start, block, axis=0)
+def _cooc_block(bitmaps: jax.Array, block: int) -> jax.Array:
+    """Counts of every row against every row, ``block`` rows at a time, in
+    one device program; the row count of ``bitmaps`` is a multiple of
+    ``block``."""
+    n_pad, words = bitmaps.shape
+
+    def count(rows):
         inter = jnp.bitwise_and(rows[:, None, :], bitmaps[None, :, :])
         return jax.lax.population_count(inter).astype(jnp.int32).sum(-1)
+    with jax.named_scope("level2_cooc"):
+        per_block = jax.lax.map(
+            count, bitmaps.reshape(n_pad // block, block, words))
+        return per_block.reshape(n_pad, n_pad)
 
 
 @partial(jax.jit, static_argnames=("pad",))
@@ -37,21 +44,25 @@ def _pad_rows(bitmaps: jax.Array, pad: int) -> jax.Array:
     return jnp.pad(bitmaps, ((0, pad), (0, 0)))
 
 
-def cooc_blocks(n: int, block: int = 64) -> int:
-    """Row blocks of :func:`cooccurrence_counts` over ``n`` rows, one
-    blocking device->host read each: ``n`` padded to ``block`` times a
-    power of two (so nearby ``n`` reuse one compiled block kernel)."""
-    if n == 0:
-        return 0
+def _padded_rows(n: int, block: int) -> int:
+    """``n`` padded to ``block`` times a power of two, so nearby ``n``
+    reuse one compiled :func:`_cooc_block`."""
     blocks = 1
     while blocks * block < n:
         blocks <<= 1
-    return blocks
+    return blocks * block
+
+
+def cooc_blocks(n: int) -> int:
+    """Blocking device->host reads one :func:`cooccurrence_counts` pass
+    over ``n`` rows makes: one, or none when there are no rows."""
+    return 1 if n else 0
 
 
 def cooccurrence_counts(bitmaps, block: int = 64) -> np.ndarray:
-    """Full (n, n) co-occurrence count matrix, computed in row blocks so the
-    (block, n, W) intermediate stays cache/VMEM sized."""
+    """Full (n, n) co-occurrence count matrix: one device program over
+    ``block``-row blocks, so the (block, n, W) intermediate stays
+    cache/VMEM sized, and one blocking read."""
     if not isinstance(bitmaps, jax.Array):
         # explicit upload (staticcheck RS005): callers on the slide hot path
         # hand device arrays in; host arrays are device_put once, up front
@@ -59,13 +70,9 @@ def cooccurrence_counts(bitmaps, block: int = 64) -> np.ndarray:
     n = bitmaps.shape[0]
     if n == 0:
         return np.zeros((0, 0), np.int32)
-    pad = cooc_blocks(n, block) * block - n
+    pad = _padded_rows(n, block) - n
     bitmaps_p = _pad_rows(bitmaps, pad) if pad else bitmaps
-    out = []
-    for s in range(0, n + pad, block):
-        out.append(jax.device_get(
-            _cooc_block(bitmaps_p, jax.device_put(np.int32(s)), block))[:, :n])
-    return np.concatenate(out, axis=0)[:n]
+    return jax.device_get(_cooc_block(bitmaps_p, block))[:n, :n]
 
 
 def frequent_pairs(counts: np.ndarray, min_sup: int):

@@ -108,10 +108,12 @@ def test_pair_cap_is_the_largest_that_fits_smem(spec, monkeypatch):
 
 
 def test_cooc_block_compiles_for_v5e(spec):
-    """The level-2 co-occurrence block at T10I4D100K width: one fused XLA
-    program with no temporary beyond its (64, rows) counts."""
+    """The level-2 co-occurrence pass at T10I4D100K width: one XLA program
+    over every 64-row block, with no temporary beyond twice one block's
+    (64, rows) counts, so neither a (64, rows, words) intermediate nor a
+    second (rows, rows) matrix is materialised."""
     import jax.numpy as jnp
     from repro.core.triangular import _cooc_block
     compiled = _cooc_block.lower(spec((ROWS, WIDTHS["T10I4D100K"]), jnp.uint32),
-                                 spec((), jnp.int32), 64).compile()
+                                 64).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * ROWS * 4 * 2
