@@ -8,7 +8,7 @@
 Three layers (DESIGN.md §12, ``repro.staticcheck``):
 
   1. repo AST lint    RS001-RS005 strict over src/repro + scripts,
-                      warn-only over tests/ + benchmarks/
+                      warn-only over tests/
   2. IR contracts     lower all five engine backends + the sharded ring
                       write under a forced multi-device mesh, assert the
                       declared collective set / byte budget / reduce axis
@@ -49,7 +49,7 @@ from repro.staticcheck.astlint import lint_file  # noqa: E402
 
 FIXTURE_DIR = os.path.join(ROOT, "src", "repro", "staticcheck", "fixtures")
 STRICT_DIRS = (os.path.join("src", "repro"), "scripts")
-WARN_DIRS = ("tests", "benchmarks")
+WARN_DIRS = ("tests",)
 DEFAULT_REPORT = os.path.join(ROOT, "reports", "static_findings.json")
 
 

@@ -145,8 +145,8 @@ def intersect_cost(q: int, w: int, block_w: int, *,
     per word (AND + popcount + accumulate).  A ``block_w`` wider than the
     lane-padded row is modeled as reading the padded row (the term that
     penalizes over-wide blocks on narrow frontiers).  Used by
-    ``repro.kernels.autotune`` to order candidate tile widths before
-    measuring: the model seeds the sweep, measurement decides it.
+    ``kernels.fused_intersect.ops.resolve_block_w`` to pick the tile width:
+    the best-predicted candidate is the one compiled.
     """
     q = max(int(q), 1)
     w = max(int(w), 1)

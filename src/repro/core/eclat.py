@@ -97,11 +97,8 @@ class EclatConfig:
     tri_matrix: Optional[bool] = None   # None = auto (paper's triMatrixMode)
     tri_matrix_max_items: int = 4096    # auto threshold (paper: item-id range)
     use_diffsets: bool = False          # v6 only (dEclat); other variants reject it
-    backend: str = "pallas"             # jnp | pallas | sharded | tidsharded | grid | auto (measured dispatch, DESIGN.md §6; "batched" = legacy alias)
+    backend: str = "pallas"             # jnp | pallas | sharded | tidsharded | grid (named, never measured: DESIGN.md §6)
     shard: str = "pairs"                # mesh split: "pairs" (frontier replicated) | "words" (tid axis, DESIGN.md §7) | "grid" (pairs x words 2D mesh, DESIGN.md §8)
-    block_w: Optional[int] = None       # fused-kernel word-tile width; None = autotuned table / cost-model seed
-    autotune: bool = False              # tune-on-miss: measure untuned kernel shapes before dispatching them
-    compact: bool = True                # in-executable survivor compaction (False = legacy mask-roundtrip + gather)
     mode: str = "all"                   # workload: all | closed | maximal (lineage post-filter, DESIGN.md §9)
     max_k: Optional[int] = None         # deepest itemset length to mine (>= 1); None = unbounded
     bucket_min: int = 128               # pair-buffer bucket-ladder floor (half-pow2 rungs; low floor = low padding waste)
@@ -299,17 +296,9 @@ def _mine(transactions, n_items, config, mesh) -> EclatResult:
         est = pair_work(sizes1 + 1, w)  # +1: member count of class r is n1-1-r
         eff_p = config.p if spec["partitioner"] in ("hash", "reverse_hash", "greedy") else max(n_classes, 1)
         table = assign_partitions(n_classes, spec["partitioner"], eff_p, work=est)
-        # dispatch hints for backend="auto": the dominant expansion is level 2
-        # (all cross-class pairs of the n1 frequent items over w words); the
-        # measured crossover table is indexed by exactly that (Q, W) shape
-        est_q2 = n1 * (n1 - 1) // 2
         execu = eng.resolve_engine(config.backend, mesh,
                                    bucket_min=config.bucket_min,
-                                   shard=config.shard,
-                                   block_w=config.block_w,
-                                   autotune=config.autotune,
-                                   compact=config.compact,
-                                   hints=(max(est_q2, 1), max(w, 1)))
+                                   shard=config.shard)
         stats["backend"] = execu.name
         stats["backend_requested"] = config.backend
         # partition -> device round robin (mesh-mapped backends' pair axis)
@@ -482,10 +471,7 @@ def _resume_mine(config: EclatConfig, mesh) -> EclatResult:
 
     execu = eng.resolve_engine(config.backend, mesh,
                                bucket_min=config.bucket_min,
-                               shard=config.shard,
-                               block_w=config.block_w,
-                               autotune=config.autotune,
-                               compact=config.compact)
+                               shard=config.shard)
     stats["backend"] = execu.name
     stats["backend_requested"] = config.backend
     part_to_dev = np.arange(eff_p, dtype=np.int64) % max(execu.n_devices, 1)

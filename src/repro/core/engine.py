@@ -56,8 +56,7 @@ padded host-side index buffers themselves are persistent per rung (no
 per-call allocation or ``argsort`` churn for the single-device backends).
 The default floor is 128 — the ladder is discrete, so a low floor costs at
 most a handful of extra one-time compiles, while a high one (the old 1024)
-dominated padding waste on small levels (BENCH_engine.json recorded
-``padding_efficiency: 0.115`` with every sub-floor level padded to 1024).
+pads every small level up to 1024 pairs.
 No rung exceeds ``MAX_PAIRS_PER_CALL`` (the kernel's SMEM bound):
 ``Engine.expand`` runs a larger level as several capped calls.
 """
@@ -65,8 +64,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import json
-import os
 from typing import Callable, Dict, List, Optional, Tuple, Type
 
 import numpy as np
@@ -93,7 +90,7 @@ __all__ = [
     "ShardedEngine", "TidShardedEngine", "GridShardedEngine",
     "group_pairs_by_device", "register_backend", "available_backends",
     "make_engine", "engine_from_state", "resolve_engine", "merge_stats",
-    "DispatchPolicy", "KERNELTUNE_ENV", "MAX_PAIRS_PER_CALL",
+    "MAX_PAIRS_PER_CALL",
 ]
 
 
@@ -143,21 +140,20 @@ class EngineState:
     *data*, never Python object innards.
 
     What is data: the knobs a rebuild needs (backend / inner executor /
-    ladder floors / kernel config) and the accounting ledgers that must
-    survive a crash so per-slide ``stats(since=...)`` deltas stay truthful
-    after recovery.  What is derived (and therefore absent): pair buffers,
-    compiled shard_map executors, shardings, autotune tables — all
-    reconstructed by :func:`engine_from_state` under whatever mesh the
-    restoring process brings.  ``mesh`` is the provenance descriptor of the
-    mesh the snapshot ran on; it is reported, never restored from.
+    ladder floors) and the accounting ledgers that must survive a crash so
+    per-slide ``stats(since=...)`` deltas stay truthful after recovery.
+    What is derived (and therefore absent): pair buffers, compiled
+    shard_map executors, shardings, tile widths — all reconstructed by
+    :func:`engine_from_state` under whatever mesh the restoring process
+    brings.  ``mesh`` is the provenance descriptor of the mesh the snapshot
+    ran on; it is reported, never restored from.  A tree written before the
+    ``block_w`` / ``compact`` / ``autotune`` knobs were retired still
+    restores: :meth:`from_tree` ignores those keys.
     """
     backend: str
     inner: str
     bucket_min: int
     compact_min: int
-    block_w: Optional[int]
-    compact: bool
-    autotune: bool
     interpret: Optional[bool]
     mesh: Optional[dict]                      # mesh_descriptor provenance
     n_intersections: int
@@ -177,9 +173,6 @@ class EngineState:
         extra = {"backend": self.backend, "inner": self.inner,
                  "bucket_min": int(self.bucket_min),
                  "compact_min": int(self.compact_min),
-                 "block_w": None if self.block_w is None else int(self.block_w),
-                 "compact": bool(self.compact),
-                 "autotune": bool(self.autotune),
                  "interpret": self.interpret, "mesh": self.mesh,
                  "n_intersections": int(self.n_intersections),
                  "n_padded": int(self.n_padded)}
@@ -193,9 +186,6 @@ class EngineState:
             backend=str(extra["backend"]), inner=str(extra["inner"]),
             bucket_min=int(extra["bucket_min"]),
             compact_min=int(extra["compact_min"]),
-            block_w=(None if extra["block_w"] is None
-                     else int(extra["block_w"])),
-            compact=bool(extra["compact"]), autotune=bool(extra["autotune"]),
             interpret=extra["interpret"], mesh=extra["mesh"],
             n_intersections=int(extra["n_intersections"]),
             n_padded=int(extra["n_padded"]),
@@ -210,10 +200,8 @@ def bucket_size(n: int, floor: int) -> int:
     The ladder is half-power-of-two: ``floor * {1, 1.5, 2, 3, 4, 6, 8, ...}``
     rather than pure doubling.  Pure powers of two waste up to ~50% of every
     padded batch in the worst case (n just past a rung); the 1.5x
-    intermediate rungs cap that at ~33% for ~2x the executable count — a
-    measured win for the engine benchmarks, whose level-1/2 frontier counts
-    routinely land just past a power of two (BENCH_engine.json
-    padding_efficiency was 0.115 on the pure-pow2 ladder)."""
+    intermediate rungs cap that at ~33% for ~2x the executable count;
+    level-1/2 frontier counts routinely land just past a power of two."""
     b = max(int(floor), 1)
     while b < n:
         h = b + (b >> 1)
@@ -339,32 +327,25 @@ def make_engine(
     bucket_min: int = 128,
     interpret: Optional[bool] = None,
     inner: str = "pallas",
-    block_w: Optional[int] = None,
-    compact: bool = True,
-    autotune: bool = False,
 ) -> "Engine":
     """Construct a backend by registry name.
 
     ``sharded`` / ``tidsharded`` / ``grid`` require a mesh (``grid`` a 2D
     one with ``("class", "data")`` axes); ``interpret`` forces the Pallas
     kernel's interpreter (tests) instead of the TPU/ref dispatch.
-    ``block_w`` / ``compact`` / ``autotune`` are the kernel-config knobs
-    every backend accepts (see :class:`Engine`).
     """
     cls = BACKENDS.get(backend)
     if cls is None:
         raise ValueError(f"unknown engine backend {backend!r}; "
                          f"available: {available_backends()}")
-    kcfg = dict(block_w=block_w, compact=compact, autotune=autotune)
     if backend in ("sharded", "tidsharded", "grid"):
         if mesh is None:
             raise ValueError(f"{backend} backend requires a mesh")
         return cls(mesh, bucket_min=bucket_min, inner=inner,
-                   interpret=interpret, **kcfg)
+                   interpret=interpret)
     if backend == "pallas":
-        return PallasEngine(bucket_min=bucket_min, interpret=interpret,
-                            **kcfg)
-    return cls(bucket_min=bucket_min, **kcfg)
+        return PallasEngine(bucket_min=bucket_min, interpret=interpret)
+    return cls(bucket_min=bucket_min)
 
 
 _UNSET = object()
@@ -398,90 +379,9 @@ def engine_from_state(
                       mesh=mesh if target in mesh_backends else None,
                       bucket_min=state.bucket_min,
                       interpret=interp,
-                      inner=state.inner,
-                      block_w=state.block_w,
-                      compact=state.compact,
-                      autotune=state.autotune)
+                      inner=state.inner)
     eng.compact_min = int(state.compact_min)
     return eng.restore_state(state)
-
-
-# ---------------------------------------------------------------------------
-# measured dispatch policy (BENCH_kerneltune.json crossover table)
-# ---------------------------------------------------------------------------
-
-KERNELTUNE_ENV = "REPRO_KERNELTUNE_TABLE"
-
-
-def _default_policy_paths() -> List[str]:
-    paths = []
-    env = os.environ.get(KERNELTUNE_ENV)
-    if env:
-        paths.append(env)
-    paths.append(os.path.join(os.getcwd(), "BENCH_kerneltune.json"))
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
-    paths.append(os.path.join(root, "BENCH_kerneltune.json"))
-    return paths
-
-
-class DispatchPolicy:
-    """Backend choice from *measured* crossovers, not assumptions.
-
-    ``benchmarks/kerneltune_bench.py`` sweeps the backends over a Q x W
-    grid and records, per cell, which backend won single-device and which
-    won mesh-mapped (``BENCH_kerneltune.json["crossover"]``).  This class
-    loads that table and answers "which backend for an expansion of ~q
-    pairs over ~w words?" by nearest measured cell in log space — the
-    measured replacement for the hand-waved dispatch table DESIGN.md §6
-    used to carry.  Missing / unreadable / empty tables load as ``None``
-    so ``resolve_engine(auto=...)`` can fall back to the static default
-    (pallas, or the mesh-implied backend) instead of guessing.
-    """
-
-    def __init__(self, cells: List[dict], source: Optional[str] = None):
-        self.cells = [c for c in cells
-                      if "q" in c and "w" in c and c.get("best_single")]
-        self.source = source
-
-    @classmethod
-    def load(cls, path: Optional[str] = None) -> Optional["DispatchPolicy"]:
-        """First usable table on the search path.  A table measured on
-        another platform (``jax_backend`` other than the running one, or
-        none recorded) is skipped: CPU crossovers must not steer a TPU."""
-        for p in ([path] if path else _default_policy_paths()):
-            try:
-                with open(p) as f:
-                    data = json.load(f)
-            except (OSError, ValueError):
-                continue
-            if data.get("jax_backend") != jax.default_backend():
-                continue
-            cells = data.get("crossover", [])
-            if cells:
-                policy = cls(cells, source=p)
-                if policy.cells:
-                    return policy
-        return None
-
-    def choose(self, q: int, w: int, *, have_mesh: bool = False) -> str:
-        """Measured-best backend for a ~(q pairs, w words) expansion.
-
-        Nearest cell by euclidean distance in (log2 q, log2 w) — the bench
-        grid is log-spaced, so log distance matches its geometry.  With a
-        mesh the cell's ``best_mesh`` winner is used (falling back to the
-        single-device winner's mesh mapping when the sweep ran
-        single-device only)."""
-        lq, lw = np.log2(max(int(q), 1)), np.log2(max(int(w), 1))
-
-        def dist(c):
-            return ((np.log2(max(int(c["q"]), 1)) - lq) ** 2
-                    + (np.log2(max(int(c["w"]), 1)) - lw) ** 2)
-
-        cell = min(self.cells, key=dist)
-        if have_mesh:
-            return cell.get("best_mesh") or cell["best_single"]
-        return cell["best_single"]
 
 
 def resolve_engine(
@@ -490,12 +390,6 @@ def resolve_engine(
     *,
     bucket_min: int = 128,
     shard: str = "pairs",
-    block_w: Optional[int] = None,
-    compact: bool = True,
-    autotune: bool = False,
-    auto: Optional[bool] = None,
-    hints: Optional[Tuple[int, int]] = None,
-    policy_path: Optional[str] = None,
 ) -> "Engine":
     """Map a (backend name, mesh, shard mode) request onto an engine.
 
@@ -513,64 +407,34 @@ def resolve_engine(
     *different* non-default ``shard`` is contradictory and rejected rather
     than silently resolved to either side.  Both the batch driver
     (``core.eclat.mine``) and the streaming miner (``repro.streaming``)
-    resolve their executors here.
-
-    **Measured dispatch**: ``backend="auto"`` (or ``auto=True``) consults
-    the :class:`DispatchPolicy` crossover table measured by
-    ``benchmarks/kerneltune_bench.py``, using ``hints=(est_pairs, words)``
-    — the driver's estimate of the dominant expansion shape — to pick the
-    backend nearest the measured winner (DESIGN.md §6).  The fallback is
-    always safe: no table, no hints, or an unknown winner resolves to the
-    static default exactly as before (``"batched"`` remains a legacy alias
-    for that default).  ``block_w`` / ``compact`` / ``autotune`` thread the
-    kernel-config knobs to whichever engine wins.
+    resolve their executors here.  The backend is named, never measured
+    (DESIGN.md §6): a name not in the registry raises.
     """
     shard_to_backend = {"pairs": "sharded", "words": "tidsharded",
                         "grid": "grid"}
     if shard not in shard_to_backend:
         raise ValueError(f"unknown shard mode {shard!r}; "
                          "expected 'pairs', 'words' or 'grid'")
-    requested = backend
-    auto = (backend == "auto") if auto is None else bool(auto)
-    if backend in ("batched", "auto"):
-        backend = "pallas"
-    policy = None
-    if auto:
-        policy = DispatchPolicy.load(policy_path)
-        if policy is not None and hints is not None:
-            est_q, est_w = hints
-            choice = policy.choose(est_q, est_w, have_mesh=mesh is not None)
-            if choice in BACKENDS:
-                backend = choice
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown engine backend {backend!r}; "
+                         f"available: {available_backends()}")
     implied = {"sharded": "pairs", "tidsharded": "words",
                "grid": "grid"}.get(backend)
     if implied is not None:
         # shard="pairs" is the config default, so only an explicit
-        # disagreement is a conflict — except under auto, where the policy
-        # (not the user) picked the backend and simply overrides the shard
-        if auto:
-            shard = implied
-        elif shard not in ("pairs", implied):
+        # disagreement is a conflict
+        if shard not in ("pairs", implied):
             raise ValueError(
                 f"backend {backend!r} implies shard={implied!r} but "
                 f"shard={shard!r} was requested; drop one of the two")
-        else:
-            shard = implied
-    kcfg = dict(block_w=block_w, compact=compact, autotune=autotune)
-    if mesh is not None or backend in ("sharded", "tidsharded", "grid"):
-        if mesh is None:
-            backend = "pallas"
-        else:
-            inner = backend if backend in ("jnp", "pallas") else "pallas"
-            engine = make_engine(shard_to_backend[shard], mesh=mesh,
-                                 bucket_min=bucket_min, inner=inner, **kcfg)
-            engine.dispatch = {"requested": requested, "auto": auto,
-                              "policy": policy.source if policy else None}
-            return engine
-    engine = make_engine(backend, bucket_min=bucket_min, **kcfg)
-    engine.dispatch = {"requested": requested, "auto": auto,
-                       "policy": policy.source if policy else None}
-    return engine
+        shard = implied
+    if mesh is not None:
+        inner = backend if backend in ("jnp", "pallas") else "pallas"
+        return make_engine(shard_to_backend[shard], mesh=mesh,
+                           bucket_min=bucket_min, inner=inner)
+    if implied is not None:
+        backend = "pallas"
+    return make_engine(backend, bucket_min=bucket_min)
 
 
 def merge_stats(stats: dict, engine_stats: dict) -> dict:
@@ -587,39 +451,22 @@ def merge_stats(stats: dict, engine_stats: dict) -> dict:
 class Engine:
     """Backend interface + shared accounting.
 
-    Kernel-config knobs (shared by every backend, threaded from
-    ``EclatConfig`` / ``StreamConfig`` through :func:`resolve_engine`):
+    The fused kernel's word-tile width is derived per call shape from the
+    cost model (``kernels.fused_intersect.ops.resolve_block_w``); the
+    single-device and word-sharded backends compact survivors inside the
+    fused executable (one dispatch, only survivors cross back).
 
-    ``block_w``  explicit word-tile width for the fused kernel; ``None``
-                 resolves through the autotuned shape table at trace time
-                 (``kernels.autotune.lookup``, cost-model seed on a miss).
-    ``compact``  fold the survivor-compaction epilogue into the fused
-                 executable where the backend supports it (one dispatch,
-                 only survivors cross back) instead of the legacy host-mask
-                 -> separate-gather two-step.
-    ``autotune`` tune-on-miss: before dispatching a shape class that has no
-                 table entry, run the measured sweep (cheap: cost-model
-                 seeded, truncated) and cache the winner.
     ``compact_min``  floor of the *survivor* bucket ladder — decoupled from
                  the pair-batch floor because survivor counts collapse fast
                  at deep levels; a 1024-row survivor rung for 12 survivors
-                 was most of BENCH_engine.json's 0.115 padding efficiency.
+                 would be almost all padding.
     """
 
     name = "abstract"
 
     def __init__(self, bucket_min: int = 128, *,
-                 block_w: Optional[int] = None,
-                 compact: bool = True,
-                 autotune: bool = False,
                  compact_min: Optional[int] = None):
         self.buffers = PairBuffers(bucket_min)
-        self.block_w = None if block_w is None else int(block_w)
-        self.compact = bool(compact)
-        self.autotune = bool(autotune)
-        if self.autotune:
-            from ..kernels import autotune as at
-            at.enable_user_cache()
         self.compact_min = (min(self.buffers.floor, 128)
                             if compact_min is None else max(int(compact_min), 1))
         self.n_intersections = 0
@@ -647,16 +494,6 @@ class Engine:
         self.n_reads += 1
         with span("expand_wait", self.phase_s, prefix=self.trace_prefix):
             return jax.device_get(arrays)
-
-    def _maybe_tune(self, q: int, w: int, mode: int) -> None:
-        """Tune-on-miss: warm the autotune table for this call shape so the
-        trace-time ``block_w=None`` lookup hits a measured entry.  No-op
-        unless ``autotune`` is on and no explicit ``block_w`` overrides it."""
-        if not self.autotune or self.block_w is not None:
-            return
-        from ..kernels import autotune as at
-        if at.load_table().get(at.shape_class(q, w, mode)) is None:
-            at.tune_shape(q, w, mode, reps=2, max_candidates=3)
 
     def expand(
         self,
@@ -740,15 +577,21 @@ class Engine:
         return self._take(block, _dput(idx, getattr(self, "_rep_sharding",
                                                     None)))
 
-    def _slice_survivors(self, compact: jax.Array, n_surv: int) -> jax.Array:
-        """Rung-slice a fused-epilogue compaction result: rows ``[:n_surv]``
-        are the survivors, the rung padding beyond them duplicates row 0 —
-        the same convention :meth:`_compact` produces, so the two paths are
-        interchangeable bit-for-bit.  The rung is clipped to the block: a
-        pair batch clipped to ``MAX_PAIRS_PER_CALL`` can be shorter than the
-        survivor ladder's next rung."""
-        sb = bucket_size(max(int(n_surv), 1), self.compact_min)
-        return _prefix_rows(compact, min(sb, compact.shape[0]))
+    def _survivors(self, compact: jax.Array, mask_dev: jax.Array,
+                   sup: jax.Array, q: int) -> LevelResult:
+        """A fused-compaction call's result: one blocking read of its mask
+        and supports, of which the first ``q`` are real pairs, and the
+        survivors' rung-slice of ``compact``: rows ``[:S]`` are the
+        survivors, the rung padding beyond them duplicates row 0 — the same
+        convention :meth:`_compact` produces.  The rung is clipped to the
+        block: a pair batch clipped to ``MAX_PAIRS_PER_CALL`` can be shorter
+        than the survivor ladder's next rung."""
+        mask_h, sup_h = self._read(mask_dev, sup)
+        mask = mask_h[:q].astype(bool)
+        sb = bucket_size(max(int(mask.sum()), 1), self.compact_min)
+        return LevelResult(
+            mask=mask, supports=sup_h[:q][mask].astype(np.int64),
+            bitmaps=_prefix_rows(compact, min(sb, compact.shape[0])))
 
     def prepare_frontier(self, bitmaps: jax.Array) -> jax.Array:
         """Place a frontier the way this backend will carry it (identity for
@@ -768,9 +611,6 @@ class Engine:
                           else "pallas"),
             bucket_min=self.buffers.floor,
             compact_min=self.compact_min,
-            block_w=self.block_w,
-            compact=self.compact,
-            autotune=self.autotune,
             interpret=getattr(self, "interpret", None),
             mesh=mesh_descriptor(getattr(self, "mesh", None)),
             n_intersections=self.n_intersections,
@@ -863,11 +703,8 @@ def _prefix_rows(arr: jax.Array, n: int) -> jax.Array:
 @register_backend("jnp")
 class JnpEngine(Engine):
     """XLA reference executor: one fused jit (gather + AND + popcount +
-    threshold), the semantics every other backend must match bit-exactly.
-    With ``compact`` (default) the survivor-compaction epilogue runs inside
-    the same jit — one dispatch, survivors only — via
-    :func:`fused_intersect_compact_ref`; ``compact=False`` keeps the legacy
-    host-mask -> separate-gather two-step."""
+    threshold + survivor compaction, :func:`fused_intersect_compact_ref`),
+    the semantics every other backend must match bit-exactly."""
 
     def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
                      device_of_pair=None):
@@ -876,26 +713,10 @@ class JnpEngine(Engine):
         qb, l, r, s = self.buffers.fill(left, right, sup_left)
         self._record_padding(q, qb)
         self._note_call(bitmaps, qb, mode)
-        if self.compact:
-            out, sup, mask_dev, n_surv = fused_intersect_compact_ref(
-                bitmaps, _dput(l), _dput(r), _dput(s),
-                _dput_i32(min_sup), _dput_i32(q), mode=mode)
-            mask_h, sup_h = self._read(mask_dev, sup)
-            mask = mask_h[:q].astype(bool)
-            sup_np = sup_h[:q]
-            return LevelResult(mask=mask,
-                               supports=sup_np[mask].astype(np.int64),
-                               bitmaps=self._slice_survivors(out, int(mask.sum())))
-        out, sup, _ = fused_intersect_ref(
+        out, sup, mask_dev, _ = fused_intersect_compact_ref(
             bitmaps, _dput(l), _dput(r), _dput(s),
-            _dput_i32(min_sup), mode=mode)
-        sup_h, = self._read(sup)
-        sup_np = sup_h[:q]
-        mask = sup_np >= min_sup
-        sel = np.nonzero(mask)[0]
-        return LevelResult(mask=mask,
-                           supports=sup_np[sel].astype(np.int64),
-                           bitmaps=self._compact(out, sel))
+            _dput_i32(min_sup), _dput_i32(q), mode=mode)
+        return self._survivors(out, mask_dev, sup, q)
 
 
 # ---------------------------------------------------------------------------
@@ -911,10 +732,8 @@ class PallasEngine(Engine):
     """
 
     def __init__(self, bucket_min: int = 128, interpret: Optional[bool] = None,
-                 *, block_w: Optional[int] = None, compact: bool = True,
-                 autotune: bool = False, compact_min: Optional[int] = None):
-        super().__init__(bucket_min, block_w=block_w, compact=compact,
-                         autotune=autotune, compact_min=compact_min)
+                 *, compact_min: Optional[int] = None):
+        super().__init__(bucket_min, compact_min=compact_min)
         self.interpret = interpret
 
     def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
@@ -924,29 +743,11 @@ class PallasEngine(Engine):
         qb, l, r, s = self.buffers.fill(left, right, sup_left)
         self._record_padding(q, qb)
         self._note_call(bitmaps, qb, mode)
-        self._maybe_tune(qb, bitmaps.shape[1], mode)
-        if self.compact:
-            inter, sup, mask_dev, n_surv = fused_intersect_compact(
-                bitmaps, _dput(l), _dput(r), _dput(s),
-                _dput_i32(min_sup), _dput_i32(q), mode=mode,
-                block_w=self.block_w, interpret=self.interpret)
-            mask_h, sup_h = self._read(mask_dev, sup)
-            mask = mask_h[:q].astype(bool)
-            sup_np = sup_h[:q]
-            return LevelResult(mask=mask,
-                               supports=sup_np[mask].astype(np.int64),
-                               bitmaps=self._slice_survivors(inter, int(mask.sum())))
-        inter, sup, mask_dev = fused_intersect(
+        inter, sup, mask_dev, _ = fused_intersect_compact(
             bitmaps, _dput(l), _dput(r), _dput(s),
-            _dput_i32(min_sup), mode=mode, block_w=self.block_w,
+            _dput_i32(min_sup), _dput_i32(q), mode=mode,
             interpret=self.interpret)
-        mask_h, sup_h = self._read(mask_dev, sup)
-        mask = mask_h[:q].astype(bool)
-        sup_np = sup_h[:q]
-        sel = np.nonzero(mask)[0]
-        return LevelResult(mask=mask,
-                           supports=sup_np[sel].astype(np.int64),
-                           bitmaps=self._compact(inter, sel))
+        return self._survivors(inter, mask_dev, sup, q)
 
 
 # ---------------------------------------------------------------------------
@@ -962,10 +763,8 @@ class ShardedEngine(Engine):
     def __init__(self, mesh: jax.sharding.Mesh, bucket_min: int = 128,
                  axis: str = "data", inner: str = "pallas",
                  interpret: Optional[bool] = None,
-                 *, block_w: Optional[int] = None, compact: bool = True,
-                 autotune: bool = False, compact_min: Optional[int] = None):
-        super().__init__(bucket_min, block_w=block_w, compact=compact,
-                         autotune=autotune, compact_min=compact_min)
+                 *, compact_min: Optional[int] = None):
+        super().__init__(bucket_min, compact_min=compact_min)
         self.mesh = mesh
         self.axis = axis
         self.inner = inner
@@ -979,12 +778,10 @@ class ShardedEngine(Engine):
 
         def _local(bms, l, r, s, msup, _mode):
             if inner == "pallas":
-                # block_w=None resolves through the autotune table at trace
-                # time (shard-local shapes), so tuned widths reach the
-                # shard_map body without re-plumbing
+                # the tile width is derived at trace time from the
+                # shard-local shapes
                 inter, sup, _ = fused_intersect(bms, l, r, s, msup,
                                                 mode=_mode,
-                                                block_w=self.block_w,
                                                 interpret=interpret)
             else:
                 inter, sup, _ = fused_intersect_ref(bms, l, r, s, msup,
@@ -1014,8 +811,6 @@ class ShardedEngine(Engine):
             left, right, sup_left, device_of_pair, d, self.buffers.floor)
         self.device_pair_counts.append(counts)
         self._record_padding(q, d * qmax)
-        # tune the shard-LOCAL trace shape: qmax pairs over the full width
-        self._maybe_tune(qmax, bitmaps.shape[1], mode)
         out, sup = self._sharded[mode](
             bitmaps,
             _dput(lpad.reshape(d * qmax), self._pair_sharding),
@@ -1084,8 +879,8 @@ class _WordShardedFrontierMixin:
         return self._ensure_sharded(bitmaps)
 
     def _build_partial_kernels(self, inner: str, interpret: Optional[bool],
-                               pair_spec: P, block_spec: P,
-                               compact: bool = False) -> Dict[int, Callable]:
+                               pair_spec: P, block_spec: P
+                               ) -> Dict[int, Callable]:
         """Per-mode ``jit(shard_map)`` executors over the partial fused
         kernel: shard-local intersect + popcount, one psum over the word
         (data) axis only — class shards, if any, own disjoint pair blocks
@@ -1095,10 +890,10 @@ class _WordShardedFrontierMixin:
         ``P(None, data)`` for ``tidsharded`` (pairs replicated),
         ``P(class)`` / ``P(class, data)`` for ``grid`` (pairs split).
 
-        ``compact=True`` (tidsharded only — its pairs are replicated, so
-        survivor order is globally consistent across shards) additionally
-        runs the survivor-compaction epilogue *inside* the shard_map body:
-        the post-psum mask is replicated, so every shard gathers the same
+        A backend whose ``compacts_in_executable`` is set (tidsharded — its
+        pairs are replicated, so survivor order is globally consistent
+        across shards) additionally runs the survivor-compaction epilogue
+        *inside* the shard_map body: the post-psum mask is replicated, so every shard gathers the same
         survivor rows out of its own word slice, and the padded (Q, W)
         block never exists outside the executable.  Callers pass the true
         pair count ``n_valid`` as an extra traced operand (bucket-pad pairs
@@ -1111,7 +906,6 @@ class _WordShardedFrontierMixin:
         def _local(bms, l, r, s, msup, _mode):
             if inner == "pallas":
                 inter, pop = fused_intersect_partial(bms, l, r, mode=_mode,
-                                                     block_w=self.block_w,
                                                      interpret=interpret)
             else:
                 inter, pop = fused_intersect_partial_ref(bms, l, r, mode=_mode)
@@ -1122,7 +916,7 @@ class _WordShardedFrontierMixin:
 
         # pallas_call has no shard_map replication rule -> unchecked variant
         smap = shard_map_unchecked if inner == "pallas" else shard_map
-        if compact:
+        if self.compacts_in_executable:
             def _local_compact(bms, l, r, s, msup, nv, _mode):
                 inter, sup, mask = _local(bms, l, r, s, msup, _mode)
                 return compact_epilogue(inter, sup, mask, nv)
@@ -1166,27 +960,25 @@ class TidShardedEngine(_WordShardedFrontierMixin, Engine):
     Per expansion, every shard intersects and popcounts its word slice for
     *all* pairs (the partial kernel), one ``psum`` across shards turns the
     partial counts into supports, and the min-support mask is applied to the
-    reduced value.  Survivor compaction is shard-local: with ``compact``
-    (default) the prefix-sum compaction epilogue runs *inside* the shard_map
-    executable — the post-psum mask is replicated, so every shard gathers
-    the same survivor rows out of its own word slice in the same dispatch —
-    and with ``compact=False`` it is a separate row gather under a
-    ``P(None, axis)`` constraint.  Either way the full (Q, W) intersection
-    block never materializes on any single device, the host, or the
-    interconnect — only the (Q,) count vector crosses shards.  This is the mode that lets a
-    window larger than one device's memory stay minable (DESIGN.md §7);
-    trade-off vs the pair-sharded engine: every device does every pair's
-    AND, but on 1/n of the words, so compute per device is unchanged while
-    memory drops ~1/n.
+    reduced value.  Survivor compaction is shard-local: the prefix-sum
+    compaction epilogue runs *inside* the shard_map executable — the
+    post-psum mask is replicated, so every shard gathers the same survivor
+    rows out of its own word slice in the same dispatch.  The full (Q, W)
+    intersection block never materializes on any single device, the host,
+    or the interconnect — only the (Q,) count vector crosses shards.  This
+    is the mode that lets a window larger than one device's memory stay
+    minable (DESIGN.md §7); trade-off vs the pair-sharded engine: every
+    device does every pair's AND, but on 1/n of the words, so compute per
+    device is unchanged while memory drops ~1/n.
     """
+
+    compacts_in_executable = True
 
     def __init__(self, mesh: jax.sharding.Mesh, bucket_min: int = 128,
                  axis: str = "data", inner: str = "pallas",
                  interpret: Optional[bool] = None,
-                 *, block_w: Optional[int] = None, compact: bool = True,
-                 autotune: bool = False, compact_min: Optional[int] = None):
-        super().__init__(bucket_min, block_w=block_w, compact=compact,
-                         autotune=autotune, compact_min=compact_min)
+                 *, compact_min: Optional[int] = None):
+        super().__init__(bucket_min, compact_min=compact_min)
         self.inner = inner
         self.interpret = interpret
         self._init_word_axis(mesh, axis)
@@ -1195,8 +987,7 @@ class TidShardedEngine(_WordShardedFrontierMixin, Engine):
         # pair device to the drivers
         self.n_devices = 1
         self._sharded = self._build_partial_kernels(inner, interpret,
-                                                    P(), self._spec,
-                                                    compact=self.compact)
+                                                    P(), self._spec)
 
     def stats(self, since=None) -> dict:
         out = super().stats(since=since)
@@ -1210,31 +1001,13 @@ class TidShardedEngine(_WordShardedFrontierMixin, Engine):
         qb, l, r, s = self.buffers.fill(left, right, sup_left)
         self._record_padding(q, qb)
         bitmaps = self._ensure_sharded(bitmaps)
-        self._maybe_tune(qb, bitmaps.shape[1] // self.n_shards, mode)
-        if self.compact:
-            rep = self._rep_sharding
-            inter, sup, mask_dev, _ = self._sharded[mode](
-                bitmaps, _dput(l, rep), _dput(r, rep), _dput(s, rep),
-                _dput_i32(min_sup, rep), _dput_i32(q, rep))
-            mask_h, sup_h = self._read(mask_dev, sup)
-            mask = mask_h[:q].astype(bool)
-            sup_np = sup_h[:q]
-            surv = jax.device_put(
-                self._slice_survivors(inter, int(mask.sum())), self._sharding)
-            return LevelResult(mask=mask,
-                               supports=sup_np[mask].astype(np.int64),
-                               bitmaps=surv)
         rep = self._rep_sharding
-        inter, sup, mask_dev = self._sharded[mode](
+        inter, sup, mask_dev, _ = self._sharded[mode](
             bitmaps, _dput(l, rep), _dput(r, rep), _dput(s, rep),
-            _dput_i32(min_sup, rep))
-        mask_h, sup_h = self._read(mask_dev, sup)
-        mask = mask_h[:q].astype(bool)
-        sup_np = sup_h[:q]
-        sel = np.nonzero(mask)[0]
-        return LevelResult(mask=mask,
-                           supports=sup_np[sel].astype(np.int64),
-                           bitmaps=self._compact(inter, sel))
+            _dput_i32(min_sup, rep), _dput_i32(q, rep))
+        res = self._survivors(inter, mask_dev, sup, q)
+        res.bitmaps = jax.device_put(res.bitmaps, self._sharding)
+        return res
 
 
 # ---------------------------------------------------------------------------
@@ -1269,17 +1042,16 @@ class GridShardedEngine(_WordShardedFrontierMixin, Engine):
     separately (executor count, database size), composed on one mesh.
     """
 
+    # survivors live in per-class pad blocks whose order differs from global
+    # pair order, so in-executable compaction would emit them class-blocked:
+    # they are gathered after the read (Engine._compact)
+    compacts_in_executable = False
+
     def __init__(self, mesh: jax.sharding.Mesh, bucket_min: int = 128,
                  class_axis: str = "class", data_axis: str = "data",
                  inner: str = "pallas", interpret: Optional[bool] = None,
-                 *, block_w: Optional[int] = None, compact: bool = True,
-                 autotune: bool = False, compact_min: Optional[int] = None):
-        # grid keeps the post-gather compaction path: its survivors live in
-        # per-class pad blocks whose order differs from global pair order,
-        # so in-executable compaction would emit them class-blocked;
-        # `compact` still tightens the survivor rung via _compact.
-        super().__init__(bucket_min, block_w=block_w, compact=compact,
-                         autotune=autotune, compact_min=compact_min)
+                 *, compact_min: Optional[int] = None):
+        super().__init__(bucket_min, compact_min=compact_min)
         missing = [a for a in (class_axis, data_axis)
                    if a not in mesh.axis_names]
         if missing:
@@ -1316,7 +1088,6 @@ class GridShardedEngine(_WordShardedFrontierMixin, Engine):
         self.device_pair_counts.append(counts)
         self._record_padding(q, d * qmax)
         bitmaps = self._ensure_sharded(bitmaps)
-        self._maybe_tune(qmax, bitmaps.shape[1] // self.n_shards, mode)
         inter, sup, mask_dev = self._sharded[mode](
             bitmaps,
             _dput(lpad.reshape(d * qmax), self._pair_vec_sharding),

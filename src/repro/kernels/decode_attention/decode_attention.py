@@ -1,6 +1,6 @@
 """Pallas TPU kernel: grouped-query decode attention over a KV cache.
 
-The decode-cell rooflines (EXPERIMENTS §Roofline) are KV-read bound; this
+Decode is KV-read bound (its roofline's memory term dominates); this
 kernel streams the cache once through VMEM in (block_s) tiles with an online
 softmax, computing all G query heads of a KV group against each tile — KV
 bytes are read exactly once per group instead of once per query head.

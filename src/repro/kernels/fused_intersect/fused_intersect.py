@@ -35,9 +35,8 @@ Block shapes are the ones Mosaic accepts (the TPU compile tests in
   scalar-prefetch operand: 8 bytes per pair of the 1 MiB SMEM.
   :data:`MAX_PAIRS_PER_CALL` is the per-call cap the engine never exceeds.
 
-``block_w`` is resolved by the ``ops`` dispatch layer (autotuned table or
-cost-model seed, ``repro.kernels.autotune``); ``DEFAULT_BLOCK_W`` is the
-fallback value only.
+``block_w`` is resolved by the ``ops`` dispatch layer from the cost model
+(``ops.resolve_block_w``); ``DEFAULT_BLOCK_W`` is the fallback value only.
 """
 from __future__ import annotations
 

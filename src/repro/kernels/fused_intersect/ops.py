@@ -10,21 +10,22 @@ reported by the engine's ``stats()["kernel_path"]``, never silently:
                  is what a CPU host runs when no interpreter is requested.
 
 ``block_w`` resolution: ``None`` (the default everywhere above this layer)
-consults the autotuned shape->config table (``repro.kernels.autotune``) at
-trace time, so tuned tile widths reach every call site — including the
-shard_map-wrapped partial kernels, whose bodies trace through here — without
-threading a width through every driver.  An explicit ``block_w`` (config /
-CLI override) wins over the table.
+is derived at trace time by :func:`resolve_block_w` from the call's shape
+and the cost model, so every call site — including the shard_map-wrapped
+partial kernels, whose bodies trace through here — gets the same width
+without threading one through every driver.  An explicit ``block_w``
+(kernel-level tests, compiles for a described chip) wins.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import List, Optional
 
 import jax
 
-from .fused_intersect import (fused_intersect_compact_pairs,
+from ...analysis.roofline import intersect_cost
+from .fused_intersect import (DEFAULT_BLOCK_W, fused_intersect_compact_pairs,
                               fused_intersect_pairs,
-                              fused_intersect_partial_pairs)
+                              fused_intersect_partial_pairs, round_up_lanes)
 from .ref import (fused_intersect_compact_ref, fused_intersect_partial_ref,
                   fused_intersect_ref)
 
@@ -39,13 +40,49 @@ def kernel_path(interpret: Optional[bool] = None) -> str:
     return "mosaic"
 
 
-def resolve_block_w(block_w, q: int, w: int, mode: int) -> int:
-    """Explicit width if given, else the autotuned (or cost-model-seeded)
-    width for this call's shape class."""
+# candidate tile widths: every lane-aligned power of two the pipeline can
+# reasonably hold double-buffered in VMEM ((1, bw) uint32 blocks x 2 rows
+# x 2 buffers -> 8 KiB/lane-k at bw=2048)
+_POW2_CANDIDATES = (128, 256, 512, 1024, 2048)
+
+
+def _kind() -> str:
+    return "tpu" if jax.default_backend() == "tpu" else "xla"
+
+
+def block_w_candidates(w: int, kind: Optional[str] = None) -> List[int]:
+    """Lane-aligned candidate tile widths for a row of ``w`` words: the
+    power-of-two ladder capped at the lane-padded row width, plus the
+    padded width itself (the single-block tile).  Off-TPU (``kind="xla"``)
+    the fused XLA path has no tile parameter, so the list collapses to one
+    width."""
+    kind = _kind() if kind is None else kind
+    wp = round_up_lanes(w)
+    if kind == "xla":
+        return [min(DEFAULT_BLOCK_W, wp)]
+    return sorted({c for c in _POW2_CANDIDATES if c <= wp} | {wp})
+
+
+def seeded_candidates(q: int, w: int,
+                      kind: Optional[str] = None) -> List[int]:
+    """Candidates ordered by the roofline cost model, best predicted first:
+    ``intersect_cost`` charges per-block-step overhead (penalizing tiny
+    tiles) and padded-word streaming (penalizing over-wide tiles on narrow
+    rows)."""
+    return sorted(block_w_candidates(w, kind),
+                  key=lambda bw: intersect_cost(q, w, bw).bound_s)
+
+
+def resolve_block_w(block_w, q: int, w: int, mode: int,
+                    kind: Optional[str] = None) -> int:
+    """The word-tile width of a ``q``-pair call over ``w``-word rows: an
+    explicit ``block_w`` if given, else the cost model's pick among the
+    candidates (``kind`` ``"tpu"`` on a TPU, ``"xla"`` elsewhere, unless
+    given).  Derived, never measured: the same shape always compiles the
+    same tile, in every mode."""
     if block_w is not None:
         return int(block_w)
-    from .. import autotune
-    return autotune.lookup(q, w, mode).block_w
+    return seeded_candidates(q, w, kind)[0]
 
 
 def fused_intersect_partial(

@@ -2,7 +2,7 @@
 
 ``repro.core.eclat`` routes its pair batches through here so the hot loop is
 kernel-backed on real hardware while remaining exact (and fast enough) on the
-CPU host used for tests/benchmarks.
+CPU host used for tests (``tests/test_kernels.py``).
 """
 from __future__ import annotations
 

@@ -13,7 +13,8 @@ accumulates into the (bn, bn) C tile held in VMEM.
 Keeping the bitmap packed trades the MXU (which an int8 unpacked `B @ B.T`
 would use) for 32x less VMEM traffic per word — the right trade for wide
 transaction databases where the product is memory-bound; the unpacked MXU
-variant is `ref.cooccurrence_mxu_ref` and benchmarked in benchmarks/fim_kernels.
+variant is `ref.cooccurrence_mxu_ref`, checked against the same oracle in
+tests/test_kernels.py.
 """
 from __future__ import annotations
 

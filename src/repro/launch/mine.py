@@ -43,11 +43,8 @@ def main(argv=None):
                     choices=["v1", "v2", "v3", "v4", "v5", "v6"])
     ap.add_argument("--p", type=int, default=10)
     ap.add_argument("--backend", default="pallas",
-                    choices=["jnp", "pallas", "sharded", "tidsharded", "grid",
-                             "auto"],
-                    help="engine backend; 'auto' picks from the measured "
-                         "crossover table (BENCH_kerneltune.json, "
-                         "DESIGN.md §6), falling back to pallas")
+                    choices=["jnp", "pallas", "sharded", "tidsharded", "grid"],
+                    help="engine backend (DESIGN.md §6)")
     ap.add_argument("--shard", default="pairs",
                     choices=["pairs", "words", "grid"],
                     help="mesh split under a device mesh: candidate pairs, "
@@ -58,13 +55,6 @@ def main(argv=None):
                          "(default: auto-factorize the visible devices)")
     ap.add_argument("--diffsets", action="store_true",
                     help="dEclat diffsets (variant v6 only)")
-    ap.add_argument("--block-w", type=int, default=None, metavar="WORDS",
-                    help="fused-kernel word-tile width override (default: "
-                         "autotuned table / cost-model seed)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="tune-on-miss: measure untuned kernel shape classes "
-                         "before dispatching them (winners persist in the "
-                         "autotune cache)")
     ap.add_argument("--checkpoint-dir", default=None)
     ap.add_argument("--restore", action="store_true",
                     help="resume the deepest mining checkpoint in "
@@ -96,7 +86,6 @@ def main(argv=None):
                       use_diffsets=args.diffsets,
                       backend=args.backend, shard=args.shard,
                       mode=args.mode,
-                      block_w=args.block_w, autotune=args.autotune,
                       checkpoint_dir=args.checkpoint_dir,
                       checkpoint_every_level=args.checkpoint_dir is not None)
     from .mesh import mesh_for_mining

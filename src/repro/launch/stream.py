@@ -145,11 +145,8 @@ def main(argv=None):
     ap.add_argument("--drift-every", type=int, default=None,
                     help="re-seed the pattern pool every N batches")
     ap.add_argument("--backend", default="pallas",
-                    choices=["jnp", "pallas", "sharded", "tidsharded", "grid",
-                             "auto"],
-                    help="engine backend; 'auto' picks from the measured "
-                         "crossover table (BENCH_kerneltune.json, "
-                         "DESIGN.md §6), falling back to pallas")
+                    choices=["jnp", "pallas", "sharded", "tidsharded", "grid"],
+                    help="engine backend (DESIGN.md §6)")
     ap.add_argument("--shard", default="pairs",
                     choices=["pairs", "words", "grid"],
                     help="mesh split under a device mesh: candidate pairs "
@@ -158,12 +155,6 @@ def main(argv=None):
     ap.add_argument("--grid", default=None, metavar="RxC",
                     help="class x data mesh shape for --shard grid, e.g. 2x2 "
                          "(default: auto-factorize the visible devices)")
-    ap.add_argument("--block-w", type=int, default=None, metavar="WORDS",
-                    help="fused-kernel word-tile width override (default: "
-                         "autotuned table / cost-model seed)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="tune-on-miss: measure untuned kernel shape classes "
-                         "before dispatching them")
     ap.add_argument("--top-k", type=int, default=5)
     ap.add_argument("--min-conf", type=float, default=0.0,
                     help="if >0, also report association rules per slide")
@@ -232,8 +223,7 @@ def main(argv=None):
     else:
         cfg = StreamConfig(min_sup=args.min_sup, n_blocks=args.n_blocks,
                            block_txns=args.block_txns, backend=args.backend,
-                           shard=args.shard,
-                           block_w=args.block_w, autotune=args.autotune)
+                           shard=args.shard)
         backend, shard = args.backend, args.shard
         mesh = mesh_for_mining(backend, shard, args.grid)
         miner = StreamingMiner(spec.n_items, cfg, mesh=mesh,

@@ -19,7 +19,7 @@ Expert -> device placement reuses the paper's equivalence-class partitioners
 (``repro.core.partitioners``): balancing routed load over EP shards is the
 same irregular-work-unit assignment the paper solves for equivalence
 classes; ``expert_placement="greedy"`` permutes expert ids so heavy experts
-spread across the EP axis (benchmarks/moe_balance).  Capacity overflow drops
+spread across the EP axis.  Capacity overflow drops
 tokens (weight 0) — the padding-efficiency knob the paper's balance metric
 measures.
 """

@@ -15,8 +15,8 @@ answer -> version-stamped result, instrumented end to end.
   version-keyed caching, immutable snapshot handoff, and p50/p99/QPS
   instrumentation layers.
 * ``loadgen`` — deterministic query storms + the answer-checksum
-  verification oracle (``benchmarks/serving_bench.py``, the ``--serve``
-  drivers).
+  verification oracle (the ``--serve`` drivers;
+  ``tests/test_serving_frontend.py`` checks it).
 """
 from .admission import AdmissionConfig, QueryShed, ServingFrontend, Ticket
 from .cache import VersionedCache

@@ -10,8 +10,8 @@ onto answer slots with the paper's greedy-LPT balance objective (the same
 ``core.partitioners`` call that packs equivalence classes onto executors),
 and answers every query from **one** immutable window snapshot — so each
 answer is bit-identical to the same query answered synchronously at that
-``window_version``, which ``benchmarks/serving_bench.py`` re-checks by
-checksum.
+``window_version``, which ``tests/test_serving_frontend.py`` checks
+query by query against the synchronous answer.
 
 Backpressure: a full queue either *sheds* (``QueryShed`` raised to the
 client immediately) or *blocks* the submitter until space frees, per

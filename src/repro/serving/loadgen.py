@@ -1,7 +1,7 @@
 """Deterministic query storms + the answer-checksum verification oracle.
 
-Shared by the ``--serve`` drivers (``launch.stream`` / ``launch.serve``) and
-``benchmarks/serving_bench.py``: :func:`query_mix` builds a seeded,
+Used by the ``--serve`` drivers (``launch.stream`` / ``launch.serve``) and
+``tests/test_serving_frontend.py``: :func:`query_mix` builds a seeded,
 heterogeneous query population (top-k probes of wildly different ``k``,
 rule scans at several ``min_conf``), :func:`run_storm` fires it from
 concurrent client threads at the front end while the miner slides windows

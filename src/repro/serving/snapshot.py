@@ -12,7 +12,8 @@ The answer kernels here are the *only* implementation of top-k / support /
 rules in the repo; the synchronous :class:`~repro.serving.StreamQueryService`
 and the batched :class:`~repro.serving.ServingFrontend` both call them, so
 "batched answer == direct answer at the same version" is true by
-construction and re-checked by checksum in ``benchmarks/serving_bench.py``.
+construction and checked query by query in
+``tests/test_serving_frontend.py``.
 """
 from __future__ import annotations
 

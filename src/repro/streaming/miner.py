@@ -67,9 +67,6 @@ class StreamConfig:
     p: int = 10                    # partitions for the class table
     max_k: Optional[int] = None    # deepest itemset length to mine (>= 1); None = unbounded
     bucket_min: int = 128          # engine pair-buffer ladder floor (half-pow2 rungs)
-    block_w: Optional[int] = None  # fused-kernel word-tile width; None = autotuned table / cost-model seed
-    autotune: bool = False         # tune-on-miss: measure untuned kernel shapes before dispatching them
-    compact: bool = True           # in-executable survivor compaction (False = legacy mask-roundtrip + gather)
 
     def resolve_min_sup(self, n_txn: int) -> int:
         return resolve_min_sup(self.min_sup, n_txn)
@@ -188,17 +185,9 @@ class StreamingMiner:
         # incremental state: co-occurrence counts over the item universe;
         # per-item supports are its diagonal
         self.cooc = np.zeros((n_items, n_items), np.int64)
-        # dispatch hints for backend="auto": the steady-state expansion is
-        # bounded by the window's item universe and ring capacity
-        est_q = max(n_items * (n_items - 1) // 2, 1)
-        est_w = max(-(-int(config.n_blocks) * int(config.block_txns) // 32), 1)
         self.engine = eng.resolve_engine(config.backend, mesh,
                                          bucket_min=config.bucket_min,
-                                         shard=config.shard,
-                                         block_w=config.block_w,
-                                         autotune=config.autotune,
-                                         compact=config.compact,
-                                         hints=(est_q, est_w))
+                                         shard=config.shard)
         self.engine.trace_prefix = "slide."
         self._prev_frequent: Optional[np.ndarray] = None
         # monotonic window-content stamp: bumped once per completed push();

@@ -181,3 +181,56 @@ def test_async_checkpointer_surfaces_writer_error_on_wait(tmp_path):
     ck.save(3, tree())               # the checkpointer survives the fault
     ck.wait()
     assert valid_steps(str(tmp_path)) == [1, 3]
+
+
+# an engine snapshot as the tree before the block_w / compact / autotune
+# knobs were retired wrote it: those keys are present and must be ignored
+PARENT_ENGINE_EXTRA = {
+    "pallas": {"mesh": None, "block_w": None, "compact": True,
+               "autotune": False},
+    "tidsharded": {"mesh": {"axes": ["data"], "shape": [4]},
+                   "block_w": 256, "compact": False, "autotune": False},
+    "grid": {"mesh": {"axes": ["class", "data"], "shape": [2, 2]},
+             "block_w": None, "compact": True, "autotune": True},
+}
+
+
+@pytest.mark.parametrize("backend", sorted(PARENT_ENGINE_EXTRA))
+def test_engine_state_restores_parent_tree(backend, host_devices):
+    from repro.core import engine as eng
+    from repro.dist.compat import make_mesh
+    extra = {"backend": backend, "inner": "pallas", "bucket_min": 8,
+             "compact_min": 8, "interpret": None,
+             "n_intersections": 300, "n_padded": 84,
+             **PARENT_ENGINE_EXTRA[backend]}
+    tree = {"level_padding": np.asarray([[100, 128], [200, 256]], np.int64)}
+    mesh = None
+    if backend == "tidsharded":
+        mesh = make_mesh((4,), ("data",))
+    elif backend == "grid":
+        mesh = make_mesh((2, 2), ("class", "data"),
+                         devices=jax.devices()[:4])
+        tree["device_pair_counts"] = np.asarray([[60, 40], [120, 80]],
+                                                np.int64)
+    e = eng.engine_from_state(eng.EngineState.from_tree(tree, extra), mesh)
+    assert e.name == backend and e.buffers.floor == 8 and e.compact_min == 8
+    st = e.stats()
+    assert (st["n_intersections"], st["n_padded"]) == (300, 84)
+    assert [(lv["pairs"], lv["padded_to"])
+            for lv in st["pair_padding"]["per_level"]] == [(100, 128),
+                                                            (200, 256)]
+    if backend == "grid":
+        assert st["device_balance"]["pairs_per_device"] == [180, 120]
+    # the rebuilt engine expands, and its own snapshot drops the old keys
+    rng = np.random.default_rng(0)
+    bm = rng.integers(0, 2 ** 32, (6, 8), dtype=np.uint32)
+    left, right = np.asarray([0, 1, 2], np.int32), np.asarray([3, 4, 5],
+                                                               np.int32)
+    res = e.expand(jnp.asarray(bm), left, right,
+                   np.full(3, 256, np.int32), mode=eng.MODE_TIDSET,
+                   min_sup=0)
+    want = [int(np.unpackbits((bm[a] & bm[b]).view(np.uint8)).sum())
+            for a, b in zip(left, right)]
+    assert res.supports.tolist() == want
+    _, new_extra = e.snapshot_state().to_tree()
+    assert not {"block_w", "compact", "autotune"} & set(new_extra)

@@ -274,10 +274,19 @@ def test_mine_mesh_routes_to_sharded():
     assert "device_balance" in res.stats
 
 
-def test_mine_legacy_batched_alias():
-    res = mine(DB, 10, EclatConfig(min_sup=25, variant="v4", p=3, backend="batched"))
-    assert res.stats["backend"] == "pallas"
-    assert res.support_map() == ORACLE
+@pytest.mark.parametrize("entry", ["mine", "StreamingMiner"])
+@pytest.mark.parametrize("name", ["auto", "batched"])
+def test_removed_backend_names_raise(name, entry):
+    """The measured-dispatch name and its alias are gone: naming one is the
+    unknown-backend error, listing the backends there are."""
+    with pytest.raises(ValueError, match="unknown engine backend") as err:
+        if entry == "mine":
+            mine(DB, 10, EclatConfig(min_sup=25, backend=name))
+        else:
+            from repro.streaming import StreamConfig, StreamingMiner
+            StreamingMiner(10, StreamConfig(min_sup=25, n_blocks=2,
+                                            block_txns=64, backend=name))
+    assert str(eng.available_backends()) in str(err.value)
 
 
 # ---------------------------------------------------------------------------

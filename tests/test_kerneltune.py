@@ -1,4 +1,4 @@
-"""Kernel-tune suite (ISSUE 7): the raw-speed pass must not change answers.
+"""Kernel-tune suite: the compacting kernel and its tile width.
 
 Three layers under test:
 
@@ -7,46 +7,27 @@ Three layers under test:
     ``fused_intersect_compact_ref`` bit-for-bit across modes and the edge
     regimes the epilogue has to get right: W not a multiple of ``block_w``,
     zero survivors, all survivors, and ``n_valid < Q`` bucket padding.
-2.  **Autotuner mechanics** — shape classes, candidate ladders (including the
-    honest single-candidate collapse off-TPU), cost-model-seeded ordering,
-    the persistent table (round-trip, corrupt-cache-as-miss), and
-    ``tune_shape``/``lookup`` end to end under ``interpret=True``.
-3.  **Measured dispatch** — ``DispatchPolicy`` nearest-cell choice from a
-    crossover table and ``resolve_engine("auto")`` routing with safe
-    fallback when no table exists.
-
-Plus the engine-level guarantee that ties it together: ``compact=True`` (one
-fused dispatch, survivors only) and ``compact=False`` (legacy mask-roundtrip
-two-step) mine identical itemsets.
+2.  **Tile width** — candidate ladders (including the single-candidate
+    collapse off-TPU), cost-model ordering, and ``resolve_block_w``: the
+    width a TPU compiles is derived from the shape, pinned here at the
+    deployments' row widths.
+3.  **Engine level** — a mine through the one compact expand path equals
+    the brute-force oracle on each single-device backend.
 """
-import json
-import os
-
 import numpy as np
 import pytest
-import jax
 import jax.numpy as jnp
 
 from repro.core import EclatConfig, bruteforce_fim, mine
 from repro.core import engine as eng
-from repro.kernels import autotune
-from repro.kernels.fused_intersect import (DEFAULT_BLOCK_W, compact_epilogue,
+from repro.kernels.fused_intersect import (DEFAULT_BLOCK_W, block_w_candidates,
+                                           compact_epilogue,
                                            fused_intersect_compact_pairs,
                                            fused_intersect_compact_ref,
-                                           round_up_lanes)
+                                           resolve_block_w, round_up_lanes,
+                                           seeded_candidates)
 
 MODES = [eng.MODE_TIDSET, eng.MODE_TID_TO_DIFF, eng.MODE_DIFFSET]
-
-
-@pytest.fixture
-def tune_cache(tmp_path, monkeypatch):
-    """Point the autotune cache at a throwaway file and drop the in-process
-    table around the test, so tests neither read nor pollute the real cache."""
-    path = str(tmp_path / "autotune.json")
-    monkeypatch.setenv(autotune.CACHE_ENV, path)
-    autotune.reset()
-    yield path
-    autotune.reset()
 
 
 def _case(q, w, seed=0):
@@ -121,223 +102,56 @@ def test_compact_epilogue_empty():
 
 
 # ---------------------------------------------------------------------------
-# 2. autotuner mechanics
+# 2. tile width: candidates, cost-model order, the derived width
 # ---------------------------------------------------------------------------
-
-def test_shape_class_buckets():
-    assert autotune.shape_class(1000, 100, 0, "xla") == "q1024_w128_m0_xla"
-    # every q on the same pow2 rung shares the class
-    assert (autotune.shape_class(513, 100, 0, "xla")
-            == autotune.shape_class(1024, 100, 0, "xla"))
-    # mode and kind split classes
-    assert (autotune.shape_class(1000, 100, 1, "xla")
-            != autotune.shape_class(1000, 100, 0, "xla"))
-    assert (autotune.shape_class(1000, 100, 0, "tpu")
-            != autotune.shape_class(1000, 100, 0, "xla"))
-
 
 def test_candidates_xla_collapse():
     """Off-TPU the fused path is one XLA executable with no tile knob: the
-    candidate list must collapse to a single width (an honest tuner does not
-    sweep a parameter the executable ignores)."""
+    candidate list must collapse to a single width (there is no width for
+    the executable to ignore)."""
     for w in (5, 100, 600, 4000):
-        cands = autotune.block_w_candidates(w, "xla")
+        cands = block_w_candidates(w, "xla")
         assert cands == [min(DEFAULT_BLOCK_W, round_up_lanes(w))]
 
 
 def test_candidates_tpu_ladder():
-    assert autotune.block_w_candidates(2000, "tpu") == [128, 256, 512, 1024,
-                                                        2048]
-    assert autotune.block_w_candidates(100, "tpu") == [128]
+    assert block_w_candidates(2000, "tpu") == [128, 256, 512, 1024, 2048]
+    assert block_w_candidates(100, "tpu") == [128]
     # non-pow2 padded width joins the ladder as the single-block tile
-    assert 384 in autotune.block_w_candidates(300, "tpu")
-    for bw in autotune.block_w_candidates(700, "tpu"):
+    assert 384 in block_w_candidates(300, "tpu")
+    for bw in block_w_candidates(700, "tpu"):
         assert bw % 128 == 0
 
 
 def test_seeded_candidates_is_ordered_permutation():
-    cands = autotune.block_w_candidates(2000, "tpu")
-    seeded = autotune.seeded_candidates(4096, 2000, "tpu")
+    cands = block_w_candidates(2000, "tpu")
+    seeded = seeded_candidates(4096, 2000, "tpu")
     assert sorted(seeded) == cands
 
 
-def test_table_roundtrip(tune_cache):
-    t = autotune.AutotuneTable(tune_cache)
-    t.put("q64_w128_m0_tpu", autotune.KernelConfig(block_w=256),
-          measured_s=1e-4)
-    t.save()
-    t2 = autotune.AutotuneTable(tune_cache).load()
-    cfg = t2.get("q64_w128_m0_tpu")
-    assert cfg is not None and cfg.block_w == 256
-    assert t2.entries["q64_w128_m0_tpu"]["source"] == "measured"
+def test_lookup_miss_returns_cost_model_seed():
+    """With no explicit width, the width is the cost model's first pick."""
+    assert (resolve_block_w(None, 64, 40, 0, "tpu")
+            == seeded_candidates(64, 40, "tpu")[0])
+    assert resolve_block_w(256, 64, 40, 0, "tpu") == 256
 
 
-def test_corrupt_cache_is_a_miss(tune_cache):
-    with open(tune_cache, "w") as f:
-        f.write("{not json")
-    t = autotune.AutotuneTable(tune_cache).load()
-    assert t.entries == {}
-    assert autotune.load_table(refresh=True).get("anything") is None
+# the width a TPU compiled at the parent tree (seeded_candidates(q, w,
+# "tpu")[0], computed once there), at a 4,000-basket block (125 words), a
+# 100,000-transaction frontier (3,125) and the 2M-transaction scale-up
+# (62,500), on the pair rungs from the smallest to the per-call cap
+PARENT_BLOCK_W = {125: 128, 3125: 3200, 62500: 62592}
 
 
-def test_lookup_miss_returns_cost_model_seed(tune_cache):
-    cfg = autotune.lookup(64, 40, 0, "tpu")
-    assert cfg.block_w == autotune.seeded_candidates(64, 40, "tpu")[0]
-
-
-def test_tune_shape_interpret_caches_winner(tune_cache):
-    rec = autotune.tune_shape(16, 8, 0, kind="interpret", reps=1)
-    assert rec["kind"] == "interpret"
-    assert str(rec["tuned_block_w"]) in rec["candidates"]
-    assert rec["model_pick"] == int(
-        autotune.seeded_candidates(16, 8, "tpu")[0])
-    # the winner landed in the persistent table under the tpu-class key...
-    assert os.path.exists(tune_cache)
-    cfg = autotune.lookup(16, 8, 0, "tpu")
-    assert cfg.block_w == rec["tuned_block_w"]
-    # ...and survives a cold reload
-    autotune.reset()
-    assert autotune.lookup(16, 8, 0, "tpu").block_w == rec["tuned_block_w"]
+@pytest.mark.parametrize("q", [128, 4096, 65536])
+@pytest.mark.parametrize("w", sorted(PARENT_BLOCK_W))
+def test_block_w_matches_parent_choice(w, q):
+    for mode in MODES:
+        assert resolve_block_w(None, q, w, mode, "tpu") == PARENT_BLOCK_W[w]
 
 
 # ---------------------------------------------------------------------------
-# 3. measured dispatch: DispatchPolicy + resolve_engine("auto")
-# ---------------------------------------------------------------------------
-
-FAKE_CELLS = [
-    {"q": 256, "w": 32, "best_single": "jnp", "best_mesh": "sharded"},
-    {"q": 16384, "w": 1024, "best_single": "pallas",
-     "best_mesh": "tidsharded"},
-    {"q": 4096, "w": 128, "best_single": "pallas"},   # no mesh sweep ran
-]
-
-
-@pytest.fixture
-def fake_table(tmp_path):
-    path = str(tmp_path / "BENCH_kerneltune.json")
-    with open(path, "w") as f:
-        json.dump({"jax_backend": jax.default_backend(),
-                   "crossover": FAKE_CELLS}, f)
-    return path
-
-
-def test_policy_nearest_cell(fake_table):
-    pol = eng.DispatchPolicy.load(fake_table)
-    assert pol is not None and pol.source == fake_table
-    assert pol.choose(100, 16) == "jnp"            # nearest (256, 32)
-    assert pol.choose(200000, 4096) == "pallas"    # nearest (16384, 1024)
-    assert pol.choose(100, 16, have_mesh=True) == "sharded"
-    assert pol.choose(200000, 4096, have_mesh=True) == "tidsharded"
-    # cell without a mesh sweep falls back to its single-device winner
-    assert pol.choose(4096, 128, have_mesh=True) == "pallas"
-
-
-def test_policy_missing_corrupt_empty(tmp_path):
-    assert eng.DispatchPolicy.load(str(tmp_path / "nope.json")) is None
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    assert eng.DispatchPolicy.load(str(bad)) is None
-    empty = tmp_path / "empty.json"
-    empty.write_text(json.dumps({"crossover": []}))
-    assert eng.DispatchPolicy.load(str(empty)) is None
-    # cells missing q/w/best_single are filtered -> empty -> None
-    junk = tmp_path / "junk.json"
-    junk.write_text(json.dumps({"crossover": [{"q": 1}]}))
-    assert eng.DispatchPolicy.load(str(junk)) is None
-
-
-def test_policy_skips_table_of_another_platform(tmp_path):
-    """A crossover table measured on another platform, or with none
-    recorded, never steers this one (the committed CPU table on a TPU)."""
-    for backend in ("tpu" if jax.default_backend() != "tpu" else "cpu", None):
-        path = tmp_path / f"{backend}.json"
-        table = {"crossover": FAKE_CELLS}
-        if backend is not None:
-            table["jax_backend"] = backend
-        path.write_text(json.dumps(table))
-        assert eng.DispatchPolicy.load(str(path)) is None
-        e = eng.resolve_engine("auto", policy_path=str(path), hints=(100, 16))
-        assert e.name == "pallas" and e.dispatch["policy"] is None
-
-
-def test_autotune_user_table_needs_opt_in(tmp_path, monkeypatch):
-    """The per-user autotune table is read only after --autotune opts in
-    (or REPRO_AUTOTUNE_CACHE names a table); by default lookup is the
-    cost-model seed, whatever sits in ~/.cache."""
-    monkeypatch.delenv(autotune.CACHE_ENV, raising=False)
-    monkeypatch.setenv("HOME", str(tmp_path))
-    monkeypatch.setattr(autotune, "_USE_USER_CACHE", False)
-    user = autotune.AutotuneTable(
-        os.path.expanduser(os.path.join("~", ".cache", "repro-eclat",
-                                        "autotune.json")))
-    seed = autotune.seeded_candidates(64, 1000, "tpu")[0]
-    planted = 128 if seed != 128 else 256
-    user.put(autotune.shape_class(64, 1000, 0, "tpu"),
-             autotune.KernelConfig(block_w=planted))
-    user.save()
-    autotune.reset()
-    try:
-        assert autotune.table_path() is None
-        assert autotune.lookup(64, 1000, 0, "tpu").block_w == seed
-        eng.make_engine("jnp", autotune=True)         # --autotune opts in
-        assert autotune.table_path() == user.path
-        assert autotune.lookup(64, 1000, 0, "tpu").block_w == planted
-    finally:
-        autotune.reset()
-
-
-def test_policy_env_path(fake_table, monkeypatch):
-    monkeypatch.setenv(eng.KERNELTUNE_ENV, fake_table)
-    pol = eng.DispatchPolicy.load()
-    assert pol is not None and pol.source == fake_table
-
-
-def test_resolve_auto_routes_by_hints(fake_table):
-    e = eng.resolve_engine("auto", policy_path=fake_table, hints=(100, 16))
-    assert e.name == "jnp"
-    assert e.dispatch == {"requested": "auto", "auto": True,
-                          "policy": fake_table}
-    e = eng.resolve_engine("auto", policy_path=fake_table,
-                           hints=(200000, 4096))
-    assert e.name == "pallas"
-
-
-def test_resolve_auto_mesh_overrides_shard(fake_table, host_devices):
-    """Under auto the policy picks the backend; a policy choice of
-    ``tidsharded`` must override the default shard="pairs" instead of
-    raising the contradictory-request error."""
-    from repro.dist.compat import make_mesh
-    mesh = make_mesh((4,), ("data",))
-    e = eng.resolve_engine("auto", mesh, policy_path=fake_table,
-                           hints=(200000, 4096))
-    assert e.name == "tidsharded"
-    e = eng.resolve_engine("auto", mesh, policy_path=fake_table,
-                           hints=(100, 16))
-    assert e.name == "sharded"
-
-
-def test_resolve_auto_fallbacks(tmp_path):
-    # no table at the explicit path -> static default, dispatch records it
-    e = eng.resolve_engine("auto", policy_path=str(tmp_path / "nope.json"),
-                           hints=(100, 16))
-    assert e.name == "pallas"
-    assert e.dispatch["auto"] is True and e.dispatch["policy"] is None
-    # table but no hints -> static default
-    path = tmp_path / "t.json"
-    path.write_text(json.dumps({"crossover": FAKE_CELLS}))
-    e = eng.resolve_engine("auto", policy_path=str(path))
-    assert e.name == "pallas"
-
-
-def test_resolve_non_auto_unchanged(fake_table):
-    e = eng.resolve_engine("jnp", policy_path=fake_table, hints=(100, 16))
-    assert e.name == "jnp" and e.dispatch["auto"] is False
-    e = eng.resolve_engine("batched")
-    assert e.name == "pallas" and e.dispatch["requested"] == "batched"
-
-
-# ---------------------------------------------------------------------------
-# engine-level: compact vs legacy bit-identity + padding accounting
+# 3. engine level: the compact expand path against the oracle + accounting
 # ---------------------------------------------------------------------------
 
 def _db(seed=7, n_items=10, n_txn=150):
@@ -358,19 +172,18 @@ ORACLE = bruteforce_fim(DB, min_sup=25)
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
 def test_mine_compact_matches_legacy(backend):
-    maps = {}
-    for compact in (True, False):
-        res = mine(DB, 10, EclatConfig(min_sup=25, variant="v5", p=3,
-                                       backend=backend, bucket_min=32,
-                                       compact=compact))
-        maps[compact] = res.support_map()
-    assert maps[True] == maps[False] == ORACLE
+    """The in-executable compaction is each engine's one expand path: its
+    mine equals the brute-force oracle."""
+    res = mine(DB, 10, EclatConfig(min_sup=25, variant="v5", p=3,
+                                   backend=backend, bucket_min=32))
+    assert res.support_map() == ORACLE
 
 
 def test_mine_explicit_block_w_and_diffsets():
+    """v6 diffsets on pallas at the derived tile width."""
     res = mine(DB, 10, EclatConfig(min_sup=25, variant="v6", p=3,
                                    use_diffsets=True, backend="pallas",
-                                   bucket_min=32, block_w=256))
+                                   bucket_min=32))
     assert res.support_map() == ORACLE
 
 

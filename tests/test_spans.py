@@ -149,10 +149,9 @@ def test_incidences_count_distinct_items_per_transaction(quest, variant):
     assert res.stats["counts"]["incidences"] == sum(len(set(t)) for t in txns)
 
 
-@pytest.mark.parametrize("backend,compact", [("jnp", True), ("pallas", True),
-                                             ("pallas", False)])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
 def test_host_reads_are_cooc_blocks_plus_expand_reads(quest, monkeypatch,
-                                                      backend, compact):
+                                                      backend):
     txns, n_items = quest
     reads = []
     device_get = jax.device_get
@@ -161,7 +160,7 @@ def test_host_reads_are_cooc_blocks_plus_expand_reads(quest, monkeypatch,
         reads.append(1)
         return device_get(x)
     monkeypatch.setattr(jax, "device_get", counting)
-    res = mine(txns, n_items, _config(backend, compact=compact))
+    res = mine(txns, n_items, _config(backend))
     monkeypatch.undo()
     # single-device engines make one blocking read per kernel-sized call,
     # and record one padding entry per call

@@ -11,7 +11,7 @@ Shapes are the datasets' published widths: T10I4D100K's 100,000
 transactions pack into 3,125 words, chess's 3,196 into 100.  Pair counts are
 the smallest rung the engine issues and the per-call cap
 (``MAX_PAIRS_PER_CALL``); block widths are the ones the engine resolves on a
-TPU (``autotune.seeded_candidates(..., "tpu")``).
+TPU (``resolve_block_w(None, ..., "tpu")``).
 """
 import importlib
 import os
@@ -70,9 +70,8 @@ def rungs():
 
 def _compile(spec, kernel, mode, w, q, block_w=None):
     import jax.numpy as jnp
-    from repro.kernels import autotune
-    bw = (autotune.seeded_candidates(q, w, "tpu")[0] if block_w is None
-          else block_w)
+    from repro.kernels.fused_intersect import resolve_block_w
+    bw = resolve_block_w(block_w, q, w, mode, "tpu")
     bm = spec((ROWS, w), jnp.uint32)
     pair = spec((q,), jnp.int32)
     scalar = spec((), jnp.int32)
