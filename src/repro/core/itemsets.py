@@ -7,7 +7,7 @@ and implements ARM step 2 (confident rules) for completeness.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, Iterator, List, Tuple
 
 import numpy as np
 
@@ -26,7 +26,15 @@ class LevelRecord:
 
 
 class ItemsetStore:
-    """Accumulates LevelRecords and reconstructs explicit itemsets."""
+    """Accumulates LevelRecords and reconstructs explicit itemsets.
+
+    Reconstruction is per level, over arrays: level k's paths are an
+    ``(P_k, k)`` matrix of item ids, its parents' rows with the last item
+    appended, sorted along each row once to give the keys. ``itemsets()``
+    is in level order, then row order within a level; ``support_map()``
+    holds the same pairs in the same order. Ids and supports are Python
+    ``int``.
+    """
 
     def __init__(self, item_ids: np.ndarray):
         self._item_ids = np.asarray(item_ids, dtype=np.int64)
@@ -45,25 +53,30 @@ class ItemsetStore:
     def total(self) -> int:
         return int(sum(self.counts))
 
+    def _level_keys(self) -> Iterator[Tuple[Iterator[Tuple[int, ...]], List[int]]]:
+        """Per level: (sorted item-id tuple of each row, support of each row)."""
+        paths = np.zeros((0, 0), np.int64)
+        for rec in self.levels:
+            last = self._item_ids[np.asarray(rec.item_rank)][:, None]
+            if rec.k == 1:
+                paths = last
+            else:
+                paths = np.concatenate([paths[np.asarray(rec.parent)], last], axis=1)
+            keys = map(tuple, np.sort(paths, axis=1).tolist())
+            yield keys, np.asarray(rec.support).tolist()
+
     def itemsets(self) -> List[Tuple[Tuple[int, ...], int]]:
         """All frequent itemsets as (sorted item-id tuple, support)."""
         out: List[Tuple[Tuple[int, ...], int]] = []
-        prev_paths: List[Tuple[int, ...]] = []
-        for rec in self.levels:
-            paths: List[Tuple[int, ...]] = []
-            for r in range(rec.parent.shape[0]):
-                item = int(self._item_ids[rec.item_rank[r]])
-                if rec.k == 1:
-                    path = (item,)
-                else:
-                    path = prev_paths[int(rec.parent[r])] + (item,)
-                paths.append(path)
-                out.append((tuple(sorted(path)), int(rec.support[r])))
-            prev_paths = paths
+        for keys, supports in self._level_keys():
+            out.extend(zip(keys, supports))
         return out
 
     def support_map(self) -> Dict[Tuple[int, ...], int]:
-        return dict(self.itemsets())
+        out: Dict[Tuple[int, ...], int] = {}
+        for keys, supports in self._level_keys():
+            out.update(zip(keys, supports))
+        return out
 
 
 def generate_rules(
