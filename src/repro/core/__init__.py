@@ -2,6 +2,7 @@
 
 Public surface:
   mine / EclatConfig / EclatResult     level-wise RDD-Eclat, variants v1..v6
+  CSR                                   array input: (offsets, items)
   make_engine / available_backends      pluggable device-executor backends
   apriori_mine                          YAFIM-style Spark-Apriori baseline
   bruteforce_fim                        exact oracle for tests
@@ -12,6 +13,7 @@ Public surface:
   generate_rules                        ARM step 2
 """
 from .apriori import AprioriResult, apriori_mine
+from .bitmap import CSR
 from .eclat import VARIANTS, EclatConfig, EclatResult, mine, resume_mine
 from .engine import (Engine, EngineState, LevelResult, available_backends,
                      engine_from_state, make_engine, register_backend)
@@ -35,7 +37,7 @@ from .partitioners import (
 from .vertical import VerticalDB, build_vertical, filter_transactions
 
 __all__ = [
-    "AprioriResult", "apriori_mine",
+    "AprioriResult", "apriori_mine", "CSR",
     "VARIANTS", "EclatConfig", "EclatResult", "mine", "resume_mine",
     "Engine", "EngineState", "LevelResult", "available_backends",
     "engine_from_state", "make_engine", "register_backend",

@@ -19,6 +19,7 @@ used by the driver the way Spark's driver owns dataset prep) and a jnp form
 from __future__ import annotations
 
 import itertools
+from typing import NamedTuple
 
 import numpy as np
 import jax
@@ -29,6 +30,10 @@ _WORD_DTYPE = np.uint32
 
 __all__ = [
     "WORD_BITS",
+    "CSR",
+    "to_csr",
+    "check_csr",
+    "n_transactions",
     "n_words",
     "pack_bool_matrix",
     "unpack_bitmap",
@@ -78,6 +83,55 @@ def unpack_bitmap(packed: np.ndarray, n_txn: int) -> np.ndarray:
     return dense[:, :n_txn]
 
 
+class CSR(NamedTuple):
+    """A horizontal database as compressed sparse rows: transaction ``t``
+    lists ``items[offsets[t]:offsets[t + 1]]``.  The form a packed file is
+    read into, and what ``mine`` takes in place of item-id lists when the
+    database is too large to hold as Python objects."""
+
+    offsets: np.ndarray
+    items: np.ndarray
+
+
+def to_csr(transactions) -> CSR:
+    """Item-id lists -> :class:`CSR` (int64 offsets, int32 items)."""
+    txns = transactions if isinstance(transactions, (list, tuple)) else list(transactions)
+    lens = np.fromiter(map(len, txns), np.int64, count=len(txns))
+    items = np.fromiter(itertools.chain.from_iterable(txns), np.int32,
+                        count=int(lens.sum()))
+    return CSR(np.concatenate([[0], np.cumsum(lens)]), items)
+
+
+def n_transactions(transactions) -> int:
+    """Number of transactions of item-id lists or of a :class:`CSR`."""
+    if isinstance(transactions, CSR):
+        return int(np.shape(transactions.offsets)[0]) - 1
+    return len(transactions)
+
+
+def check_csr(csr: CSR, n_items: int):
+    """``csr``'s arrays, after checking that they describe a database over
+    ``[0, n_items)``: integer 1-D arrays, offsets that start at 0, never
+    descend and end at the number of items.  A malformed one raises
+    ``ValueError`` naming the first transaction at fault."""
+    offsets, items = np.asarray(csr.offsets), np.asarray(csr.items)
+    if (offsets.ndim != 1 or items.ndim != 1 or offsets.size == 0
+            or offsets.dtype.kind not in "iu" or items.dtype.kind not in "iu"):
+        raise ValueError("a CSR database needs 1-D integer offsets (at least "
+                         "one) and items")
+    if offsets[0] != 0 or offsets[-1] != items.size:
+        raise ValueError(f"CSR offsets must run from 0 to the {items.size} "
+                         f"items, got {int(offsets[0])}..{int(offsets[-1])}")
+    step = np.diff(offsets)
+    if step.size and step.min() < 0:
+        raise ValueError(f"CSR offsets descend at txn {int(np.argmax(step < 0))}")
+    if items.size and (items.min() < 0 or items.max() >= n_items):
+        bad = int(np.argmax((items < 0) | (items >= n_items)))
+        t = int(np.searchsorted(offsets, bad, side="right")) - 1
+        raise ValueError(f"txn {t} has item outside [0, {n_items})")
+    return offsets, items
+
+
 def flatten_transactions(transactions, n_items: int):
     """Flatten a horizontal database (iterable of item-id iterables) into its
     incidence arrays.
@@ -89,8 +143,14 @@ def flatten_transactions(transactions, n_items: int):
     ``np.fromiter`` over the chained transactions, with no ndarray per
     transaction.  Other iterables (generators, arrays of other ranks) are
     converted one transaction at a time.  An item outside ``[0, n_items)``
-    raises ``ValueError`` naming its transaction.
+    raises ``ValueError`` naming its transaction.  A :class:`CSR` is
+    checked (:func:`check_csr`) and expanded.
     """
+    if isinstance(transactions, CSR):
+        offsets, items = check_csr(transactions, n_items)
+        n_txn = offsets.size - 1
+        tids = np.repeat(np.arange(n_txn, dtype=np.int64), np.diff(offsets))
+        return tids, items.astype(np.int64), n_txn
     txns = transactions if isinstance(transactions, (list, tuple)) else list(transactions)
     n_txn = len(txns)
     try:

@@ -25,19 +25,20 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import bitmap as bm
 from . import engine as eng
 from .engine import merge_stats
 from .equivalence import class_segments, pair_work, segment_pairs
 from .itemsets import ItemsetStore, LevelRecord
 from .partitioners import assign_partitions, partition_stats
 from .triangular import cooc_blocks, cooccurrence_counts, frequent_pairs
-from .vertical import VerticalDB, build_vertical
+from .vertical import VerticalDB, build_vertical, build_vertical_sharded
 from ..spans import span
 
 # the batch miner's phases, as ``mine.<phase>`` spans and ``phase_s`` keys
@@ -203,17 +204,30 @@ def run_bottom_up(
                          lvl_bitmaps)
 
 
-def _build_db(transactions, n_items, abs_min_sup, spec, mesh) -> Tuple[VerticalDB, dict]:
+def _build_db(transactions, n_items, abs_min_sup, spec, mesh, execu=None,
+              phase_s=None) -> Tuple[VerticalDB, dict]:
     """The variant's vertical build: v2+ filter transactions before the
     pack, v3+ merge the per-shard partials by ``psum`` when given a mesh.
-    ``filter_reduction`` is the share of transactions the filter removed
-    (paper §5.2.1 reports e.g. 3.2%..25.8% for T40I10D100K)."""
-    db = build_vertical(transactions, n_items, abs_min_sup, order="support_asc",
-                        filter_txns=spec["filter_txns"],
-                        mesh=mesh if spec["accumulator"] else None)
+    Under a word-sharded engine every variant builds per shard on the
+    device (:func:`build_vertical_sharded`, the ``shard_build`` span), so
+    the frontier is born placed.  ``filter_reduction`` is the share of
+    transactions the filter removed (paper §5.2.1 reports e.g.
+    3.2%..25.8% for T40I10D100K)."""
+    word_axis = getattr(execu, "data_axis", None)
+    if word_axis is not None:
+        with _span("shard_build", phase_s):
+            db = build_vertical_sharded(
+                transactions, n_items, abs_min_sup, order="support_asc",
+                filter_txns=spec["filter_txns"], mesh=execu.mesh,
+                axis=word_axis)
+    else:
+        db = build_vertical(transactions, n_items, abs_min_sup,
+                            order="support_asc",
+                            filter_txns=spec["filter_txns"],
+                            mesh=mesh if spec["accumulator"] else None)
     info: dict = {}
     if spec["filter_txns"]:
-        n_txn = len(transactions)
+        n_txn = bm.n_transactions(transactions)
         info["filter_reduction"] = 1.0 - db.n_txn / n_txn if n_txn else 0.0
     return db, info
 
@@ -232,25 +246,32 @@ def _finish(store: ItemsetStore, db: VerticalDB, stats: dict,
 
 
 def mine(
-    transactions: Sequence[Sequence[int]],
+    transactions: Union[Sequence[Sequence[int]], bm.CSR],
     n_items: int,
     config: EclatConfig,
     mesh: Optional[jax.sharding.Mesh] = None,
 ) -> EclatResult:
-    """Mine all frequent itemsets.  ``mesh`` enables the mesh-mapped
-    backends (``config.shard`` picks pair-, word-, or 2D grid-sharding).
+    """Mine all frequent itemsets.  ``transactions`` are item-id lists or a
+    :class:`~repro.core.bitmap.CSR` pair ``(offsets, items)``, with the
+    same answer.  ``mesh`` enables the mesh-mapped backends
+    (``config.shard`` picks pair-, word-, or 2D grid-sharding).
 
     ``stats["phase_s"]`` times the phases, each a ``mine.<phase>`` span of
     the profiler trace inside the call's ``mine`` span, whose length is
     ``stats["total_s"]``: ``vertical`` (the vertical build), ``plan``
-    (store, partitions, engine, frontier upload), ``tri_matrix`` (level 2,
+    (store, partitions, frontier upload), ``tri_matrix`` (level 2,
     holding ``cooc``, the co-occurrence pass), ``bottom_up`` (levels >= 3,
     one ``mine.level`` span each) and ``expand_wait`` (the host blocked on
-    the engine's reads, inside the last two).  ``stats["counts"]`` holds
-    ``incidences`` (distinct (transaction, item) bits the vertical build
-    scattered) and ``host_reads`` (blocking device->host reads)."""
+    the engine's reads, inside the last two).  Under a word-sharded engine
+    ``vertical`` holds ``shard_build`` (the per-shard device build, from
+    the CSR upload to the placed frontier) and the co-occurrence pass is
+    ``shard_cooc`` (shard-local counts, one psum, one read).
+    ``stats["counts"]`` holds ``incidences`` (distinct (transaction, item)
+    bits the vertical build scattered), ``host_reads`` (blocking
+    device->host reads), ``psum_calls`` (the collectives the mine issued)
+    and ``host_bitmap_bytes`` (bitmap bytes that crossed the host)."""
     wall: dict = {}
-    with span("mine", wall, prefix="", n_txn=len(transactions),
+    with span("mine", wall, prefix="", n_txn=bm.n_transactions(transactions),
               n_items=int(n_items)):
         res = _mine(transactions, n_items, config, mesh)
     res.stats["total_s"] = wall["mine"]
@@ -275,15 +296,22 @@ def _mine(transactions, n_items, config, mesh) -> EclatResult:
     stats: dict = {"variant": config.variant, "phase_s": {}, "counts": {}}
     phase_s = stats["phase_s"]
 
-    n_txn = len(transactions)
+    n_txn = bm.n_transactions(transactions)
     abs_min_sup = config.resolve_min_sup(n_txn)
     stats["abs_min_sup"] = abs_min_sup
+    execu = eng.resolve_engine(config.backend, mesh,
+                               bucket_min=config.bucket_min,
+                               shard=config.shard)
+    word_axis = getattr(execu, "data_axis", None)
 
     # ---- Phase 1 (+2 filtering / +3 accumulator): vertical DB -------------
     with _span("vertical", phase_s):
-        db, info = _build_db(transactions, n_items, abs_min_sup, spec, mesh)
+        db, info = _build_db(transactions, n_items, abs_min_sup, spec, mesh,
+                             execu, phase_s)
     stats.update(info)
     stats["counts"]["incidences"] = db.n_incidences
+    stats["counts"]["host_bitmap_bytes"] = db.host_bitmap_bytes
+    stats["counts"]["psum_calls"] = db.psum_calls
     n1, w = db.n_items, db.n_words
     stats["n_freq_items"] = n1
     stats["n_words"] = w
@@ -296,9 +324,6 @@ def _mine(transactions, n_items, config, mesh) -> EclatResult:
         est = pair_work(sizes1 + 1, w)  # +1: member count of class r is n1-1-r
         eff_p = config.p if spec["partitioner"] in ("hash", "reverse_hash", "greedy") else max(n_classes, 1)
         table = assign_partitions(n_classes, spec["partitioner"], eff_p, work=est)
-        execu = eng.resolve_engine(config.backend, mesh,
-                                   bucket_min=config.bucket_min,
-                                   shard=config.shard)
         stats["backend"] = execu.name
         stats["backend_requested"] = config.backend
         # partition -> device round robin (mesh-mapped backends' pair axis)
@@ -335,8 +360,10 @@ def _mine(transactions, n_items, config, mesh) -> EclatResult:
             # place the level-1 frontier the way the backend carries it,
             # once — level 2 may expand it in several kernel-sized calls, and
             # per-call placement (a word-axis reshard for tidsharded) would
-            # repeat for each
-            bitmaps = execu.prepare_frontier(jax.device_put(db.bitmaps))
+            # repeat for each; a per-shard build is already placed
+            bitmaps = execu.prepare_frontier(
+                db.bitmaps if isinstance(db.bitmaps, jax.Array)
+                else jax.device_put(db.bitmaps))
     if not deep:
         merge_stats(stats, execu.stats())
         return _finish(store, db, stats, config)
@@ -352,8 +379,14 @@ def _mine(transactions, n_items, config, mesh) -> EclatResult:
         sup1 = db.supports.astype(np.int32)
         mode2 = eng.MODE_TID_TO_DIFF if diffsets else eng.MODE_TIDSET
         if tri:
-            with _span("cooc", phase_s):
-                counts2 = cooccurrence_counts(bitmaps)
+            if word_axis is None:
+                with _span("cooc", phase_s):
+                    counts2 = cooccurrence_counts(bitmaps)
+            else:
+                with _span("shard_cooc", phase_s):
+                    counts2 = cooccurrence_counts(bitmaps, mesh=execu.mesh,
+                                                  axis=word_axis)
+                stats["counts"]["psum_calls"] += 1
             stats["counts"]["host_reads"] = cooc_blocks(n1)
             iu, ju, _ = frequent_pairs(counts2, abs_min_sup)
         else:

@@ -57,8 +57,10 @@ per-call allocation or ``argsort`` churn for the single-device backends).
 The default floor is 128 — the ladder is discrete, so a low floor costs at
 most a handful of extra one-time compiles, while a high one (the old 1024)
 pads every small level up to 1024 pairs.
-No rung exceeds ``MAX_PAIRS_PER_CALL`` (the kernel's SMEM bound):
-``Engine.expand`` runs a larger level as several capped calls.
+No rung exceeds ``MAX_PAIRS_PER_CALL`` (the kernel's SMEM bound), nor the
+pairs whose ``(Q, W)`` block fits a device's share of its memory
+(:func:`block_budget`): ``Engine.expand`` runs a larger level as several
+capped calls.
 """
 from __future__ import annotations
 
@@ -81,7 +83,8 @@ from ..kernels.fused_intersect import (MAX_PAIRS_PER_CALL, MODE_DIFFSET,
                                        fused_intersect_compact_ref,
                                        fused_intersect_partial,
                                        fused_intersect_partial_ref,
-                                       fused_intersect_ref, kernel_path)
+                                       fused_intersect_ref, kernel_path,
+                                       round_up_lanes)
 from ..spans import span
 
 __all__ = [
@@ -89,6 +92,7 @@ __all__ = [
     "LevelResult", "Engine", "EngineState", "JnpEngine", "PallasEngine",
     "ShardedEngine", "TidShardedEngine", "GridShardedEngine",
     "group_pairs_by_device", "register_backend", "available_backends",
+    "block_budget", "call_cap",
     "make_engine", "engine_from_state", "resolve_engine", "merge_stats",
     "MAX_PAIRS_PER_CALL",
 ]
@@ -209,6 +213,39 @@ def bucket_size(n: int, floor: int) -> int:
             return h
         b <<= 1
     return b
+
+
+def rung_at_most(n: int, floor: int) -> int:
+    """Largest ladder rung <= ``n`` (the floor where ``n`` is below it)."""
+    best = b = max(int(floor), 1)
+    while b <= n:
+        best = b
+        h = b + (b >> 1)
+        if h > b and h <= n:
+            best = h
+        b <<= 1
+    return best
+
+
+# the devices' memory when a device reports none (the CPU): a TPU v5e's
+DEFAULT_DEVICE_BYTES = 16 * 10**9
+
+
+def block_budget(device) -> int:
+    """Bytes of one call's padded ``(Q, W)`` intersection block a device may
+    hold: a quarter of its memory.  The kernel writes the block and the
+    compaction epilogue a copy of it, so the call takes at most half, and
+    the frontier it reads and the survivors it leaves keep the rest."""
+    stats = device.memory_stats() or {}
+    return int(stats.get("bytes_limit", DEFAULT_DEVICE_BYTES)) // 4
+
+
+def call_cap(row_bytes: int, budget: int, floor: int) -> int:
+    """Pairs one call may carry: a ladder rung, at most
+    ``MAX_PAIRS_PER_CALL`` (SMEM) and at most the rows of ``row_bytes``
+    that ``budget`` holds (HBM)."""
+    fit = max(int(budget) // max(int(row_bytes), 1), 1)
+    return min(rung_at_most(fit, floor), MAX_PAIRS_PER_CALL)
 
 
 def pair_bucket(n: int, floor: int) -> int:
@@ -475,6 +512,12 @@ class Engine:
         self.level_padding: List[Tuple[int, int]] = []
         self.call_shapes: set = set()
         self.n_devices = 1
+        self.n_psums = 0
+        # bytes of a call's block each device may hold (block_budget); the
+        # mesh backends read their mesh's devices
+        self.block_budget = block_budget(jax.devices()[0])
+        # the row's words are split this many ways across devices
+        self.word_split = 1
         # host time blocked on expansions (``expand_wait``) and the blocking
         # reads behind it: timings of this process, not engine state
         self.phase_s: Dict[str, float] = {}
@@ -509,13 +552,13 @@ class Engine:
         """Intersect all (left[q], right[q]) frontier-row pairs, threshold at
         ``min_sup``, and return the device-compacted survivors.
 
-        A batch above ``MAX_PAIRS_PER_CALL`` pairs is split into calls of at
-        most that many; their survivors are concatenated in pair order, so
-        the result is bit-exact with one unbounded call."""
+        A batch above :meth:`call_cap` pairs is split into calls of at most
+        that many; their survivors are concatenated in pair order, so the
+        result is bit-exact with one unbounded call."""
         q = int(left.shape[0])
         if q == 0:
             return self._empty(bitmaps)
-        cap = MAX_PAIRS_PER_CALL
+        cap = self.call_cap(bitmaps)
         parts = [
             self._expand_call(
                 bitmaps, left[s:s + cap], right[s:s + cap],
@@ -536,9 +579,17 @@ class Engine:
             supports=np.concatenate([p.supports for p in parts]),
             bitmaps=self._compact(block, sel.astype(np.int32)))
 
+    def call_cap(self, bitmaps) -> int:
+        """Pairs per call over ``bitmaps``: capped by SMEM and by the bytes
+        of the block on each device (its words split ``word_split`` ways,
+        lane-padded), against ``block_budget``."""
+        words = -(-int(bitmaps.shape[1]) // self.word_split)
+        return call_cap(round_up_lanes(words) * 4, self.block_budget,
+                        self.buffers.floor)
+
     def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
                      device_of_pair=None) -> LevelResult:
-        """One kernel-sized batch (``0 < Q <= MAX_PAIRS_PER_CALL``)."""
+        """One kernel-sized batch (``0 < Q <= call_cap``)."""
         raise NotImplementedError
 
     def kernel_path(self) -> str:
@@ -635,19 +686,20 @@ class Engine:
         self.device_pair_counts = dpc
         return self
 
-    def snapshot(self) -> Tuple[int, int, int, int, int, float]:
+    def snapshot(self) -> Tuple[int, int, int, int, int, float, int]:
         """Counter snapshot, for per-call deltas on a long-lived engine
         (``stats(since=snapshot)`` — the streaming miner reports per-slide
         work, not lifetime totals)."""
         return (self.n_intersections, self.n_padded,
                 len(self.device_pair_counts), len(self.level_padding),
-                self.n_reads, self.phase_s.get("expand_wait", 0.0))
+                self.n_reads, self.phase_s.get("expand_wait", 0.0),
+                self.n_psums)
 
     def stats(self, since: Optional[Tuple] = None) -> dict:
         """Counters since ``since`` (or ever).  ``phase_s`` and ``counts``
         are sub-dicts a miner merges into its own (:func:`merge_stats`)."""
-        i0, p0, d0, l0, r0, w0 = ((tuple(since) + (0,) * 6)[:6] if since
-                                  else (0,) * 6)
+        i0, p0, d0, l0, r0, w0, s0 = ((tuple(since) + (0,) * 7)[:7]
+                                      if since else (0,) * 7)
         out = {
             "backend": self.name,
             "kernel_path": self.kernel_path(),
@@ -658,6 +710,8 @@ class Engine:
             out["phase_s"] = {
                 "expand_wait": self.phase_s.get("expand_wait", 0.0) - w0}
             out["counts"] = {"host_reads": self.n_reads - r0}
+        if self.n_psums > s0:
+            out.setdefault("counts", {})["psum_calls"] = self.n_psums - s0
         if self.call_shapes:
             out["call_shapes"] = sorted(list(c) for c in self.call_shapes)
         levels = self.level_padding[l0:]
@@ -754,6 +808,115 @@ class PallasEngine(Engine):
 # sharded backend (shard_map over either single-device executor)
 # ---------------------------------------------------------------------------
 
+@functools.lru_cache(maxsize=None)
+def _pair_executors(mesh: jax.sharding.Mesh, axis: str, inner: str,
+                    interpret: Optional[bool]) -> Dict[int, Callable]:
+    """Per-mode ``jit(shard_map)`` executors of the pair-sharded engine,
+    built once per mesh: an engine made per mine reuses them, so nothing
+    is traced or compiled again after the first mine."""
+    if inner not in ("jnp", "pallas"):
+        raise ValueError(f"unknown inner executor {inner!r}")
+
+    def _local(bms, l, r, s, msup, _mode):
+        if inner == "pallas":
+            # the tile width is derived at trace time from the shard-local
+            # shapes
+            inter, sup, _ = fused_intersect(bms, l, r, s, msup, mode=_mode,
+                                            interpret=interpret)
+        else:
+            inter, sup, _ = fused_intersect_ref(bms, l, r, s, msup,
+                                                mode=_mode)
+        return inter, sup
+
+    # pallas_call has no shard_map replication rule -> unchecked variant
+    smap = shard_map_unchecked if inner == "pallas" else shard_map
+    return {
+        mode: jax.jit(
+            smap(
+                lambda bms, l, r, s, m, _mode=mode: _local(bms, l, r, s, m, _mode),
+                mesh=mesh,
+                in_specs=(P(), P(axis), P(axis), P(axis), P()),
+                out_specs=(P(axis), P(axis)),
+            )
+        )
+        for mode in (MODE_TIDSET, MODE_TID_TO_DIFF, MODE_DIFFSET)
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _partial_executors(mesh: jax.sharding.Mesh, data_axis: str, inner: str,
+                       interpret: Optional[bool], pair_spec: P,
+                       block_spec: P, compacts: bool) -> Dict[int, Callable]:
+    """Per-mode ``jit(shard_map)`` executors over the partial fused kernel,
+    built once per (mesh, placement): shard-local intersect + popcount, one
+    psum over the word (data) axis only — class shards, if any, own
+    disjoint pair blocks whose counts must never mix — then support
+    conversion and the min-support mask on the reduced value.  The
+    pair/block specs are the only thing the word-sharded backends differ
+    by: ``P()`` / ``P(None, data)`` for ``tidsharded`` (pairs replicated),
+    ``P(class)`` / ``P(class, data)`` for ``grid`` (pairs split).
+
+    With ``compacts`` (tidsharded — its pairs are replicated, so survivor
+    order is globally consistent across shards) the survivor-compaction
+    epilogue runs *inside* the shard_map body: the post-psum mask is
+    replicated, so every shard gathers the same survivor rows out of its
+    own word slice, and the padded (Q, W) block never exists outside the
+    executable.  Callers pass the true pair count ``n_valid`` as an extra
+    traced operand (bucket-pad pairs must not be compacted even when their
+    garbage supports pass the threshold).  That executable is jitted as
+    ``fused_intersect_partial_compact``, its name in a trace."""
+    if inner not in ("jnp", "pallas"):
+        raise ValueError(f"unknown inner executor {inner!r}")
+    spec = word_shard_spec(data_axis)
+
+    def _local(bms, l, r, s, msup, _mode):
+        if inner == "pallas":
+            inter, pop = fused_intersect_partial(bms, l, r, mode=_mode,
+                                                 interpret=interpret)
+        else:
+            inter, pop = fused_intersect_partial_ref(bms, l, r, mode=_mode)
+        total = jax.lax.psum(pop, data_axis)
+        sup = total if _mode == MODE_TIDSET else s - total
+        mask = (sup >= msup).astype(jnp.int32)
+        return inter, sup, mask
+
+    # pallas_call has no shard_map replication rule -> unchecked variant
+    smap = shard_map_unchecked if inner == "pallas" else shard_map
+    if compacts:
+        def executor(_mode):
+            def fused_intersect_partial_compact(bms, l, r, s, msup, nv):
+                inter, sup, mask = _local(bms, l, r, s, msup, _mode)
+                return compact_epilogue(inter, sup, mask, nv)
+
+            # shard_map keeps the function's name, the jitted program's
+            return jax.jit(smap(
+                fused_intersect_partial_compact, mesh=mesh,
+                in_specs=(spec, pair_spec, pair_spec, pair_spec, P(), P()),
+                out_specs=(block_spec, pair_spec, pair_spec, P())))
+
+        return {mode: executor(mode)
+                for mode in (MODE_TIDSET, MODE_TID_TO_DIFF, MODE_DIFFSET)}
+    return {
+        mode: jax.jit(
+            smap(
+                lambda bms, l, r, s, m, _mode=mode: _local(bms, l, r, s, m, _mode),
+                mesh=mesh,
+                in_specs=(spec, pair_spec, pair_spec, pair_spec, P()),
+                out_specs=(block_spec, pair_spec, pair_spec),
+            )
+        )
+        for mode in (MODE_TIDSET, MODE_TID_TO_DIFF, MODE_DIFFSET)
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_take(sharding: NamedSharding) -> Callable:
+    """Row gather that keeps ``sharding`` (one jitted program per placement)."""
+    return jax.jit(lambda arr, idx: jax.lax.with_sharding_constraint(
+        jnp.take(arr, idx, axis=0), sharding))
+
+
+
 @register_backend("sharded")
 class ShardedEngine(Engine):
     """Executor-task mapping: pairs grouped by partition device, padded per
@@ -770,37 +933,11 @@ class ShardedEngine(Engine):
         self.inner = inner
         self.interpret = interpret
         self.n_devices = int(mesh.shape[axis])
+        self.block_budget = block_budget(mesh.devices.flat[0])
         # upload placements matching the executor's in_specs (see _dput)
         self._rep_sharding = NamedSharding(mesh, P())
         self._pair_sharding = NamedSharding(mesh, P(axis))
-        if inner not in ("jnp", "pallas"):
-            raise ValueError(f"unknown inner executor {inner!r}")
-
-        def _local(bms, l, r, s, msup, _mode):
-            if inner == "pallas":
-                # the tile width is derived at trace time from the
-                # shard-local shapes
-                inter, sup, _ = fused_intersect(bms, l, r, s, msup,
-                                                mode=_mode,
-                                                interpret=interpret)
-            else:
-                inter, sup, _ = fused_intersect_ref(bms, l, r, s, msup,
-                                                    mode=_mode)
-            return inter, sup
-
-        # pallas_call has no shard_map replication rule -> unchecked variant
-        smap = shard_map_unchecked if inner == "pallas" else shard_map
-        self._sharded = {
-            mode: jax.jit(
-                smap(
-                    lambda bms, l, r, s, m, _mode=mode: _local(bms, l, r, s, m, _mode),
-                    mesh=mesh,
-                    in_specs=(P(), P(axis), P(axis), P(axis), P()),
-                    out_specs=(P(axis), P(axis)),
-                )
-            )
-            for mode in (MODE_TIDSET, MODE_TID_TO_DIFF, MODE_DIFFSET)
-        }
+        self._sharded = _pair_executors(mesh, axis, inner, interpret)
 
     def _expand_call(self, bitmaps, left, right, sup_left, *, mode, min_sup,
                      device_of_pair=None):
@@ -850,12 +987,12 @@ class _WordShardedFrontierMixin:
         self.mesh = mesh
         self.data_axis = data_axis
         self.n_shards = int(mesh.shape[data_axis])
+        self.word_split = self.n_shards
+        self.block_budget = block_budget(mesh.devices.flat[0])
         self._spec = word_shard_spec(data_axis)
         self._sharding = NamedSharding(mesh, self._spec)
         self._rep_sharding = NamedSharding(mesh, P())
-        self._take_rows_sharded = jax.jit(
-            lambda arr, idx: jax.lax.with_sharding_constraint(
-                jnp.take(arr, idx, axis=0), self._sharding))
+        self._take_rows_sharded = _sharded_take(self._sharding)
 
     def _ensure_sharded(self, bitmaps: jax.Array) -> jax.Array:
         """Commit the frontier to ``P(None, data_axis)``, zero-padding the
@@ -877,74 +1014,6 @@ class _WordShardedFrontierMixin:
 
     def prepare_frontier(self, bitmaps: jax.Array) -> jax.Array:
         return self._ensure_sharded(bitmaps)
-
-    def _build_partial_kernels(self, inner: str, interpret: Optional[bool],
-                               pair_spec: P, block_spec: P
-                               ) -> Dict[int, Callable]:
-        """Per-mode ``jit(shard_map)`` executors over the partial fused
-        kernel: shard-local intersect + popcount, one psum over the word
-        (data) axis only — class shards, if any, own disjoint pair blocks
-        whose counts must never mix — then support conversion and the
-        min-support mask on the reduced value.  The pair/block specs are
-        the only thing the word-sharded backends differ by: ``P()`` /
-        ``P(None, data)`` for ``tidsharded`` (pairs replicated),
-        ``P(class)`` / ``P(class, data)`` for ``grid`` (pairs split).
-
-        A backend whose ``compacts_in_executable`` is set (tidsharded — its
-        pairs are replicated, so survivor order is globally consistent
-        across shards) additionally runs the survivor-compaction epilogue
-        *inside* the shard_map body: the post-psum mask is replicated, so every shard gathers the same
-        survivor rows out of its own word slice, and the padded (Q, W)
-        block never exists outside the executable.  Callers pass the true
-        pair count ``n_valid`` as an extra traced operand (bucket-pad pairs
-        must not be compacted even when their garbage supports pass the
-        threshold)."""
-        if inner not in ("jnp", "pallas"):
-            raise ValueError(f"unknown inner executor {inner!r}")
-        data_axis = self.data_axis
-
-        def _local(bms, l, r, s, msup, _mode):
-            if inner == "pallas":
-                inter, pop = fused_intersect_partial(bms, l, r, mode=_mode,
-                                                     interpret=interpret)
-            else:
-                inter, pop = fused_intersect_partial_ref(bms, l, r, mode=_mode)
-            total = jax.lax.psum(pop, data_axis)
-            sup = total if _mode == MODE_TIDSET else s - total
-            mask = (sup >= msup).astype(jnp.int32)
-            return inter, sup, mask
-
-        # pallas_call has no shard_map replication rule -> unchecked variant
-        smap = shard_map_unchecked if inner == "pallas" else shard_map
-        if self.compacts_in_executable:
-            def _local_compact(bms, l, r, s, msup, nv, _mode):
-                inter, sup, mask = _local(bms, l, r, s, msup, _mode)
-                return compact_epilogue(inter, sup, mask, nv)
-
-            return {
-                mode: jax.jit(
-                    smap(
-                        lambda bms, l, r, s, m, nv, _mode=mode:
-                            _local_compact(bms, l, r, s, m, nv, _mode),
-                        mesh=self.mesh,
-                        in_specs=(self._spec, pair_spec, pair_spec,
-                                  pair_spec, P(), P()),
-                        out_specs=(block_spec, pair_spec, pair_spec, P()),
-                    )
-                )
-                for mode in (MODE_TIDSET, MODE_TID_TO_DIFF, MODE_DIFFSET)
-            }
-        return {
-            mode: jax.jit(
-                smap(
-                    lambda bms, l, r, s, m, _mode=mode: _local(bms, l, r, s, m, _mode),
-                    mesh=self.mesh,
-                    in_specs=(self._spec, pair_spec, pair_spec, pair_spec, P()),
-                    out_specs=(block_spec, pair_spec, pair_spec),
-                )
-            )
-            for mode in (MODE_TIDSET, MODE_TID_TO_DIFF, MODE_DIFFSET)
-        }
 
 
 # ---------------------------------------------------------------------------
@@ -972,8 +1041,6 @@ class TidShardedEngine(_WordShardedFrontierMixin, Engine):
     device is unchanged while memory drops ~1/n.
     """
 
-    compacts_in_executable = True
-
     def __init__(self, mesh: jax.sharding.Mesh, bucket_min: int = 128,
                  axis: str = "data", inner: str = "pallas",
                  interpret: Optional[bool] = None,
@@ -986,8 +1053,10 @@ class TidShardedEngine(_WordShardedFrontierMixin, Engine):
         # (device_of_pair) is meaningless and ignored, so advertise a single
         # pair device to the drivers
         self.n_devices = 1
-        self._sharded = self._build_partial_kernels(inner, interpret,
-                                                    P(), self._spec)
+        # pairs are replicated, so survivor order is global: compaction runs
+        # inside the executable
+        self._sharded = _partial_executors(mesh, axis, inner, interpret, P(),
+                                           self._spec, True)
 
     def stats(self, since=None) -> dict:
         out = super().stats(since=since)
@@ -1000,8 +1069,10 @@ class TidShardedEngine(_WordShardedFrontierMixin, Engine):
         self.n_intersections += q
         qb, l, r, s = self.buffers.fill(left, right, sup_left)
         self._record_padding(q, qb)
+        self._note_call(bitmaps, qb, mode)
         bitmaps = self._ensure_sharded(bitmaps)
         rep = self._rep_sharding
+        self.n_psums += 1
         inter, sup, mask_dev, _ = self._sharded[mode](
             bitmaps, _dput(l, rep), _dput(r, rep), _dput(s, rep),
             _dput_i32(min_sup, rep), _dput_i32(q, rep))
@@ -1042,11 +1113,6 @@ class GridShardedEngine(_WordShardedFrontierMixin, Engine):
     separately (executor count, database size), composed on one mesh.
     """
 
-    # survivors live in per-class pad blocks whose order differs from global
-    # pair order, so in-executable compaction would emit them class-blocked:
-    # they are gathered after the read (Engine._compact)
-    compacts_in_executable = False
-
     def __init__(self, mesh: jax.sharding.Mesh, bucket_min: int = 128,
                  class_axis: str = "class", data_axis: str = "data",
                  inner: str = "pallas", interpret: Optional[bool] = None,
@@ -1067,9 +1133,12 @@ class GridShardedEngine(_WordShardedFrontierMixin, Engine):
         # drivers route partition->device over the pair (class) axis
         self.n_devices = self.n_class
         self._pair_vec_sharding = NamedSharding(mesh, grid_pair_spec(class_axis))
-        self._sharded = self._build_partial_kernels(
-            inner, interpret, grid_pair_spec(class_axis),
-            grid_block_spec(class_axis, data_axis))
+        # survivors live in per-class pad blocks whose order differs from
+        # global pair order, so in-executable compaction would emit them
+        # class-blocked: they are gathered after the read (Engine._compact)
+        self._sharded = _partial_executors(
+            mesh, data_axis, inner, interpret, grid_pair_spec(class_axis),
+            grid_block_spec(class_axis, data_axis), False)
 
     def stats(self, since=None) -> dict:
         out = super().stats(since=since)
@@ -1088,6 +1157,7 @@ class GridShardedEngine(_WordShardedFrontierMixin, Engine):
         self.device_pair_counts.append(counts)
         self._record_padding(q, d * qmax)
         bitmaps = self._ensure_sharded(bitmaps)
+        self.n_psums += 1
         inter, sup, mask_dev = self._sharded[mode](
             bitmaps,
             _dput(lpad.reshape(d * qmax), self._pair_vec_sharding),

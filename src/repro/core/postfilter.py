@@ -160,7 +160,7 @@ def top_k_mine(
         raise ValueError(f"top-k needs k >= 1, got {k}")
     if min_len < 1:
         raise ValueError(f"min_len must be >= 1, got {min_len}")
-    n_txn = len(transactions)
+    n_txn = bm.n_transactions(transactions)
     template = config if config is not None else EclatConfig(min_sup=1)
 
     sup1 = bm.support_np(bm.pack_transactions(transactions, n_items))

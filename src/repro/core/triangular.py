@@ -12,10 +12,15 @@ tests assert the kernel matches it bit-exactly in interpret mode.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 from functools import partial
+from jax.sharding import PartitionSpec as P
+
+from ..dist.compat import shard_map
 
 __all__ = ["cooc_blocks", "cooccurrence_counts", "frequent_pairs"]
 
@@ -59,10 +64,28 @@ def cooc_blocks(n: int) -> int:
     return 1 if n else 0
 
 
-def cooccurrence_counts(bitmaps, block: int = 64) -> np.ndarray:
+@functools.lru_cache(maxsize=None)
+def _shard_cooc_program(mesh, axis: str, block: int, pad: int):
+    """Per word shard: the counts of its slice (:func:`_cooc_block`), then
+    one ``psum`` of the ``(n, n)`` int32 partial counts over ``axis``."""
+
+    def shard_cooc(bitmaps):
+        if pad:
+            bitmaps = jnp.pad(bitmaps, ((0, pad), (0, 0)))
+        return jax.lax.psum(_cooc_block(bitmaps, block), axis)
+
+    return jax.jit(shard_map(shard_cooc, mesh=mesh, in_specs=P(None, axis),
+                             out_specs=P()))
+
+
+def cooccurrence_counts(bitmaps, block: int = 64, *, mesh=None,
+                        axis=None) -> np.ndarray:
     """Full (n, n) co-occurrence count matrix: one device program over
     ``block``-row blocks, so the (block, n, W) intermediate stays
-    cache/VMEM sized, and one blocking read."""
+    cache/VMEM sized, and one blocking read.  With ``mesh``, ``bitmaps``
+    is a frontier word-sharded over ``axis``: each shard counts its own
+    word slice and one psum adds the partial counts, so no shard sees
+    another's words."""
     if not isinstance(bitmaps, jax.Array):
         # explicit upload (staticcheck RS005): callers on the slide hot path
         # hand device arrays in; host arrays are device_put once, up front
@@ -71,6 +94,9 @@ def cooccurrence_counts(bitmaps, block: int = 64) -> np.ndarray:
     if n == 0:
         return np.zeros((0, 0), np.int32)
     pad = _padded_rows(n, block) - n
+    if mesh is not None:
+        counts = _shard_cooc_program(mesh, axis, block, pad)(bitmaps)
+        return jax.device_get(counts)[:n, :n]
     bitmaps_p = _pad_rows(bitmaps, pad) if pad else bitmaps
     return jax.device_get(_cooc_block(bitmaps_p, block))[:n, :n]
 

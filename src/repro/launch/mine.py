@@ -17,6 +17,7 @@ import os
 import jax
 
 from ..core import EclatConfig, generate_rules, mine, resume_mine, top_k_mine
+from ..core.bitmap import to_csr
 from ..data import PAPER_DATASETS, generate, load_fimi
 from .compile_cache import enable_compile_cache
 
@@ -97,8 +98,12 @@ def main(argv=None):
         profile = jax.profiler.trace(args.profile)
     else:
         profile = contextlib.nullcontext()
+    # the database goes in as CSR arrays, as a deployment reading a packed
+    # file hands it over; under a word-sharded mesh each device then builds
+    # its own slice of the vertical database from them
     with profile:
-        return _run(args, cfg, mesh, txns, n_items, name + scale_note)
+        return _run(args, cfg, mesh, to_csr(txns), n_items,
+                    name + scale_note)
 
 
 def _run(args, cfg, mesh, txns, n_items, name):

@@ -201,7 +201,8 @@ def test_stats_pair_padding():
 def test_snapshot_is_four_tuple():
     e = eng.make_engine("pallas", bucket_min=8)
     snap = e.snapshot()
-    # four work counters, then the blocking reads and their wait seconds
-    assert snap == (0, 0, 0, 0, 0, 0.0)
+    # four work counters, the blocking reads and their wait seconds, then
+    # the psums the mesh engines issue
+    assert snap == (0, 0, 0, 0, 0, 0.0, 0)
     stats = e.stats(since=snap)
     assert stats["n_intersections"] == 0

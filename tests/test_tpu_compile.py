@@ -116,3 +116,46 @@ def test_cooc_block_compiles_for_v5e(spec):
     compiled = _cooc_block.lower(spec((ROWS, WIDTHS["T10I4D100K"]), jnp.uint32),
                                  64).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * ROWS * 4 * 2
+
+
+@pytest.fixture(scope="module")
+def data_mesh(topo):
+    """The described v5e:2x2 as a one-axis ``("data",)`` mesh of 4."""
+    import numpy as np
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices).reshape(4), ("data",))
+
+
+def test_word_sharded_executor_compiles_for_a_2x2_v5e_at_t40i10d4m(
+        data_mesh, monkeypatch):
+    """The ``tidsharded`` executable at T40I10D4M's width (32,768 words a
+    chip, 4,000,000 transactions over four) and its peak pair rung
+    (24,576): the partial kernel, an all-reduce of the counts, under the
+    jitted name the trace readers look up, within one chip's memory with
+    a frontier of as many rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import engine as eng
+
+    # a described device reports no memory; the budget is not compiled
+    monkeypatch.setattr(eng, "block_budget", lambda device: 4 * 10**9)
+    e = eng.TidShardedEngine(data_mesh, inner="pallas", interpret=False)
+
+    def spec(shape, dtype, p):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(data_mesh, p))
+
+    q = 24576
+    pair = spec((q,), jnp.int32, P())
+    scalar = spec((), jnp.int32, P())
+    compiled = e._sharded[eng.MODE_TIDSET].lower(
+        spec((q, 4 * 32768), jnp.uint32, P(None, "data")), pair, pair, pair,
+        scalar, scalar).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert "jit_fused_intersect_partial_compact" in text
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 16e9
